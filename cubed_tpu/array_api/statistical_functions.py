@@ -79,10 +79,9 @@ def _sum_with_dtype(a, axis=None, keepdims=False, dtype=None):
     return nxp.sum(a, axis=axis, keepdims=keepdims, dtype=dtype)
 
 
-# semantic tag on the combine (e.g. "sum"): kept as the seam for kernel
-# substitution experiments — the round-3 Pallas streaming-reduction kernels
-# consumed it before being retired on measured evidence (see
-# benchmarks/BENCH_PROFILE.md "Pallas verdict")
+# semantic tag on the combine (e.g. "sum"): the seam for kernel substitution
+# (hand-written streaming-reduction kernels consumed it before they were
+# retired; git history keeps them)
 _sum_with_dtype.reduce_kind = "sum"
 
 

@@ -31,20 +31,21 @@ if BACKEND == "jax":
     # compiles of structurally identical kernels ~100x cheaper.
     # CPU-only runs (tests) skip it: XLA:CPU AOT entries bake host machine
     # features, so a cache written on one machine can SIGILL on another.
-    if (
-        os.environ.get("CUBED_TPU_COMPILATION_CACHE", "1") == "1"
-        and os.environ.get("JAX_PLATFORMS", "").lower() != "cpu"
-    ):
-        cache_dir = os.environ.get(
-            "CUBED_TPU_COMPILATION_CACHE_DIR",
-            os.path.expanduser("~/.cache/cubed_tpu_xla"),
-        )
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        except Exception:
-            pass
+    # Where JAX_COMPILATION_CACHE_DIR is set, jax's own reading of it stands
+    # and no directory is set here, so whoever launches the process decides
+    # where compiled programs persist; otherwise the cache lives at one
+    # fixed path beside the package, the same from any working directory.
+    if os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update(
+                "jax_compilation_cache_dir",
+                os.path.join(
+                    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                    ".jax_cache",
+                ),
+            )
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
     import jax.numpy as namespace  # noqa: F401
 

@@ -167,6 +167,10 @@ def _store_op(x: CoreArray, store, storage_options) -> CoreArray:
     def _identity(a):
         return a
 
+    # values pass through untouched: a device executor may move them in
+    # whatever representation keeps every bit (executors/jax.py)
+    _identity.bit_preserving = True
+
     # identity blockwise into an explicit target store; fuses with producers
     return blockwise(
         _identity,

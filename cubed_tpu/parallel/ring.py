@@ -33,10 +33,7 @@ def ring_matmul(a, b, mesh=None, axis_name: str = "data"):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     if mesh is None:
         from .mesh import make_mesh
@@ -105,10 +102,7 @@ def ring_reduction(x, combine, mesh=None, axis_name: str = "data"):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     if mesh is None:
         from .mesh import make_mesh

@@ -145,10 +145,7 @@ def ring_attention(
 
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     spec = P(None, axis_name, None, None)
     fn = functools.partial(

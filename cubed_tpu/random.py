@@ -11,13 +11,10 @@ HLO is identical for every plan — one persistent-cache compile serves all
 random arrays of a given chunk shape.
 
 Backend-appropriate generation (``CUBED_TPU_RNG`` = ``auto`` | ``threefry``
-| ``philox``, default ``auto``): threefry is the TPU fast path (counter-
-based, fuses into the surrounding XLA program — the committed 20.7 GB/s
-vorticity device profile is four such generations), but XLA-CPU executes
-the same threefry ~20x slower than numpy's Philox (measured:
-benchmarks/BENCH_PROFILE.md r4/r5 sections — it dominates every below-
-baseline CPU-fallback metric). ``auto`` therefore routes by the actual
-execution platform at kernel-trace time: TPU/GPU generate with fused
+| ``philox``, default ``auto``): threefry is the accelerator path (counter-
+based, fuses into the surrounding XLA program), but XLA-CPU executes the
+same threefry far slower than numpy's Philox. ``auto`` therefore routes by
+the actual execution platform at kernel-trace time: TPU/GPU generate with fused
 threefry; single-device CPU generates with the numpy Philox stream via
 ``jax.pure_callback`` — block-sized host generation feeding the fused XLA
 consumer, giving the CPU path the numpy backend's generation rate AND
@@ -175,9 +172,9 @@ def _philox_block(shape, seeded_offset, draw, out_dtype):
 def _ensure_partitionable_threefry():
     """Counter-parallel threefry lowering: generates each element
     independently instead of odd/even halves + strided interleave — the
-    interleave was measured as the dominant kernel in the vorticity
-    benchmark's device profile (a 2-tuple "select_select" fusion at
-    ~11 GB/s). This selects a DIFFERENT (still deterministic,
+    interleave (a 2-tuple "select_select" fusion) was the dominant kernel
+    of the vorticity pipeline in a device profile taken on an earlier
+    installation. This selects a DIFFERENT (still deterministic,
     platform-invariant) stream than the default lowering, which is fine
     for the per-block contract: the flag is set lazily at the FIRST
     cubed_tpu RNG use in a process — array construction client-side, and

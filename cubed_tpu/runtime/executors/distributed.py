@@ -58,7 +58,6 @@ from ..types import (
     callbacks_on,
 )
 from ..utils import end_generation, merge_generation
-from .multiprocess import _PLUGIN_ENV_PREFIXES
 from .python_async import compute_retry_budget, map_unordered
 
 logger = logging.getLogger(__name__)
@@ -76,14 +75,11 @@ _PER_COMPUTE_ENV_VARS = (
 
 
 def _worker_env() -> dict:
-    """Hermetic env for locally spawned workers: CPU jax, no device plugin
-    registration (workers do chunk IO + host compute; the client process owns
-    any device executor)."""
+    """Env for locally spawned workers: CPU jax (workers do chunk IO + host
+    compute; the client process owns any device executor, and an
+    accelerator belongs to one process at a time)."""
     env = {
-        k: v
-        for k, v in os.environ.items()
-        if not k.startswith(_PLUGIN_ENV_PREFIXES)
-        and k not in _PER_COMPUTE_ENV_VARS
+        k: v for k, v in os.environ.items() if k not in _PER_COMPUTE_ENV_VARS
     }
     env["JAX_PLATFORMS"] = "cpu"
     repo_root = os.path.dirname(

@@ -25,6 +25,19 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
 - **Spill path.** If HBM residency would exceed ``device_mem``, least-recently
   used arrays are flushed to their Zarr targets and dropped; reads fall back
   to storage. This keeps the bounded-memory story for arrays larger than HBM.
+- **64-bit floats that only move.** A device without native f64 (TPU v5e
+  holds a float64 as a pair of float32: ~49 significand bits, float32's
+  exponent range) changes values merely by holding them. A compute whose
+  every op only moves values (rechunk, the ``to_zarr`` store op) therefore
+  carries float64 arrays (and float64 fields of record arrays) through HBM
+  as their uint64 bit patterns, so Zarr -> HBM -> Zarr stays bit for bit;
+  arithmetic plans compute in the device's float64 as before. The choice
+  is made once per compute (``_execute_dag_inner``) and acted on in one
+  pair of functions, ``_device_put`` and ``_to_host``, which every
+  transfer in either direction goes through. It is all or nothing: a
+  movement op that shares its compute with arithmetic, or with a
+  complex128 array, moves in the device's float64, and
+  ``stats["f64_lossy_moves"]`` counts those.
 - **Scheduling.** This executor always keeps op ordering and ignores
   ``Spec(scheduler="dataflow")``: whole (fused) segments compile to single
   XLA programs over HBM-resident arrays, so there is no per-chunk task
@@ -103,6 +116,27 @@ class _Resident:
         self.last_used = time.monotonic()
 
 
+def _moves_values(op) -> bool:
+    """Whether a primitive op passes values through unchanged: rechunk,
+    array creation, a blockwise kernel declared ``bit_preserving``."""
+    function = op.pipeline.function
+    return (
+        function is copy_read_to_write
+        or function is create_zarr_array
+        or (
+            function is apply_blockwise
+            and getattr(op.pipeline.config.function, "bit_preserving", False)
+        )
+    )
+
+
+def _holds(dtype, *kinds) -> bool:
+    """Whether ``dtype`` is one of ``kinds`` or a record with such a field."""
+    if dtype.fields is not None:
+        return any(_holds(field[0], *kinds) for field in dtype.fields.values())
+    return any(dtype == kind for kind in kinds)
+
+
 def _value_nbytes(value) -> int:
     if isinstance(value, dict):
         return sum(_value_nbytes(v) for v in value.values())
@@ -176,22 +210,11 @@ class JaxExecutor(DagExecutor):
         self.matmul_precision = matmul_precision
         #: trace consecutive traceable ops into ONE jitted XLA program
         self.fuse_plan = fuse_plan
-        if "use_pallas" in kwargs:
-            # removed in round 5 (see BENCH_PROFILE.md "Pallas verdict");
-            # a silent no-op would misread as the kernels running
-            import warnings
-
-            warnings.warn(
-                "use_pallas was removed: the Pallas streaming-reduction "
-                "kernels were retired on measured evidence "
-                "(benchmarks/BENCH_PROFILE.md); reductions use XLA's "
-                "fused combines",
-                FutureWarning,
-                stacklevel=2,
-            )
-            kwargs.pop("use_pallas")
         self.kwargs = kwargs
         self._tracing = False
+        #: this compute moves float64 arrays as uint64 bit patterns
+        #: (decided per compute in _execute_dag_inner)
+        self._carry_bits = False
         self._prepared_bases: Dict[int, Any] = {}
         self._placement = None  # factorized placement mesh, built lazily
         #: execution-path counters for the last ``execute_dag`` call, reported
@@ -200,7 +223,9 @@ class JaxExecutor(DagExecutor):
         #: ``segment_mem_aborts``, ``segment_hbm_footprint``,
         #: ``whole_array_hits``, ``whole_concat_hits``, ``batched_ops``,
         #: ``chunked_ops``, ``rechunk_alias`` (zero-copy), ``rechunk_virtual``
-        #: (materialized), ``eager_ops``, and the
+        #: (materialized), ``eager_ops``, ``f64_as_bits`` (float64 arrays
+        #: moved to the device as bit patterns), ``f64_lossy_moves`` (copies
+        #: of 64-bit floats through a device float64 that is not one), and the
         #: failure counters ``eager_fallbacks`` / ``trace_failures`` /
         #: ``whole_array_errors`` / ``batched_errors`` / ``whole_select_errors``
         #: / ``jit_kernel_errors``
@@ -216,14 +241,69 @@ class JaxExecutor(DagExecutor):
     def _budget(self) -> int:
         if self.device_mem is not None:
             return self.device_mem
-        jax = _jax()
-        try:
-            stats = jax.devices()[0].memory_stats()
+        device = self._first_device()
+        stats = device.memory_stats()
+        if stats and "bytes_limit" in stats:
             per_device = int(stats["bytes_limit"] * 0.75)
-        except Exception:
-            per_device = 8 * 2**30  # CPU/virtual devices: pick a sane default
+        elif device.platform == "cpu":
+            # host (and virtual) CPU devices report no limit
+            per_device = 8 * 2**30
+        else:
+            raise RuntimeError(
+                f"{device} reports no memory_stats()['bytes_limit']; pass "
+                "device_mem= to set the HBM residency budget explicitly"
+            )
         n = len(self.mesh.devices.flat) if self.mesh is not None else 1
         return per_device * n
+
+    def _first_device(self):
+        """The first device this process can address (under multi-controller
+        SPMD the mesh also holds other hosts' devices)."""
+        jax = _jax()
+        if self.mesh is None:
+            return jax.local_devices()[0]
+        return next(
+            d for d in self.mesh.devices.flat
+            if d.process_index == jax.process_index()
+        )
+
+    def _only_moves_values(self, dag) -> bool:
+        """True when every op of the plan passes values through unchanged
+        (see ``_moves_values``) between Zarr arrays, so nothing on the
+        device ever needs them as numbers. complex128 has no bit-pattern
+        form here (a uint64 view changes the shape), so a plan that holds
+        one is not carried."""
+        for _, d in dag.nodes(data=True):
+            op = d.get("primitive_op")
+            if op is not None:
+                if not _moves_values(op):
+                    return False
+            elif d.get("type") == "array":
+                target = d.get("target")
+                if not isinstance(
+                    target, (LazyZarrArray, ZarrV2Array)
+                ) or _holds(target.dtype, np.complex128):
+                    return False
+        return True
+
+    def _lossy_moves(self, dag) -> int:
+        """Ops that only move values and write an array holding 64-bit
+        floats: on a device without native float64, outside a compute that
+        carries bits, each such copy is not exact."""
+        count = 0
+        for name, d in dag.nodes(data=True):
+            op = d.get("primitive_op")
+            if op is None or not _moves_values(op):
+                continue
+            written = (
+                dag.nodes[out].get("target") for out in dag.successors(name)
+            )
+            count += any(
+                _holds(t.dtype, np.float64, np.complex128)
+                for t in written
+                if hasattr(t, "dtype")
+            )
+        return count
 
     def _placement_mesh(self):
         """Prime-factorized view of the mesh used for all array placement
@@ -235,7 +315,8 @@ class JaxExecutor(DagExecutor):
         return self._placement
 
     def _keep_sharding_constraint(self, value, target):
-        """Pin a traced segment output to the executor's mesh sharding.
+        """Pin an array of a traced segment to the executor's mesh sharding
+        (every array the segment produces, see ``_admit``, and its outputs).
 
         Batched kernels gather/stack/reassemble chunks inside the trace,
         after which XLA may propagate a REPLICATED layout to the output.
@@ -297,18 +378,59 @@ class JaxExecutor(DagExecutor):
         return jax.numpy.full(shape, fill_value, dtype=dtype)
 
     def _device_put(self, value, shape, chunkset=None):
+        """Host -> device. With ``_to_host`` the only pair of functions that
+        knows how a value is represented on the device, and every transfer
+        goes through them.
+
+        ``value`` is a host array or an opened storage array; ``shape`` is
+        the shape of the array it is (a part of) for sharding, None for a
+        piece that is placed whole. A record array becomes a dict of its
+        fields. float64 goes as its uint64 bit pattern when this compute
+        carries bits (see the module docstring). Under a mesh a storage
+        array is read through ``make_array_from_callback``: each process
+        materializes only the regions its addressable shards cover — the
+        per-host Zarr IO sharding seam of docs/multihost.md (on one host
+        this degenerates to reading everything, shard by shard)."""
         jax = _jax()
+        stored = not isinstance(value, (np.ndarray, np.generic))
+        if value.dtype.fields is not None:
+            data = (value[...] if value.shape else value[()]) if stored else value
+            return {
+                k: self._device_put(np.ascontiguousarray(data[k]), shape, chunkset)
+                for k in data.dtype.names
+            }
+        as_bits = self._carry_bits and value.dtype == np.float64
+        if as_bits:
+            self.stats["f64_as_bits"] += 1
+
+        def transferred(data):
+            data = np.asarray(data)
+            return data.view(np.uint64) if as_bits else data
+
         sharding = self._sharding_for(shape, chunkset)
-        if sharding is not None:
-            if isinstance(value, dict):
-                return {
-                    k: jax.device_put(v, self._sharding_for(v.shape, chunkset))
-                    for k, v in value.items()
-                }
-            return jax.device_put(value, sharding)
+        if sharding is None:
+            if stored:
+                value = value[...] if value.shape else value[()]
+            return jax.device_put(transferred(value))
+        if stored:
+            return jax.make_array_from_callback(
+                tuple(shape), sharding, lambda idx: transferred(value[idx])
+            )
+        return jax.device_put(transferred(value), sharding)
+
+    def _to_host(self, value, dtype) -> np.ndarray:
+        """Device -> host: a device value (or dict of record fields) as a
+        host array of the target's ``dtype``; undoes ``_device_put``."""
         if isinstance(value, dict):
-            return {k: jax.device_put(v) for k, v in value.items()}
-        return jax.device_put(value)
+            fields = {k: self._to_host(value[k], dtype[k]) for k in dtype.names}
+            rec = np.empty(next(iter(fields.values())).shape, dtype=dtype)
+            for k, field in fields.items():
+                rec[k] = field
+            return rec
+        host = np.asarray(value)
+        if self._carry_bits and host.dtype == np.uint64 and dtype == np.float64:
+            host = host.view(np.float64)
+        return host
 
     # ------------------------------------------------------------------
 
@@ -373,6 +495,15 @@ class JaxExecutor(DagExecutor):
         self.stats = Counter()
         resident: Dict[str, _Resident] = {}
         budget = self._budget()
+        self._carry_bits = False
+        # with x64 off (compute_dtype="float32") float64 is given up by
+        # choice, and there is no uint64 to carry it in
+        if jax.config.jax_enable_x64 and not _float64_round_trips(
+            self._first_device()
+        ):
+            self._carry_bits = self._only_moves_values(dag)
+            if not self._carry_bits:
+                self.stats["f64_lossy_moves"] = self._lossy_moves(dag)
 
         # map array-node name -> target, to know what must be flushed
         requested_stores = set()
@@ -541,14 +672,7 @@ class JaxExecutor(DagExecutor):
 
     def _preload(self, arr, resident, budget) -> bool:
         """Load a concrete storage array onto the device (outside any trace)
-        so segment programs take it as an input, not a baked constant.
-
-        Under a mesh, ingestion goes through ``make_array_from_callback``:
-        each process materializes only the storage regions its addressable
-        shards cover — the per-host Zarr IO sharding seam of
-        docs/multihost.md (on one host this degenerates to reading
-        everything, shard by shard)."""
-        jax = _jax()
+        so segment programs take it as an input, not a baked constant."""
         key = str(arr.store)
         if key in resident:
             return True
@@ -564,26 +688,7 @@ class JaxExecutor(DagExecutor):
             if concrete.shape and getattr(concrete, "chunks", None)
             else None
         )
-        shape = tuple(concrete.shape)
-        sharding = self._sharding_for(shape, cs)
-        if (
-            sharding is not None
-            and shape
-            and concrete.dtype.fields is None
-        ):
-            value = jax.make_array_from_callback(
-                shape, sharding, lambda idx: np.asarray(concrete[idx])
-            )
-            self._admit(resident, key, value, arr, budget)
-            return True
-        data = concrete[...] if concrete.shape else concrete[()]
-        if data.dtype.fields is not None:
-            value = {
-                k: self._device_put(np.ascontiguousarray(data[k]), data.shape, cs)
-                for k in data.dtype.names
-            }
-        else:
-            value = self._device_put(data, data.shape, cs)
+        value = self._device_put(concrete, tuple(concrete.shape), cs)
         self._admit(resident, key, value, arr, budget)
         return True
 
@@ -840,10 +945,10 @@ class JaxExecutor(DagExecutor):
                 bool(jax.config.jax_enable_x64),
                 devices,
                 jax.devices()[0].platform,
-                # executor config that changes the traced program: the Pallas
-                # opt-in swaps combine kernels; the mesh SHAPE (not just the
-                # flat device order) determines shardings; the contraction
-                # precision changes MXU pass counts inside the same HLO shape
+                # executor config that changes the traced program: the mesh
+                # SHAPE (not just the flat device order) determines
+                # shardings; the contraction precision changes MXU pass
+                # counts inside the same HLO shape
                 str(self.matmul_precision),
                 tuple(self.mesh.devices.shape) if self.mesh is not None else None,
                 tuple(self.mesh.axis_names) if self.mesh is not None else None,
@@ -1223,13 +1328,7 @@ class JaxExecutor(DagExecutor):
                 if self._tracing:
                     raise _TraceAbort("storage read inside traced segment")
                 data = arr[...] if arr.shape else arr[()]
-                if data.dtype.fields is not None:
-                    out[name] = {
-                        k: self._device_put(np.ascontiguousarray(data[k]), data.shape)
-                        for k in data.dtype.names
-                    }
-                else:
-                    out[name] = self._device_put(data, data.shape)
+                out[name] = self._device_put(data, data.shape)
             elif isinstance(arr, LazyZarrArray):
                 if self._tracing:
                     raise _TraceAbort("storage read inside traced segment")
@@ -1298,8 +1397,8 @@ class JaxExecutor(DagExecutor):
         """Stack every task's input chunks on device and run vmap(kernel) once.
 
         Collapses the reference's task fan-out (one dispatch per chunk through
-        storage) into a single XLA program: per-task host overhead and tunnel
-        round-trips vanish, and XLA tiles the batched kernel onto the MXU/VPU.
+        storage) into a single XLA program: per-task host overhead
+        vanishes, and XLA tiles the batched kernel onto the MXU/VPU.
         Returns None when the op isn't batchable (ragged grid, streamed reads,
         non-uniform structure)."""
         jax = _jax()
@@ -1458,17 +1557,7 @@ class JaxExecutor(DagExecutor):
                 host = np.stack(
                     [np.asarray(opened[get_item(chunkset, c)]) for c in coords]
                 )
-                if host.dtype.fields is not None:
-                    stacked_leaves.append(
-                        {
-                            k: self._device_put(
-                                np.ascontiguousarray(host[k]), None
-                            )
-                            for k in host.dtype.names
-                        }
-                    )
-                else:
-                    stacked_leaves.append(self._device_put(host, None))
+                stacked_leaves.append(self._device_put(host, None))
                 in_axes_leaves.append(0)
 
             if all(ax is None for ax in in_axes_leaves):
@@ -1688,15 +1777,15 @@ class JaxExecutor(DagExecutor):
         # storage / small-virtual fallback (host read + device transfer)
         if self._tracing and isinstance(arr, (ZarrV2Array, LazyZarrArray)):
             raise _TraceAbort("storage read inside traced segment")
-        from ...primitive.blockwise import get_chunk
-
         opened = proxy.open()
         chunkset = (
             blockdims_from_blockshape(opened.shape, proxy.chunks)
             if opened.shape
             else ()
         )
-        return get_chunk(opened, chunkset, coords)
+        return self._device_put(
+            np.asarray(opened[get_item(chunkset, coords)]), None
+        )
 
     # ------------------------------------------------------------------
     # rechunk: resident alias / storage fallback
@@ -1734,13 +1823,7 @@ class JaxExecutor(DagExecutor):
             opened = None
         if opened is not None and opened.nbytes < budget // 2:
             data = opened[...] if opened.shape else opened[()]
-            if data.dtype.fields is not None:
-                value = {
-                    k: self._device_put(np.ascontiguousarray(data[k]), data.shape)
-                    for k in data.dtype.names
-                }
-            else:
-                value = self._device_put(data, data.shape)
+            value = self._device_put(data, data.shape)
             self._admit(resident, dst_key, value, dst, budget)
         else:
             # bounded host-side copy (the spill path)
@@ -1752,6 +1835,12 @@ class JaxExecutor(DagExecutor):
     # ------------------------------------------------------------------
 
     def _admit(self, resident, store: str, value, target, budget: int) -> None:
+        if self._tracing:
+            # inside a traced segment this is where an array is "placed":
+            # without the constraint a segment whose inputs are all virtual
+            # (random arrays) carries no sharding at all and XLA compiles
+            # it for ONE device of the mesh
+            value = self._keep_sharding_constraint(value, target)
         nbytes = _value_nbytes(value)
         self._evict(resident, budget - nbytes, exclude=store)
         resident[store] = _Resident(value, nbytes, target)
@@ -1783,13 +1872,7 @@ class JaxExecutor(DagExecutor):
         value = res.value
         shape = tuple(concrete.shape)
         if not shape:
-            if isinstance(value, dict):
-                rec = np.empty((), dtype=concrete.dtype)
-                for k in concrete.dtype.names:
-                    rec[k] = np.asarray(value[k])
-                concrete[()] = rec
-            else:
-                concrete[()] = np.asarray(value)
+            concrete[()] = self._to_host(value, concrete.dtype)
             return
         chunkset = blockdims_from_blockshape(shape, concrete.chunks)
         coords_iter = itertools.product(*(range(len(c)) for c in chunkset))
@@ -1825,15 +1908,14 @@ class JaxExecutor(DagExecutor):
             coords_iter = iter(mine)
         for idx in coords_iter:
             sel = get_item(chunkset, idx)
-            if isinstance(value, dict):
-                fields = {k: np.asarray(v[sel]) for k, v in value.items()}
-                first = next(iter(fields.values()))
-                rec = np.empty(first.shape, dtype=concrete.dtype)
-                for k in concrete.dtype.names:
-                    rec[k] = fields[k]
-                concrete[sel] = rec
-            else:
-                concrete[sel] = np.asarray(value[sel])
+            # the device slice is not bound to a name here: it would stay
+            # alive on the device while the next chunk is sliced
+            concrete[sel] = self._to_host(
+                {k: v[sel] for k, v in value.items()}
+                if isinstance(value, dict)
+                else value[sel],
+                concrete.dtype,
+            )
 
 
 #: in-process cache of (compiled segment program, HBM footprint) keyed by the
@@ -1856,6 +1938,26 @@ _STRUCT_DEBUG: Optional[list] = None
 #: otherwise interleave the size-check/evict/insert sequences and could
 #: evict an entry a sibling just read or resurrect one past the bound
 _CACHE_LOCK = threading.Lock()
+
+
+#: (platform, device_kind) -> whether float64 survives a round trip
+_FLOAT64_ROUND_TRIPS: Dict[tuple, bool] = {}
+
+
+def _float64_round_trips(device) -> bool:
+    """Whether a float64 comes back from ``device`` bit for bit.
+
+    Observed, not assumed from the platform's name: a few values that need
+    all 53 significand bits or float64's exponent range go to the device
+    and back once per kind of device."""
+    key = (device.platform, device.device_kind)
+    known = _FLOAT64_ROUND_TRIPS.get(key)
+    if known is None:
+        probe = np.array([np.pi, 1.0 + 2.0**-52, 1e300, 1e-300])
+        back = np.asarray(_jax().device_put(probe, device))
+        known = back.tobytes() == probe.tobytes()
+        _FLOAT64_ROUND_TRIPS[key] = known
+    return known
 
 
 def _hbm_footprint(compiled) -> int:

@@ -126,7 +126,7 @@ def test_prime_factors():
 
 @needs_8
 def test_factorized_mesh_shards_odd_shapes():
-    # the round-2 verdict case: (499, 450, 400) replicated under a 1-d 8-mesh
+    # the vorticity slice: (499, 450, 400) replicated under a 1-d 8-mesh
     # because no dim divides by 8; the factorized (2,2,2) placement shards it
     # 8-way across two dims
     from cubed_tpu.parallel.mesh import (

@@ -2,8 +2,8 @@
 sort entirely (.github/workflows/array-api-tests.yml skip list).
 
 The headline property: an axis LARGER than ``allowed_mem`` sorts, because
-every network task touches exactly two chunks (VERDICT r3 #8 closed the
-single-chunk-axis wall). The conformance suite additionally fuzzes the
+every network task touches exactly two chunks (no single-chunk-axis
+wall). The conformance suite additionally fuzzes the
 multi-chunk path against numpy across dtypes/shapes (chunks_for always
 splits axes, so sorting there goes through the network).
 """

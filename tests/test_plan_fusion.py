@@ -459,7 +459,7 @@ def test_fused_output_also_persisted(spec, tmp_path):
 
 
 def test_compute_dtype_f32_ingestion(spec):
-    """f32 ingestion (VERDICT r4 #4): an f64 plan executed with
+    """f32 ingestion: an f64 plan executed with
     ``compute_dtype="float32"`` computes on-device in single precision —
     including random generation — and casts back to the declared f64 at
     the store boundary, within f32 error bounds of the f64 result."""
@@ -571,3 +571,164 @@ def test_small_host_from_array_traces(spec):
     assert ex.stats["segments_traced"] == 1
     assert ex.stats["trace_failures"] == 0
     assert ex.stats["eager_fallbacks"] == 0
+
+
+# values a float-float device representation would change: all 53
+# significand bits, and exponents beyond float32's range
+_FULL_F64 = np.array(
+    [[np.pi, 1.0 + 2.0**-52, 1e300, 1e-300], [-np.e, 2.0**-1000, 1e40, 2.0**-1022]]
+    * 4
+)
+
+
+@pytest.mark.parametrize("mesh", [False, True])
+@pytest.mark.parametrize("fuse_plan", [True, False])
+def test_movement_only_compute_carries_float64_as_bits(
+    spec, tmp_path, monkeypatch, mesh, fuse_plan
+):
+    """On a device whose float64 does not survive a round trip (TPU v5e
+    holds it as a float32 pair), a compute that only moves values —
+    ``to_zarr(from_zarr(...).rechunk(...))`` — carries them through the
+    device as uint64 bit patterns, so the copy is bit for bit; a compute
+    with arithmetic is left to the device's float64."""
+    import cubed_tpu.runtime.executors.jax as jx
+    from cubed_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(jx, "_float64_round_trips", lambda device: False)
+    src, out = str(tmp_path / "src.zarr"), str(tmp_path / "out.zarr")
+    ct.to_zarr(ct.from_array(_FULL_F64, chunks=(4, 2), spec=spec), src)
+    a = ct.from_zarr(src, spec=spec)
+
+    def executor():
+        return JaxExecutor(
+            mesh=make_mesh() if mesh else None, fuse_plan=fuse_plan
+        )
+
+    ex = executor()
+    ct.to_zarr(a.rechunk((2, 4)), out, executor=ex)
+    assert ex.stats["f64_as_bits"] >= 1
+    assert not ex.stats.get("eager_fallbacks")
+    got = ct.from_zarr(out, spec=spec)
+    assert got.chunks == ((2, 2, 2, 2), (4,))
+    assert got.compute().tobytes() == _FULL_F64.tobytes()
+
+    ex = executor()
+    doubled = xp.add(a, a).compute(executor=ex)
+    assert not ex.stats.get("f64_as_bits")
+    assert not ex.stats.get("f64_lossy_moves")  # nothing here only moves
+    assert np.array_equal(doubled, _FULL_F64 + _FULL_F64)
+
+    # a copy that shares its compute with arithmetic is not carried as
+    # bits, and the counter says so
+    ex = executor()
+    out2 = str(tmp_path / "out2.zarr")
+    ct.store([a.rechunk((2, 4)), xp.add(a, a)], [out2, str(tmp_path / "sum.zarr")],
+             executor=ex)
+    assert not ex.stats.get("f64_as_bits")
+    assert ex.stats["f64_lossy_moves"] >= 1
+
+
+def _records(dtype):
+    rec = np.empty(_FULL_F64.shape, dtype=dtype)
+    rec["n"] = np.arange(_FULL_F64.size).reshape(_FULL_F64.shape)
+    rec["total"] = _FULL_F64
+    return rec
+
+
+@pytest.mark.parametrize("mesh", [False, True])
+def test_movement_only_compute_carries_float64_fields_of_records_as_bits(
+    spec, tmp_path, monkeypatch, mesh
+):
+    """A float64 field of a record array takes the same way in and out as a
+    plain float64 array: the dict-of-fields form goes through the same pair
+    of conversions, in ``_preload``/``_exec_rechunk`` and in ``_flush``."""
+    import cubed_tpu.runtime.executors.jax as jx
+    from cubed_tpu.parallel.mesh import make_mesh
+    from cubed_tpu.storage.store import open_zarr_array
+
+    monkeypatch.setattr(jx, "_float64_round_trips", lambda device: False)
+    rec = _records([("n", "<i8"), ("total", "<f8")])
+    src, out = str(tmp_path / "src.zarr"), str(tmp_path / "out.zarr")
+    stored = open_zarr_array(
+        src, mode="w", shape=rec.shape, dtype=rec.dtype, chunks=(4, 2)
+    )
+    stored[...] = rec
+    a = ct.from_zarr(src, spec=spec)
+
+    ex = JaxExecutor(mesh=make_mesh() if mesh else None)
+    ct.to_zarr(a.rechunk((2, 4)), out, executor=ex)
+    assert ex.stats["f64_as_bits"] >= 1
+    assert not ex.stats.get("eager_fallbacks")
+    got = open_zarr_array(out, mode="r")[...]
+    assert got.dtype == rec.dtype
+    assert got.tobytes() == rec.tobytes()
+
+
+def test_complex128_is_not_carried_as_bits(spec, tmp_path, monkeypatch):
+    """complex128 has no uint64 form of the same shape: a compute that holds
+    one stays in the device's representation and is counted as lossy."""
+    import cubed_tpu.runtime.executors.jax as jx
+
+    monkeypatch.setattr(jx, "_float64_round_trips", lambda device: False)
+    z = _FULL_F64[:, :2] + 1j * _FULL_F64[:, 2:]
+    src, out = str(tmp_path / "src.zarr"), str(tmp_path / "out.zarr")
+    ct.to_zarr(ct.from_array(z, chunks=(4, 2), spec=spec), src)
+
+    ex = JaxExecutor()
+    ct.to_zarr(ct.from_zarr(src, spec=spec).rechunk((2, 2)), out, executor=ex)
+    assert not ex.stats.get("f64_as_bits")
+    assert ex.stats["f64_lossy_moves"] >= 1
+    assert np.array_equal(ct.from_zarr(out, spec=spec).compute(), z)
+
+
+def test_float64_round_trip_is_observed_once_per_device_kind(monkeypatch):
+    import jax
+
+    import cubed_tpu.runtime.executors.jax as jx
+
+    monkeypatch.setattr(jx, "_FLOAT64_ROUND_TRIPS", {})
+    device = jax.devices()[0]
+    assert jx._float64_round_trips(device) is True  # the CPU holds real f64
+    assert jx._FLOAT64_ROUND_TRIPS == {(device.platform, device.device_kind): True}
+
+
+def test_budget_refuses_to_assume_a_limit_off_the_cpu():
+    class _Device:
+        platform = "tpu"
+
+        def memory_stats(self):
+            return None
+
+    ex = JaxExecutor()
+    ex._first_device = lambda: _Device()
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        ex._budget()
+    assert JaxExecutor(device_mem=123)._budget() == 123
+    assert JaxExecutor()._budget() == 8 * 2**30  # CPU devices report no limit
+
+
+def test_mesh_segment_with_only_virtual_inputs_is_partitioned(spec, monkeypatch):
+    """Under a mesh, a fused segment whose arrays are all generated inside it
+    (the vorticity pipeline: four random arrays, nothing preloaded with a
+    sharding) must still be compiled for the whole mesh — every array the
+    segment produces is pinned to the chunk-grid sharding — not for one
+    device of it."""
+    import jax
+
+    import cubed_tpu.runtime.executors.jax as jx
+    from cubed_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(jx, "_SEGMENT_CACHE", {})
+    monkeypatch.setattr(jx, "_STRUCT_CACHE", {})
+    a, b, x, y = (
+        cubed_tpu.random.random((20, 18, 16), chunks=4, spec=spec)
+        for _ in range(4)
+    )
+    expr = xp.mean(xp.add(xp.multiply(a[1:], x[1:]), xp.multiply(b[1:], y[1:])))
+    ex = JaxExecutor(mesh=make_mesh())
+    value = float(expr.compute(executor=ex))
+    assert 0.3 < value < 0.7
+    assert ex.stats["segments_traced"] == 1 and not ex.stats.get("eager_fallbacks")
+    ((compiled, _),) = jx._SEGMENT_CACHE.values()
+    (out_sharding,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert len(out_sharding.device_set) == len(jax.devices())

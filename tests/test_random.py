@@ -152,7 +152,7 @@ def test_auto_cpu_matches_numpy_philox_oracle(spec):
     the numpy-backend oracle's (and the reference's, cubed/random.py:
     13-36) stream, so cross-backend differential comparisons see
     identical values, and the CPU path gets numpy's generation rate
-    instead of XLA-CPU threefry (~20x slower, BENCH_PROFILE.md)."""
+    instead of XLA-CPU threefry."""
     _jax_backend_or_skip()
     import random as pyrandom
 
