@@ -1,0 +1,14 @@
+"""The benchmark's own tests run on the CPU with four virtual devices; the
+environment is pinned before jax is imported."""
+
+import os
+import sys
+
+flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in flags:
+    os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=4").strip()
+os.environ["JAX_PLATFORMS"] = "cpu"
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
