@@ -265,10 +265,15 @@ class Plan:
             resume = True
             kwargs["journal"] = load_journal(resume_from_journal)
 
+        # optimise and finalise run before on_compute_start, where no task
+        # scope reaches: timed here, reported as executor_stats'
+        # plan_finalize_s (0.0 for a plan handed in finalized)
+        finalize_started = time.perf_counter()
         if finalized is None:
             finalized = self._finalize(
                 optimize_graph, optimize_function, array_names
             )
+        plan_finalize_s = time.perf_counter() - finalize_started
         # else: a pre-finalized plan (the service's structural plan cache)
         # skips optimization + lazy-array creation entirely; the caller is
         # responsible for the fingerprint match that makes this sound
@@ -440,7 +445,7 @@ class Plan:
             # aggregator's own fold) are exact per compute either way.
             if cancel_token is not None:
                 cancel_mod.unregister_compute(compute_id)
-            stats: dict = {}
+            stats: dict = {"plan_finalize_s": plan_finalize_s}
             try:
                 executor_own = getattr(executor, "stats", None)
                 if executor_own:

@@ -31,7 +31,7 @@ _TIMELINE_GROUPS = {
     "retries": ("retry", "requeue", "backup", "task_failed", "pool_rebuild"),
     "integrity": ("recompute", "quarantine"),
     "memory guard": ("admission_step_down", "admission_restore",
-                     "guard_soft_exceeded", "device_memory"),
+                     "guard_soft_exceeded"),
     "stragglers": ("straggler",),
     "scheduling": ("scheduler_mode", "dataflow_graph", "dispatch_early"),
     # the control plane's connection lifecycle: partitions, reconnects,

@@ -9,8 +9,10 @@ every chunk transfer. Attribution rules:
   what makes the numbers survive process boundaries: multiprocess and
   distributed workers measure their own IO and the client aggregates it from
   ``TaskEndEvent``s.
-- Outside any task scope (the JAX executor's whole-array preloads/flushes,
-  plan-level metadata ops), bytes go straight to the process registry.
+- Outside any task scope (plan-level metadata ops, a read-back after the
+  executor has returned), bytes go straight to the process registry. The
+  JAX executor opens a scope around each segment, eager op and flush, so
+  its whole-array preloads and flushes ride its ``TaskEndEvent``s too.
 
 The two paths are exclusive by construction, so summing task-event bytes
 into the registry (``callback._ComputeAggregator``) never double-counts.
@@ -140,9 +142,16 @@ class TaskScope:
         "counters",
         "spans",
         "spans_dropped",
+        "max_spans",
+        "_open",
+        "_next_id",
     )
 
-    def __init__(self):
+    def __init__(self, max_spans: int = MAX_TASK_SPANS):
+        #: bound of the span buffer (``MAX_TASK_SPANS`` for a task whose
+        #: stats are shipped; the device executor's in-process scopes, each
+        #: a whole array's worth of chunk IO, take more room)
+        self.max_spans = max_spans
         self.bytes_read = 0
         self.bytes_written = 0
         self.chunks_read = 0
@@ -159,15 +168,28 @@ class TaskScope:
         #: merged trace (observability/collect.py)
         self.spans: list = []
         self.spans_dropped = 0
+        #: ids of the ``scope_span``s open right now, outermost first: a
+        #: span's parent is the one open when it was entered
+        self._open: list = []
+        self._next_id = 0
 
     def add_span(
-        self, name: str, start: float, end: float, cat: str = "span", **attrs
+        self, name: str, start: float, end: float, cat: str = "span",
+        span_id: Optional[int] = None, parent: Optional[int] = None, **attrs
     ) -> None:
-        if len(self.spans) >= MAX_TASK_SPANS:
+        """Buffer one finished span. ``span_id`` is unique within this
+        scope and ``parent`` is the id of the span that enclosed it, so a
+        reader gets self time as duration minus children."""
+        if len(self.spans) >= self.max_spans:
             self.spans_dropped += 1
             return
+        if span_id is None:
+            span_id = self._next_id
+            self._next_id += 1
         span = {"name": name, "ts": start, "dur": max(0.0, end - start),
-                "cat": cat}
+                "cat": cat, "id": span_id}
+        if parent is not None:
+            span["parent"] = parent
         if attrs:
             span["attrs"] = attrs
         self.spans.append(span)
@@ -195,11 +217,14 @@ class task_scope:
     count them twice.
     """
 
+    def __init__(self, max_spans: int = MAX_TASK_SPANS):
+        self._max_spans = max_spans
+
     def __enter__(self) -> TaskScope:
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
-        self._scope = TaskScope()
+        self._scope = TaskScope(self._max_spans)
         stack.append(self._scope)
         return self._scope
 
@@ -223,9 +248,19 @@ class scope_span:
     results measured inside the block (byte counts, retry counts). A block
     that raises still records its span, closed at the raise instant with
     ``error=True`` — failures are when the trace matters most.
+
+    A recording span knows the span of the same scope that encloses it
+    (``parent``), and is also entered as a ``jax.profiler.TraceAnnotation``
+    named ``cubed:<name>``: a profiler session running at the time (the
+    ``JaxProfilerCallback``, a benchmark's traced run) then holds the
+    host's phases on the same clock as the device's operations. Without a
+    session the annotation is a sub-microsecond no-op.
     """
 
-    __slots__ = ("name", "cat", "attrs", "_scope", "_start")
+    __slots__ = (
+        "name", "cat", "attrs", "_scope", "_start", "_id", "_parent",
+        "_annotation",
+    )
 
     def __init__(self, name: str, cat: str = "span", **attrs):
         self.name = name
@@ -233,9 +268,21 @@ class scope_span:
         self.attrs = attrs
         self._scope: Optional[TaskScope] = None
 
+    @property
+    def recording(self) -> bool:
+        """Whether this (entered) span records: work that exists only to
+        be measured (a device sync ahead of a fetch) is guarded by it."""
+        return self._scope is not None
+
     def __enter__(self) -> "scope_span":
-        self._scope = current_scope() if spans_enabled() else None
-        if self._scope is not None:
+        scope = self._scope = current_scope() if spans_enabled() else None
+        if scope is not None:
+            self._id = scope._next_id
+            scope._next_id += 1
+            self._parent = scope._open[-1] if scope._open else None
+            scope._open.append(self._id)
+            self._annotation = _trace_annotation(ANNOTATION_PREFIX + self.name)
+            self._annotation.__enter__()
             self._start = clock.now()
         return self
 
@@ -243,12 +290,35 @@ class scope_span:
         scope = self._scope
         if scope is None:
             return
+        end = clock.now()
+        self._annotation.__exit__(None, None, None)
+        scope._open.pop()
         if exc_type is not None:
             self.attrs["error"] = True
             self.attrs["error_type"] = exc_type.__name__
         scope.add_span(
-            self.name, self._start, clock.now(), cat=self.cat, **self.attrs
+            self.name, self._start, end, cat=self.cat,
+            span_id=self._id, parent=self._parent, **self.attrs
         )
+
+
+#: what every annotation ``scope_span`` writes into a profiler trace starts
+#: with, so that a reader of the trace finds the program's spans again
+ANNOTATION_PREFIX = "cubed:"
+
+_TraceAnnotation = None
+
+
+def _trace_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``; jax is imported on first use,
+    which only a process with spans armed ever reaches."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
 
 
 def _track_store(store: str, read: int, written: int) -> None:

@@ -25,8 +25,8 @@ questions an operator actually asks:
   op-level dependency skeleton, and decomposes the wall clock into
   attribution buckets::
 
-      kernel | storage_read | storage_write | peer_fetch | shuffle
-      | retry | ready_wait | dispatch_overhead | queue_wait
+      kernel | transfer | storage_read | storage_write | peer_fetch
+      | shuffle | retry | ready_wait | dispatch_overhead | queue_wait
       | straggler_excess | uninstrumented | other
 
   The decomposition is exact by construction (segments tile the
@@ -82,6 +82,22 @@ SPAN_BUCKETS = {
     # write so "the store was slow" and "the store told us to slow down"
     # are distinguishable in the attribution
     "throttle_wait": "throttle_wait",
+    # the device executor (runtime/executors/jax.py): host<->device copies
+    # are ``transfer``; the host's wait for the device ahead of a fetch is
+    # the kernel running; fingerprinting, tracing, compiling and enqueueing
+    # a segment program are the executor's own dispatch cost. A span that
+    # encloses another bucketed span (``jax.h2d`` around the store's reads
+    # under a mesh, ``storage_write`` around ``fsync``) counts its self
+    # time only, so nothing is counted twice
+    "jax.h2d": "transfer",
+    "jax.d2h": "transfer",
+    "jax.device_wait": "kernel",
+    "jax.struct_key": "dispatch_overhead",
+    "jax.trace_lower": "dispatch_overhead",
+    "jax.compile": "dispatch_overhead",
+    "jax.dispatch": "dispatch_overhead",
+    "chunk_encode": "storage_write",
+    "fsync": "storage_write",
 }
 
 #: every attribution bucket, in render order. ``ready_wait`` /
@@ -90,7 +106,7 @@ SPAN_BUCKETS = {
 #: tasks that shipped no dispatch ledger (old traces, local executors
 #: without stamps)
 BUCKETS = (
-    "kernel", "storage_read", "storage_write", "peer_fetch", "shuffle",
+    "kernel", "transfer", "storage_read", "storage_write", "peer_fetch", "shuffle",
     "retry", "throttle_wait", "ready_wait", "dispatch_overhead",
     "queue_wait", "straggler_excess", "uninstrumented", "other",
 )
@@ -481,7 +497,7 @@ def _trace_tables(trace: dict) -> tuple:
             })
         elif cat in (
             "storage", "kernel", "integrity", "retry", "transfer",
-            "repair", "span",
+            "repair", "dispatch", "span",
         ):
             spans.append({
                 "name": e.get("name"),
@@ -489,6 +505,11 @@ def _trace_tables(trace: dict) -> tuple:
                 "end": end,
                 "tid": e.get("tid"),
                 "chunk": args.get("chunk_of_task"),
+                # the span's id within its task and its enclosing span's
+                # (absent in traces older than the ids: self time is then
+                # the whole duration)
+                "id": args.get("span_id"),
+                "parent": args.get("parent_id"),
             })
     if compute_bounds is None and tasks:
         compute_bounds = (
@@ -500,7 +521,10 @@ def _trace_tables(trace: dict) -> tuple:
 def _attach_spans(tasks: List[dict], spans: List[dict]) -> None:
     """Associate sub-spans with their task record: same lane (tid), the
     task's chunk key, and time containment (small epsilon for clock
-    granularity). Each task gains a ``"spans"`` list."""
+    granularity). Each task gains a ``"spans"`` list. A span that no task
+    contains (the device executor runs a fused segment as one scope and
+    apportions its wall time over the member ops' task records) is cut at
+    the boundaries of the tasks it overlaps, a piece to each."""
     eps = 2e-3
     index: Dict[tuple, List[dict]] = {}
     for t in tasks:
@@ -517,6 +541,11 @@ def _attach_spans(tasks: List[dict], spans: List[dict]) -> None:
                     best = t  # smallest containing task (retried chunks)
         if best is not None:
             best["spans"].append(s)
+            continue
+        for t in candidates:
+            lo, hi = max(s["start"], t["start"]), min(s["end"], t["end"])
+            if hi > lo:
+                t["spans"].append(dict(s, start=lo, end=hi))
 
 
 def _op_medians(tasks: List[dict]) -> Dict[str, float]:
@@ -542,13 +571,24 @@ def _is_straggler(t: dict, medians: Dict[str, float]) -> bool:
 
 def _interior_buckets(t: dict) -> Dict[str, float]:
     """A task's instrumented interior: seconds per bucket from its
-    sub-spans, clipped so their total never exceeds the task duration."""
+    sub-spans' self times (duration less the bucketed spans directly
+    inside), clipped so their total never exceeds the task duration."""
+    spans = [
+        s for s in t.get("spans") or [] if s["name"] in SPAN_BUCKETS
+    ]
+    inside: Dict[Any, float] = {}
+    for s in spans:
+        if s.get("parent") is not None:
+            inside[s["parent"]] = (
+                inside.get(s["parent"], 0.0) + s["end"] - s["start"]
+            )
     out: Dict[str, float] = {}
-    for s in t.get("spans") or []:
-        bucket = SPAN_BUCKETS.get(s["name"])
-        if bucket is None:
-            continue
-        out[bucket] = out.get(bucket, 0.0) + max(0.0, s["end"] - s["start"])
+    for s in spans:
+        bucket = SPAN_BUCKETS[s["name"]]
+        own = s["end"] - s["start"]
+        if s.get("id") is not None:
+            own -= inside.get(s["id"], 0.0)
+        out[bucket] = out.get(bucket, 0.0) + max(0.0, own)
     total = sum(out.values())
     if total > t["dur"] > 0:
         scale = t["dur"] / total

@@ -170,6 +170,12 @@ class _ComputeAggregator(EventLogCallback):
         #: per-task number, so comparing it against projected_mem is
         #: meaningful
         self._guard_peaks: dict[str, int] = {}
+        #: totals of the spans that task events carried, by span name:
+        #: seconds, seconds less what child spans cover, and calls
+        self._span_s: dict[str, float] = {}
+        self._span_self_s: dict[str, float] = {}
+        self._span_n: dict[str, int] = {}
+        self._spans_dropped = 0
 
     # note: no on_task_start override — the tasks_started counter lives in
     # runtime.utils.fire_task_start, so executors can skip building start
@@ -212,6 +218,27 @@ class _ComputeAggregator(EventLogCallback):
             self._guard_peaks[name] = max(
                 self._guard_peaks.get(name, 0), event.guard_mem_peak
             )
+        if event.spans:
+            self._fold_spans(event.spans)
+        if event.spans_dropped:
+            self._spans_dropped += event.spans_dropped
+
+    def _fold_spans(self, spans: list) -> None:
+        """Add one task's spans to the per-name totals. A span's ``parent``
+        is the ``id`` of the span of the same task that enclosed it, so
+        self time is duration minus the children's."""
+        children: dict = {}
+        for s in spans:
+            parent = s.get("parent")
+            if parent is not None:
+                children[parent] = children.get(parent, 0.0) + s["dur"]
+        for s in spans:
+            name, dur = s["name"], s["dur"]
+            self._span_s[name] = self._span_s.get(name, 0.0) + dur
+            self._span_self_s[name] = self._span_self_s.get(name, 0.0) + max(
+                0.0, dur - children.get(s.get("id"), 0.0)
+            )
+            self._span_n[name] = self._span_n.get(name, 0) + 1
 
     def peak_measured_mem_by_op(self) -> dict[str, int]:
         # the base class derives this from retained events; we keep it live
@@ -227,7 +254,10 @@ class _ComputeAggregator(EventLogCallback):
 
     def summary(self) -> dict:
         """The ``per_op`` block for ``executor_stats``: one row per op that
-        ran, joining event-stream aggregates with the plan projections."""
+        ran, joining event-stream aggregates with the plan projections.
+        Where task events carried spans (recording armed), also their
+        totals by name: ``span_s``, ``span_self_s``, ``span_n`` and
+        ``spans_dropped``."""
         rows = {r["array_name"]: r for r in self.projected_vs_measured()}
         per_op = {}
         for name, timing in self.op_timings.items():
@@ -252,7 +282,15 @@ class _ComputeAggregator(EventLogCallback):
                     and guard_peak > projected + _MEM_OVER_NOISE_FLOOR
                 ),
             }
-        return {"per_op": per_op} if per_op else {}
+        out: dict = {"per_op": per_op} if per_op else {}
+        if self._span_n or self._spans_dropped:
+            out.update(
+                span_s=dict(self._span_s),
+                span_self_s=dict(self._span_self_s),
+                span_n=dict(self._span_n),
+                spans_dropped=self._spans_dropped,
+            )
+        return out
 
     def on_compute_end(self, event) -> None:
         super().on_compute_end(event)

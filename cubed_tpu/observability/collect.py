@@ -582,6 +582,10 @@ class TraceCollector(EventLogCallback):
             for s in rec["spans"]:
                 attrs = dict(s.get("attrs") or {})
                 attrs["chunk_of_task"] = rec["chunk"]
+                if s.get("id") is not None:
+                    attrs["span_id"] = s["id"]
+                if s.get("parent") is not None:
+                    attrs["parent_id"] = s["parent"]
                 tr.add_complete(
                     s["name"], s["ts"] + off, s["ts"] + s["dur"] + off,
                     lane=lane, cat=s.get("cat", "span"), **attrs,

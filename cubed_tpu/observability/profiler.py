@@ -1,13 +1,22 @@
-"""**Device** profiler: JAX device traces + per-op device memory, folded
-into the span pipeline.
+"""**Device** profiler: a JAX profiler trace of one compute.
 
-``JaxProfilerCallback`` brackets a compute in ``jax.profiler.trace`` (xprof
-traces for TensorBoard/XProf) and ``DeviceMemoryCallback`` snapshots device
-memory watermarks per op — the HBM analogue of the host RSS the memory
-guard samples. Both now feed the unified pipeline: profiler start/stop and
-each device-memory snapshot are recorded as :func:`collect.record_decision`
-entries, so they appear on the ``scheduler`` lane of the merged trace and
-inside flight-recorder bundles next to the host-side story.
+``JaxProfilerCallback`` brackets a compute in ``jax.profiler.start_trace``
+/ ``stop_trace`` (an ``.xplane.pb`` for TensorBoard/XProf). Together with
+the span pipeline it is the operator's joint timeline: every recording
+``scope_span`` is also a ``cubed:<name>`` trace annotation
+(``observability/accounting.py``), so with spans armed (a ``TraceCollector``
+attached, or ``CUBED_TPU_TASK_SPANS=1``) the trace holds the host's phases
+(the device executor's preload, dispatch, device wait and fetch, the store's
+reads, writes and fsyncs) on the same clock as the device's operations,
+and the operations of a fused segment carry the plan's op in their metadata
+(``op00.blockwise...``). Start and stop are recorded as
+:func:`collect.record_decision` entries, so they appear on the
+``scheduler`` lane of the merged trace and inside flight-recorder bundles.
+
+Device memory is not sampled here: ``peak_bytes_in_use`` does not see a
+program's temporaries on this runtime; the executor's
+``segment_hbm_footprint`` (XLA's own accounting of the compiled segment) in
+``executor_stats`` is the number to read.
 
 Not to be confused with ``observability/dispatchprofile.py`` — the
 **dispatch** profiler, which samples the host-side control-plane threads
@@ -15,14 +24,9 @@ Not to be confused with ``observability/dispatchprofile.py`` — the
 profiles what the *devices* do; that one profiles what the *coordinator*
 does. See docs/observability.md "Device profiler" vs "Control-plane
 observability".
-
-``cubed_tpu.extensions.profiler`` re-exports these classes unchanged (the
-historical import path keeps working).
 """
 
 from __future__ import annotations
-
-from typing import Optional
 
 from ..runtime.types import Callback
 from .collect import record_decision
@@ -52,25 +56,3 @@ class JaxProfilerCallback(Callback):
             jax.profiler.stop_trace()
             self._active = False
             record_decision("jax_profiler_stop", log_dir=self.log_dir)
-
-
-class DeviceMemoryCallback(Callback):
-    """Record per-op device memory watermarks (HBM analogue of peak RSS)."""
-
-    def __init__(self):
-        self.samples: list[dict] = []
-
-    def on_operation_start(self, event) -> None:
-        import jax
-
-        try:
-            stats = jax.devices()[0].memory_stats() or {}
-        except Exception:
-            stats = {}
-        sample = {
-            "op": event.name,
-            "bytes_in_use": stats.get("bytes_in_use"),
-            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
-        }
-        self.samples.append(sample)
-        record_decision("device_memory", **sample)

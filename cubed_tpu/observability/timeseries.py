@@ -386,8 +386,9 @@ class ComputeProgressCallback(Callback):
             return
         # some executors (jax) emit ONE event covering an op's whole task
         # batch — num_tasks carries the real count (cf. the metrics
-        # callback's tasks_completed fold)
-        n = getattr(event, "num_tasks", 1) or 1
+        # callback's tasks_completed fold), and one of zero tasks to carry
+        # a flush's IO and spans
+        n = getattr(event, "num_tasks", 1)
         with _computes_lock:
             row = _computes.get(cid)
             if row is not None:

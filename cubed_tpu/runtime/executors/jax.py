@@ -44,6 +44,14 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   frontier for the chunk-granular scheduler to overlap — XLA's own
   scheduler already overlaps at the instruction level inside each program
   (``runtime/dataflow.py`` is the multi-host fleet's analogue).
+- **Spans.** Each segment (with its preloads), eager op and flush runs in a
+  task scope of the span pipeline (``observability/accounting.py``) and
+  times its phases with ``scope_span`` (``jax.preload``, ``jax.h2d``,
+  ``jax.struct_key``, ``jax.trace_lower``, ``jax.compile``,
+  ``jax.dispatch``, ``jax.flush``, ``jax.device_wait``, ``jax.d2h``): the
+  phases' only clock (the task events keep their timestamps), a no-op unless a ``TraceCollector`` is attached or
+  ``CUBED_TPU_TASK_SPANS=1`` (docs/observability.md, "Device executor
+  spans").
 
 Reference parity: replaces cubed's serverless executors
 (cubed/runtime/executors/*) with a device-mesh substrate.
@@ -66,6 +74,7 @@ from ...chunks import blockdims_from_blockshape
 from ...primitive.blockwise import BlockwiseSpec, apply_blockwise
 from ...primitive.rechunk import copy_read_to_write
 from ...core.plan import create_zarr_array
+from ...observability.accounting import scope_span, spans_enabled, task_scope
 from ...storage.store import ZarrV2Array
 from ...storage.virtual import (
     VirtualEmptyArray,
@@ -216,6 +225,9 @@ class JaxExecutor(DagExecutor):
         #: (decided per compute in _execute_dag_inner)
         self._carry_bits = False
         self._prepared_bases: Dict[int, Any] = {}
+        #: keys of the task events of this compute in the order they were
+        #: fired, kept only while spans are recorded (see ``_task_end``)
+        self._task_order: Optional[list] = None
         self._placement = None  # factorized placement mesh, built lazily
         #: execution-path counters for the last ``execute_dag`` call, reported
         #: via ``ComputeEndEvent.executor_stats``. Keys: ``segments_traced``,
@@ -225,7 +237,10 @@ class JaxExecutor(DagExecutor):
         #: ``chunked_ops``, ``rechunk_alias`` (zero-copy), ``rechunk_virtual``
         #: (materialized), ``eager_ops``, ``f64_as_bits`` (float64 arrays
         #: moved to the device as bit patterns), ``f64_lossy_moves`` (copies
-        #: of 64-bit floats through a device float64 that is not one), and the
+        #: of 64-bit floats through a device float64 that is not one),
+        #: ``host_syncs`` (fetches in ``_to_host``, each of which blocks on the
+        #: device), ``h2d_bytes`` / ``d2h_bytes`` (bytes moved by ``_device_put``
+        #: / ``_to_host``), and the
         #: failure counters ``eager_fallbacks`` / ``trace_failures`` /
         #: ``whole_array_errors`` / ``batched_errors`` / ``whole_select_errors``
         #: / ``jit_kernel_errors``
@@ -405,18 +420,23 @@ class JaxExecutor(DagExecutor):
 
         def transferred(data):
             data = np.asarray(data)
+            self.stats["h2d_bytes"] += data.nbytes
             return data.view(np.uint64) if as_bits else data
 
         sharding = self._sharding_for(shape, chunkset)
-        if sharding is None:
+        if sharding is None and stored:
+            value = value[...] if value.shape else value[()]
+            stored = False
+        with scope_span("jax.h2d", cat="transfer") as sp:
             if stored:
-                value = value[...] if value.shape else value[()]
-            return jax.device_put(transferred(value))
-        if stored:
-            return jax.make_array_from_callback(
-                tuple(shape), sharding, lambda idx: transferred(value[idx])
-            )
-        return jax.device_put(transferred(value), sharding)
+                # the store's reads happen inside, shard by shard
+                out = jax.make_array_from_callback(
+                    tuple(shape), sharding, lambda idx: transferred(value[idx])
+                )
+            else:
+                out = jax.device_put(transferred(value), sharding)
+            sp.attrs["bytes"] = _value_nbytes(out)
+        return out
 
     def _to_host(self, value, dtype) -> np.ndarray:
         """Device -> host: a device value (or dict of record fields) as a
@@ -427,9 +447,19 @@ class JaxExecutor(DagExecutor):
             for k, field in fields.items():
                 rec[k] = field
             return rec
-        host = np.asarray(value)
-        if self._carry_bits and host.dtype == np.uint64 and dtype == np.float64:
-            host = host.view(np.float64)
+        self.stats["host_syncs"] += 1
+        with scope_span("jax.device_wait", cat="kernel") as sp:
+            # only a recording span waits here: it keeps the execution that
+            # produces the value out of the fetch's time. Unobserved, the
+            # fetch below is the one synchronisation
+            if sp.recording:
+                _jax().block_until_ready(value)
+        with scope_span("jax.d2h", cat="transfer") as sp:
+            host = np.asarray(value)
+            if self._carry_bits and host.dtype == np.uint64 and dtype == np.float64:
+                host = host.view(np.float64)
+            sp.attrs["bytes"] = host.nbytes
+        self.stats["d2h_bytes"] += host.nbytes
         return host
 
     # ------------------------------------------------------------------
@@ -493,6 +523,7 @@ class JaxExecutor(DagExecutor):
     ) -> None:
         jax = _jax()
         self.stats = Counter()
+        self._task_order = [] if spans_enabled() else None
         resident: Dict[str, _Resident] = {}
         budget = self._budget()
         self._carry_bits = False
@@ -538,7 +569,9 @@ class JaxExecutor(DagExecutor):
             # observe-only guard (see _run_segment): measure, never enforce
             from ..memory import task_guard
 
-            with task_guard(f"eager:{name}", observe_only=True) as guard:
+            with task_scope(_SCOPE_SPANS) as scope, task_guard(
+                f"eager:{name}", observe_only=True
+            ) as guard:
                 if pipeline.function is apply_blockwise:
                     self._exec_blockwise(primitive_op, resident, budget)
                 elif pipeline.function is copy_read_to_write:
@@ -553,18 +586,16 @@ class JaxExecutor(DagExecutor):
                     for m in pipeline.mappable:
                         pipeline.function(m, config=pipeline.config)
             t1 = time.time()
-            callbacks_on(
-                callbacks, "on_task_end",
-                TaskEndEvent(
-                    array_name=name,
-                    num_tasks=primitive_op.num_tasks,
-                    task_create_tstamp=t0,
-                    function_start_tstamp=t0,
-                    function_end_tstamp=t1,
-                    task_result_tstamp=t1,
-                    executor=self.name,
-                    guard_mem_peak=guard.measured,
-                ),
+            self._task_end(
+                callbacks,
+                array_name=name,
+                num_tasks=primitive_op.num_tasks,
+                task_create_tstamp=t0,
+                function_start_tstamp=t0,
+                function_end_tstamp=t1,
+                task_result_tstamp=t1,
+                guard_mem_peak=guard.measured,
+                **scope.stats(),
             )
             callbacks_on(
                 callbacks, "on_operation_end",
@@ -598,10 +629,54 @@ class JaxExecutor(DagExecutor):
                 run_eager(name, node)
         run_segment()
 
-        # flush requested outputs that are still resident
+        # flush requested outputs that are still resident. Each flush is a
+        # unit of work with IO and spans of its own but no task of the plan:
+        # they ride an event of zero tasks in the name of the op that
+        # produced the array
+        producers = {
+            str(node_targets[arr].store): op
+            for op, arr in dag.edges()
+            if arr in node_targets and hasattr(node_targets[arr], "store")
+            and dag.nodes[op].get("primitive_op") is not None
+        }
         for store, res in list(resident.items()):
             if store in requested_stores:
-                self._flush(res)
+                t0 = time.time()
+                with task_scope(_SCOPE_SPANS) as scope:
+                    self._flush(res)
+                t1 = time.time()
+                self._task_end(
+                    callbacks,
+                    array_name=producers.get(store, store),
+                    num_tasks=0,
+                    chunk_key=_FLUSH_KEY,
+                    task_create_tstamp=t0,
+                    function_start_tstamp=t0,
+                    function_end_tstamp=t1,
+                    task_result_tstamp=t1,
+                    **scope.stats(),
+                )
+        if self._task_order:
+            # the dependency edges of this compute's tasks, for the critical
+            # path of ``analytics.analyze`` (as the dataflow scheduler hands
+            # over its chunk graph)
+            from ...observability.collect import record_chunk_graph
+
+            order = self._task_order
+            record_chunk_graph(
+                {key: [gate] for gate, key in zip(order, order[1:])}
+                | {order[0]: []}
+            )
+
+    def _task_end(self, callbacks, **fields) -> None:
+        """Fire one ``TaskEndEvent``. While spans are recorded its key is
+        kept too: this executor runs its units one after another on one
+        host thread, so each task is gated by the one before it, and
+        ``_execute_dag_inner`` hands that chain to the trace analysis."""
+        event = TaskEndEvent(executor=self.name, **fields)
+        if self._task_order is not None:
+            self._task_order.append(f"{event.array_name}\t{event.chunk_key}")
+        callbacks_on(callbacks, "on_task_end", event)
 
     # ------------------------------------------------------------------
     # plan fusion: trace runs of ops into ONE jitted XLA program
@@ -688,8 +763,9 @@ class JaxExecutor(DagExecutor):
             if concrete.shape and getattr(concrete, "chunks", None)
             else None
         )
-        value = self._device_put(concrete, tuple(concrete.shape), cs)
-        self._admit(resident, key, value, arr, budget)
+        with scope_span("jax.preload", bytes=nbytes):
+            value = self._device_put(concrete, tuple(concrete.shape), cs)
+            self._admit(resident, key, value, arr, budget)
         return True
 
     def _segment_keep(self, ops, dag, requested_stores) -> Dict[str, Any]:
@@ -729,7 +805,9 @@ class JaxExecutor(DagExecutor):
         from ..memory import task_guard
 
         seg_key = ",".join(name for name, _ in ops)
-        with task_guard(f"segment:{seg_key}", observe_only=True) as guard:
+        with task_scope(_SCOPE_SPANS) as scope, task_guard(
+            f"segment:{seg_key}", observe_only=True
+        ) as guard:
             traced = False
             if len(ops) > 0:
                 try:
@@ -770,31 +848,33 @@ class JaxExecutor(DagExecutor):
         total_tasks = sum(node["primitive_op"].num_tasks for _, node in ops) or 1
         elapsed = t1 - t0
         start = t0
+        # what the segment's scope measured (preload and spill IO, spans)
+        # rides the first member op's event alone: once, not per op
+        carried = scope.stats()
         for name, node in ops:
             num_tasks = node["primitive_op"].num_tasks
             end = start + elapsed * (num_tasks / total_tasks)
-            callbacks_on(
-                callbacks, "on_task_end",
-                TaskEndEvent(
-                    array_name=name,
-                    num_tasks=num_tasks,
-                    task_create_tstamp=start,
-                    function_start_tstamp=start,
-                    function_end_tstamp=end,
-                    task_result_tstamp=end,
-                    executor=self.name,
-                    # the guard measured the WHOLE segment: attributing
-                    # that aggregate to each member op would flag
-                    # correctly-modelled ops as over-projected, so per-op
-                    # attribution only exists for single-op segments
-                    guard_mem_peak=guard.measured if len(ops) == 1 else None,
-                ),
+            self._task_end(
+                callbacks,
+                array_name=name,
+                num_tasks=num_tasks,
+                task_create_tstamp=start,
+                function_start_tstamp=start,
+                function_end_tstamp=end,
+                task_result_tstamp=end,
+                # the guard measured the WHOLE segment: attributing
+                # that aggregate to each member op would flag
+                # correctly-modelled ops as over-projected, so per-op
+                # attribution only exists for single-op segments
+                guard_mem_peak=guard.measured if len(ops) == 1 else None,
+                **carried,
             )
             callbacks_on(
                 callbacks, "on_operation_end",
                 OperationEndEvent(name, num_tasks),
             )
             start = end
+            carried = {}
 
     def _structural_key(
         self, ops, dag, in_keys, resident, keep_list, seeded
@@ -1046,7 +1126,10 @@ class JaxExecutor(DagExecutor):
         # structural fast path: a repeat compute of an identical plan shape
         # reuses the compiled program WITHOUT re-tracing (the dominant warm
         # cost); store paths/seeds are re-bound positionally
-        skey = self._structural_key(ops, dag, in_keys, resident, keep_list, seeded)
+        with scope_span("jax.struct_key", cat="dispatch"):
+            skey = self._structural_key(
+                ops, dag, in_keys, resident, keep_list, seeded
+            )
         with _CACHE_LOCK:
             cached_struct = (
                 _STRUCT_CACHE.get(skey) if skey is not None else None
@@ -1054,15 +1137,38 @@ class JaxExecutor(DagExecutor):
         if cached_struct is not None:
             compiled, footprint = cached_struct
             self.stats["segment_struct_hits"] += 1
-            if footprint:
-                self.stats["segment_hbm_footprint"] = max(
-                    self.stats.get("segment_hbm_footprint", 0), footprint
-                )
+        else:
+            compiled, footprint = self._lower_and_compile(
+                ops, resident, in_keys, in_vals, seeded, base_vals, keep,
+                keep_list,
+            )
+            if skey is not None:
+                with _CACHE_LOCK:
+                    if len(_STRUCT_CACHE) >= 64:
+                        _STRUCT_CACHE.pop(next(iter(_STRUCT_CACHE)))
+                    _STRUCT_CACHE[skey] = (compiled, footprint)
+        if footprint:
+            self.stats["segment_hbm_footprint"] = max(
+                self.stats.get("segment_hbm_footprint", 0), footprint
+            )
+        with scope_span(
+            "jax.dispatch", cat="dispatch",
+            struct_hit=cached_struct is not None,
+        ):
+            # returns when the program is enqueued, not when it has run
             outs = compiled(in_vals, base_vals)
             for store, value in zip(keep_list, outs):
                 self._admit(resident, store, value, keep[store], budget)
-            return True
+        return True
 
+    def _lower_and_compile(
+        self, ops, resident, in_keys, in_vals, seeded, base_vals, keep,
+        keep_list,
+    ):
+        """Trace and lower the segment's ops as one function, and compile
+        it unless a program of the same HLO is cached: (compiled, HBM
+        footprint). The structural miss of ``_trace_segment``."""
+        jax = _jax()
         targets = {k: resident[k].target for k in in_keys}
 
         def seg_fn(vals, bases):
@@ -1074,16 +1180,18 @@ class JaxExecutor(DagExecutor):
                 id(arr): b for arr, b in zip(seeded, bases)
             }
             try:
-                for _, node in ops:
+                for position, (_, node) in enumerate(ops):
                     primitive_op = node["primitive_op"]
-                    if primitive_op.pipeline.function is apply_blockwise:
-                        self._exec_blockwise(
-                            primitive_op, local, budget=float("inf")
-                        )
-                    else:
-                        self._exec_rechunk(
-                            primitive_op, local, budget=float("inf")
-                        )
+                    # the plan's op in the metadata of its device operations
+                    with jax.named_scope(_op_scope_name(position, primitive_op)):
+                        if primitive_op.pipeline.function is apply_blockwise:
+                            self._exec_blockwise(
+                                primitive_op, local, budget=float("inf")
+                            )
+                        else:
+                            self._exec_rechunk(
+                                primitive_op, local, budget=float("inf")
+                            )
             finally:
                 self._tracing = False
                 self._prepared_bases = {}
@@ -1092,7 +1200,8 @@ class JaxExecutor(DagExecutor):
                 for k in keep_list
             ]
 
-        lowered = jax.jit(seg_fn).lower(in_vals, base_vals)
+        with scope_span("jax.trace_lower", cat="dispatch"):
+            lowered = jax.jit(seg_fn).lower(in_vals, base_vals)
         try:
             import hashlib
 
@@ -1111,7 +1220,8 @@ class JaxExecutor(DagExecutor):
         with _CACHE_LOCK:
             cached = _SEGMENT_CACHE.get(key) if key is not None else None
         if cached is None:
-            compiled = lowered.compile()
+            with scope_span("jax.compile", cat="dispatch"):
+                compiled = lowered.compile()
             self.stats["segments_compiled"] += 1
             footprint = _hbm_footprint(compiled)
             if key is not None:
@@ -1122,19 +1232,7 @@ class JaxExecutor(DagExecutor):
         else:
             compiled, footprint = cached
             self.stats["segment_cache_hits"] += 1
-        if footprint:
-            self.stats["segment_hbm_footprint"] = max(
-                self.stats.get("segment_hbm_footprint", 0), footprint
-            )
-        if skey is not None:
-            with _CACHE_LOCK:
-                if len(_STRUCT_CACHE) >= 64:
-                    _STRUCT_CACHE.pop(next(iter(_STRUCT_CACHE)))
-                _STRUCT_CACHE[skey] = (compiled, footprint)
-        outs = compiled(in_vals, base_vals)
-        for store, value in zip(keep_list, outs):
-            self._admit(resident, store, value, keep[store], budget)
-        return True
+        return compiled, footprint
 
     # ------------------------------------------------------------------
     # blockwise
@@ -1869,11 +1967,15 @@ class JaxExecutor(DagExecutor):
             concrete = target
         else:
             return
-        value = res.value
+        with scope_span("jax.flush", bytes=res.nbytes) as sp:
+            sp.attrs["chunks"] = self._flush_chunks(res.value, concrete)
+
+    def _flush_chunks(self, value, concrete) -> int:
+        """``_flush``'s fetches and writes; the number of chunks written."""
         shape = tuple(concrete.shape)
         if not shape:
             concrete[()] = self._to_host(value, concrete.dtype)
-            return
+            return 1
         chunkset = blockdims_from_blockshape(shape, concrete.chunks)
         coords_iter = itertools.product(*(range(len(c)) for c in chunkset))
         sharding = getattr(value, "sharding", None)
@@ -1906,6 +2008,7 @@ class JaxExecutor(DagExecutor):
                         "(parallel.mesh.sharding_for_chunks prefers one)"
                     )
             coords_iter = iter(mine)
+        chunks = 0
         for idx in coords_iter:
             sel = get_item(chunkset, idx)
             # the device slice is not bound to a name here: it would stay
@@ -1916,6 +2019,31 @@ class JaxExecutor(DagExecutor):
                 else value[sel],
                 concrete.dtype,
             )
+            chunks += 1
+        return chunks
+
+
+#: the ``chunk_key`` of the event that carries a flush's IO and spans
+_FLUSH_KEY = "flush"
+
+#: span buffer of one of this executor's task scopes. A scope here is a whole
+#: segment with its preloads, or the flush of a whole array: a few spans a
+#: chunk, in-process, shipped nowhere. Beyond it spans drop, with a count
+_SCOPE_SPANS = 4096
+
+
+def _op_scope_name(position: int, primitive_op) -> str:
+    """The ``jax.named_scope`` of one op of a traced segment. Structural
+    (its place in the segment, its kind, its kernel's name), never a store
+    path nor a gensym'd array name: the compiled program is shared by every
+    compute of the same plan shape."""
+    pipeline = primitive_op.pipeline
+    if pipeline.function is not apply_blockwise:
+        return f"op{position:02d}.rechunk"
+    kernel = getattr(pipeline.config.function, "__name__", None)
+    if not kernel or not kernel.isidentifier():  # a lambda, a partial
+        return f"op{position:02d}.blockwise"
+    return f"op{position:02d}.blockwise.{kernel}"
 
 
 #: in-process cache of (compiled segment program, HBM footprint) keyed by the

@@ -53,7 +53,10 @@ class TaskStartEvent:
 
 @dataclass
 class TaskEndEvent:
-    """Metrics for a completed task."""
+    """Metrics for a completed task (or ``num_tasks`` tasks of one op that
+    ran as one). An event of zero tasks completes none: it carries the IO
+    and spans of work done in an op's name outside its tasks (the device
+    executor's flush of the op's array)."""
 
     array_name: str
     num_tasks: int = 1

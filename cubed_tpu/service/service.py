@@ -435,7 +435,10 @@ class _CostTracker:
         self.tasks = 0
 
     def on_task_end(self, event) -> None:
-        self.tasks += 1
+        if getattr(event, "num_tasks", 1):
+            # not an event of zero tasks, which the device executor sends
+            # to carry a flush's IO and spans
+            self.tasks += 1
         start = getattr(event, "function_start_tstamp", None)
         end = getattr(event, "function_end_tstamp", None)
         if start is not None and end is not None:

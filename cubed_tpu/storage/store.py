@@ -152,9 +152,11 @@ class _LocalIO:
             # fsync before rename: without it a host crash can leave a
             # renamed-but-empty chunk that existence-based accounting (and
             # any pre-checksum reader) counts as done
-            os.fsync(f.fileno())
+            with scope_span("fsync", cat="storage"):
+                os.fsync(f.fileno())
         os.replace(tmp, path)  # atomic on POSIX: concurrent duplicate tasks are safe
-        _fsync_dir(os.path.dirname(path))
+        with scope_span("fsync", cat="storage", dir=True):
+            _fsync_dir(os.path.dirname(path))
 
     def rename(self, old: str, new: str) -> None:
         os.replace(os.path.join(self.root, old), os.path.join(self.root, new))
@@ -778,11 +780,13 @@ class ZarrV2Array:
         # abort never interrupts an atomic chunk write mid-flight, so the
         # store/manifest/journal stay consistent for resume
         cancellation.check_current()
-        arr = np.ascontiguousarray(arr, dtype=self.dtype)
-        data = arr.tobytes()
-        if self._codec is not None:
-            data = self._codec[0](data)
         key = self._chunk_key(idx)
+        with scope_span("chunk_encode", cat="storage", key=key) as sp:
+            arr = np.ascontiguousarray(arr, dtype=self.dtype)
+            data = arr.tobytes()
+            if self._codec is not None:
+                data = self._codec[0](data)
+            sp.attrs["bytes"] = len(data)
         with scope_span(
             "storage_write", cat="storage", key=key, bytes=len(data)
         ):

@@ -36,7 +36,7 @@ _SAMPLE_LINE = re.compile(
     r"^[a-zA-Z_:][a-zA-Z0-9_:]*"               # metric name
     r"(\{[a-zA-Z_][a-zA-Z0-9_]*=\"(?:[^\"\\\n]|\\\\|\\\"|\\n)*\""
     r"(,[a-zA-Z_][a-zA-Z0-9_]*=\"(?:[^\"\\\n]|\\\\|\\\"|\\n)*\")*\})?"
-    r" -?[0-9.eE+naif]+$"                      # value (incl. nan/inf)
+    r" -?[0-9.eE+\-naif]+$"                    # value (incl. 4e-05, nan/inf)
 )
 
 
@@ -317,10 +317,17 @@ def test_ensure_started_is_idempotent_singleton(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_live_fleet_compute_serves_metrics_and_healthz(tmp_path):
+def test_live_fleet_compute_serves_metrics_and_healthz(tmp_path, monkeypatch):
+    import weakref
+
+    from cubed_tpu.observability import timeseries
     from cubed_tpu.runtime.executors.distributed import DistributedDagExecutor
     from tests.utils import SlowAdd
 
+    # /healthz counts the workers of every live fleet of the process: a
+    # fleet that an earlier test file of this xdist worker left open would
+    # be counted with this test's two workers
+    monkeypatch.setattr(timeseries, "_fleets", weakref.WeakSet())
     export.shutdown()
     spec = ct.Spec(
         work_dir=str(tmp_path), allowed_mem="500MB", telemetry_port=0

@@ -1,6 +1,6 @@
-"""The folded device profiler: the legacy import path keeps working, both
-callbacks feed the span pipeline's decision ring, and neither can fail a
-compute when jax's profiler/device stats are unavailable."""
+"""The device profiler callback: it brackets a compute in a jax profiler
+trace, feeds the span pipeline's decision ring, and cannot fail a compute
+when jax's profiler is unavailable."""
 
 from __future__ import annotations
 
@@ -8,17 +8,7 @@ import time
 import types
 
 from cubed_tpu.observability.collect import decisions_since
-from cubed_tpu.observability.profiler import (
-    DeviceMemoryCallback,
-    JaxProfilerCallback,
-)
-
-
-def test_legacy_extensions_import_path_is_a_shim():
-    from cubed_tpu.extensions import profiler as legacy
-
-    assert legacy.JaxProfilerCallback is JaxProfilerCallback
-    assert legacy.DeviceMemoryCallback is DeviceMemoryCallback
+from cubed_tpu.observability.profiler import JaxProfilerCallback
 
 
 def test_jax_profiler_callback_brackets_the_compute(monkeypatch):
@@ -53,34 +43,3 @@ def test_jax_profiler_start_failure_is_swallowed(monkeypatch):
     cb.on_compute_start(types.SimpleNamespace(dag=None))
     assert not cb._active
     cb.on_compute_end(types.SimpleNamespace(dag=None))  # no stop, no raise
-
-
-def test_device_memory_callback_samples_per_op(monkeypatch):
-    import jax
-
-    fake = types.SimpleNamespace(
-        memory_stats=lambda: {"bytes_in_use": 123, "peak_bytes_in_use": 456}
-    )
-    monkeypatch.setattr(jax, "devices", lambda: [fake])
-    t0 = time.time()
-    cb = DeviceMemoryCallback()
-    cb.on_operation_start(types.SimpleNamespace(name="op-a", num_tasks=4))
-    assert cb.samples == [
-        {"op": "op-a", "bytes_in_use": 123, "peak_bytes_in_use": 456}
-    ]
-    assert any(
-        d["kind"] == "device_memory" and d.get("op") == "op-a"
-        for d in decisions_since(t0)
-    )
-
-
-def test_device_memory_callback_tolerates_missing_stats(monkeypatch):
-    import jax
-
-    def broken():
-        raise RuntimeError("no device")
-
-    monkeypatch.setattr(jax, "devices", broken)
-    cb = DeviceMemoryCallback()
-    cb.on_operation_start(types.SimpleNamespace(name="op-b", num_tasks=1))
-    assert cb.samples[0]["bytes_in_use"] is None

@@ -3,7 +3,11 @@
 from __future__ import annotations
 
 import time
+import weakref
 
+import pytest
+
+from cubed_tpu.observability import timeseries
 from cubed_tpu.observability.metrics import MetricsRegistry, get_registry
 from cubed_tpu.observability.timeseries import (
     ComputeProgressCallback,
@@ -17,6 +21,16 @@ from cubed_tpu.observability.timeseries import (
     register_fleet,
     unregister_fleet,
 )
+
+
+@pytest.fixture(autouse=True)
+def _own_fleet_and_service_registries(monkeypatch):
+    """The sampler reads process-global registries of live fleets and
+    services; a fleet that an earlier test file of this xdist worker left
+    open (weakly held, not yet collected) would be counted with the fake
+    one here. Every test of this file sees registries of its own."""
+    monkeypatch.setattr(timeseries, "_fleets", weakref.WeakSet())
+    monkeypatch.setattr(timeseries, "_services", weakref.WeakSet())
 
 
 # ---------------------------------------------------------------------------
