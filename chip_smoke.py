@@ -27,7 +27,8 @@ below with small sizes), fails if any compute left the device path
 also runs the mesh legs (``JaxExecutor(mesh=make_mesh())``: vorticity, and
 ``zarr_add``'s two ``to_zarr`` computes) and checks that every chip held its
 share. It ends with the raw device facts the timings are read against
-(``device_facts``: float64 round trip, transfer rates, dispatch).
+(``device_facts``: float64 round trip, transfer rates, the executor's
+plane route out, dispatch).
 
 The reference for ``zarr_add`` is numpy on the host arrays the sources
 were written from, and outputs are read back from the store's files with
@@ -90,6 +91,8 @@ PATH_COUNTERS = (
     "rechunk_alias",
     "f64_as_bits",
     "f64_lossy_moves",
+    "d2h_bytes",
+    "d2h_plane_bytes",
 )
 
 #: the sizes the upstream project itself calls real (see the module docstring)
@@ -445,7 +448,8 @@ def device_facts(device, n: int, readings: int, calls: int, *, seed: int) -> dic
     """What the legs' timings are read against (PERF.md section 5), through
     jax alone: whether a float64 survives being held by the device, how fast
     an (n, n) array moves each way as float64, as its uint64 bit pattern and
-    as float32, and what one dispatch costs. Medians of ``readings``
+    as float32, how fast the float64 leaves through the executor's own split
+    into 32-bit planes, and what one dispatch costs. Medians of ``readings``
     transfers and of ``calls`` dispatches, on the host's clock."""
     import jax
 
@@ -484,6 +488,32 @@ def device_facts(device, n: int, readings: int, calls: int, *, seed: int) -> dic
             f"{data.dtype.name} {gb:.1f} GB: host->device {rates[0]:.2f} "
             f"GB/s, device->host {rates[1]:.2f} GB/s"
         )
+    # the float64 array again, the way the executor fetches it: split into
+    # 32-bit planes on the device, one fetch, joined on the host
+    from cubed_tpu.runtime.executors.jax import _join_planes, _plane_splitter
+
+    split, fetch = _plane_splitter(), []
+    jax.block_until_ready(split(jax.device_put(host, device)))  # compiled here
+    for _ in range(readings):
+        on_device = jax.device_put(host, device)
+        on_device.block_until_ready()
+        t0 = time.perf_counter()
+        head, tail, _ = jax.device_get(split(on_device))
+        joined = _join_planes(head, tail, host.dtype)
+        fetch.append(time.perf_counter() - t0)
+    differ = int(np.count_nonzero(joined.view(np.uint64) != back.view(np.uint64)))
+    # the two fetches agree bit for bit only on a device that holds a
+    # float64 as two float32; one with a real float64 loses bits to the split
+    if changed and differ:
+        raise AssertionError(
+            f"float64 fetched as planes differs from the direct fetch in {differ} values"
+        )
+    facts["float64_planes"] = host.nbytes / 1e9 / statistics.median(fetch)
+    _say(
+        f"float64 {host.nbytes / 1e9:.1f} GB as two 32-bit planes (split on the "
+        f"device, joined on the host): device->host {facts['float64_planes']:.2f} "
+        f"GB/s; {differ} of {host.size} values differ from the direct fetch"
+    )
     step = jax.jit(lambda v: v + 1.0)
     small = jax.device_put(np.ones(8, np.float32), device)
     step(small).block_until_ready()

@@ -37,7 +37,12 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   transfer in either direction goes through. It is all or nothing: a
   movement op that shares its compute with arithmetic, or with a
   complex128 array, moves in the device's float64, and
-  ``stats["f64_lossy_moves"]`` counts those.
+  ``stats["f64_lossy_moves"]`` counts those. Such a device also hands
+  64-bit elements to the host ten times slower than 32-bit ones, so
+  ``_to_host`` splits a large 64-bit value into two 32-bit planes on the
+  device (the words of an integer, the float32 pair of a float64), fetches
+  both at once and joins them on the host, bit for bit the direct fetch.
+  ``stats["d2h_plane_bytes"]`` counts the bytes that left that way.
 - **Scheduling.** This executor always keeps op ordering and ignores
   ``Spec(scheduler="dataflow")``: whole (fused) segments compile to single
   XLA programs over HBM-resident arrays, so there is no per-chunk task
@@ -60,12 +65,15 @@ Reference parity: replaces cubed's serverless executors
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import logging
 import math
+import sys
 import threading
 import time
 from collections import Counter
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Iterator, Optional
 
 import numpy as np
@@ -224,6 +232,11 @@ class JaxExecutor(DagExecutor):
         #: this compute moves float64 arrays as uint64 bit patterns
         #: (decided per compute in _execute_dag_inner)
         self._carry_bits = False
+        #: the arrays this compute holds in HBM (``_execute_dag_inner``'s
+        #: table), and whether a flush is running to make room (``_evict``):
+        #: what ``_leaves_as_planes`` reads the room for its planes from
+        self._resident: Dict[str, _Resident] = {}
+        self._spilling = False
         self._prepared_bases: Dict[int, Any] = {}
         #: keys of the task events of this compute in the order they were
         #: fired, kept only while spans are recorded (see ``_task_end``)
@@ -240,7 +253,10 @@ class JaxExecutor(DagExecutor):
         #: of 64-bit floats through a device float64 that is not one),
         #: ``host_syncs`` (fetches in ``_to_host``, each of which blocks on the
         #: device), ``h2d_bytes`` / ``d2h_bytes`` (bytes moved by ``_device_put``
-        #: / ``_to_host``), and the
+        #: / ``_to_host``), ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
+        #: left as 32-bit planes), ``d2h_plane_no_room`` / ``d2h_plane_inexact``
+        #: (fetches that qualified for planes and were made directly: no room
+        #: in HBM, values the split cannot vouch for), and the
         #: failure counters ``eager_fallbacks`` / ``trace_failures`` /
         #: ``whole_array_errors`` / ``batched_errors`` / ``whole_select_errors``
         #: / ``jit_kernel_errors``
@@ -440,7 +456,11 @@ class JaxExecutor(DagExecutor):
 
     def _to_host(self, value, dtype) -> np.ndarray:
         """Device -> host: a device value (or dict of record fields) as a
-        host array of the target's ``dtype``; undoes ``_device_put``."""
+        host array of the target's ``dtype``; undoes ``_device_put``.
+
+        A large 64-bit value on a device without native float64 leaves as
+        two 32-bit planes (``_fetch_as_planes``); everything else is fetched
+        as it is. Either way the host array is the same, bit for bit."""
         if isinstance(value, dict):
             fields = {k: self._to_host(value[k], dtype[k]) for k in dtype.names}
             rec = np.empty(next(iter(fields.values())).shape, dtype=dtype)
@@ -455,12 +475,57 @@ class JaxExecutor(DagExecutor):
             if sp.recording:
                 _jax().block_until_ready(value)
         with scope_span("jax.d2h", cat="transfer") as sp:
-            host = np.asarray(value)
+            host = self._fetch_as_planes(value)
+            planes = sp.attrs["planes"] = host is not None
+            if not planes:
+                host = np.asarray(value)
             if self._carry_bits and host.dtype == np.uint64 and dtype == np.float64:
                 host = host.view(np.float64)
             sp.attrs["bytes"] = host.nbytes
         self.stats["d2h_bytes"] += host.nbytes
+        # present, and 0, where nothing left as planes
+        self.stats["d2h_plane_bytes"] += host.nbytes if planes else 0
         return host
+
+    def _leaves_as_planes(self, value) -> bool:
+        """Whether ``_to_host`` fetches ``value`` as 32-bit planes: a 64-bit
+        real value, large enough to pay for the split's dispatch, on a
+        device that holds 64-bit elements as 32-bit pairs, with room in HBM
+        for the planes. All of it observed in the call; nothing selects the
+        route from outside."""
+        if (
+            np.dtype(value.dtype) not in _PLANE_DTYPES
+            or value.nbytes < _PLANES_MIN_BYTES
+            # under multi-controller SPMD every process would have to run
+            # the split of every chunk; each fetches only its own
+            or _jax().process_count() > 1
+            or _float64_round_trips(self._first_device())
+        ):
+            return False
+        # the planes are a second chunk-sized temporary beside the slice
+        # being fetched. A flush that runs because HBM is over budget must
+        # not be what tips it over, and one at the end of a compute takes
+        # them only where the residency accounting leaves room for both
+        held = sum(r.nbytes for r in self._resident.values())
+        if self._spilling or held + 2 * value.nbytes > self._budget():
+            self.stats["d2h_plane_no_room"] += 1
+            return False
+        return True
+
+    def _fetch_as_planes(self, value) -> Optional[np.ndarray]:
+        """``value`` through ``_split_planes`` on the device, one fetch of
+        both planes, ``_join_planes`` on the host. None where it does not
+        leave as planes (``_leaves_as_planes``) or the device reports
+        values that the split cannot reproduce (counted): the caller then
+        fetches the value itself."""
+        if not self._leaves_as_planes(value):
+            return None
+        first, second, inexact = _jax().device_get(_plane_splitter()(value))
+        if inexact:
+            self.stats["d2h_plane_inexact"] += 1
+            self.stats["host_syncs"] += 1
+            return None
+        return _join_planes(first, second, np.dtype(value.dtype))
 
     # ------------------------------------------------------------------
 
@@ -525,6 +590,7 @@ class JaxExecutor(DagExecutor):
         self.stats = Counter()
         self._task_order = [] if spans_enabled() else None
         resident: Dict[str, _Resident] = {}
+        self._resident = resident
         budget = self._budget()
         self._carry_bits = False
         # with x64 off (compute_dtype="float32") float64 is given up by
@@ -1947,14 +2013,20 @@ class JaxExecutor(DagExecutor):
         total = sum(r.nbytes for r in resident.values())
         if total <= budget:
             return
-        for store, res in sorted(resident.items(), key=lambda kv: kv[1].last_used):
-            if store == exclude:
-                continue
-            self._flush(res)
-            del resident[store]
-            total -= res.nbytes
-            if total <= budget:
-                return
+        self._spilling = True
+        try:
+            for store, res in sorted(
+                resident.items(), key=lambda kv: kv[1].last_used
+            ):
+                if store == exclude:
+                    continue
+                self._flush(res)
+                del resident[store]
+                total -= res.nbytes
+                if total <= budget:
+                    return
+        finally:
+            self._spilling = False
 
     def _flush(self, res: _Resident) -> None:
         """Write a resident array to its Zarr target, chunk by chunk."""
@@ -2066,6 +2138,136 @@ _STRUCT_DEBUG: Optional[list] = None
 #: otherwise interleave the size-check/evict/insert sequences and could
 #: evict an entry a sibling just read or resurrect one past the bound
 _CACHE_LOCK = threading.Lock()
+
+
+#: the dtypes that ``_to_host`` can fetch as two 32-bit planes. complex128
+#: (a pair of float64) and record fields narrower than 8 bytes are fetched
+#: as they are
+_PLANE_DTYPES = frozenset(map(np.dtype, (np.float64, np.int64, np.uint64)))
+
+#: bytes at or above which a 64-bit value leaves the device as planes.
+#: TPU v5e hands over a 64-bit array at 0.21 GB/s whatever its size, a
+#: 32-bit one at 2.6 to 5.6 GB/s; the split costs one more dispatch and
+#: one more buffer to fetch. Milliseconds a fetch on the chip, ``np.asarray``
+#: of a float64 value against split, fetch and join (medians of 11, of 3 at
+#: 200 MB, on two machines; uint64 reads the same; PERF.md section 6, PR 27):
+#:
+#:   bytes    direct  planes          bytes    direct  planes
+#:   8         0.42   1.2 to 1.5      1 MB       3.8   1.6 to 3.3
+#:   40 KB     0.60   1.3             2 MB       6.8   1.8 to 5.0
+#:   256 KB    1.4    1.3 to 1.5      16 MB     56.7   6.6 to 19.5
+#:   512 KB    2.3    1.4 to 1.5      200 MB   960     171
+#:
+#: The two meet near 256 KB; the constant stands where planes won on both
+#: machines
+_PLANES_MIN_BYTES = 2**20
+
+#: float32 bit pattern of 2**-73. A float64 below it in magnitude can have
+#: a tail (its second float32) that is subnormal, which the device's
+#: arithmetic flushes to zero (see ``_split_planes``)
+_HEAD_BITS_2_POW_MINUS_73 = (127 - 73) << 23
+#: float32 bit pattern of infinity, less its sign
+_HEAD_BITS_INF = 0x7F800000
+
+#: threads of ``_join_planes`` and the least bytes of result each one
+#: takes. The host pays for the result's fresh pages by the page (200 ms
+#: for 200 MB on the v5e's host, 33 ms into pages already touched), and
+#: that cost divides among threads
+_JOIN_THREADS = 4
+_JOIN_MIN_BYTES_PER_THREAD = 2**22
+
+
+def _split_planes(x):
+    """A 64-bit device array as two uint32 planes and a flag, on the device.
+
+    int64 / uint64: the low and the high word of each element; the flag is
+    False. float64, which such a device holds as a pair of float32 (head,
+    tail; value = head + tail): the bit patterns of the pair. The head is
+    what the conversion to float32 hands back untouched. The tail has to be
+    computed, ``x - head``, which is exact in the pair arithmetic but does
+    not give back the tail's bits in four cases, each decided from the
+    head's bits (all measured on the v5e: PERF.md section 6, PR 27):
+
+    - a NaN head: the subtraction gives a NaN of its own. The tail is set
+      to zero: ``head + tail`` is the head's NaN, sign and payload,
+      whatever the tail;
+    - an infinite head: a direct fetch reads ``inf + tail``, inf or NaN by
+      a tail that the subtraction cannot show (the chip's division leaves
+      (inf, NaN) pairs);
+    - a head of -0.0: ``head + tail`` is -0.0 or 0.0 by the tail's sign,
+      which the subtraction loses;
+    - a head below 2**-73 in magnitude: the tail may be subnormal, and the
+      subtraction flushes it to zero.
+
+    The flag says that some element is of the last three kinds. They cannot
+    be repaired from here (the device offers no bitcast of a float64), so
+    the caller fetches such a value directly.
+
+    The planes are integers so that no operation on the way out (a
+    concatenate lowers to a ``maximum`` on this chip, which flushes
+    subnormals and canonicalises NaNs) can touch their bits."""
+    jax = _jax()
+    jnp, lax = jax.numpy, jax.lax
+    if x.dtype == jnp.float64:
+        head = x.astype(jnp.float32)
+        tail = (x - head.astype(jnp.float64)).astype(jnp.float32)
+        tail = jnp.where(jnp.isfinite(head), tail, jnp.float32(0))
+        bits = lax.bitcast_convert_type(head, jnp.uint32)
+        magnitude = bits & jnp.uint32(0x7FFFFFFF)
+        inexact = jnp.any(
+            ((bits != 0) & (magnitude < jnp.uint32(_HEAD_BITS_2_POW_MINUS_73)))
+            | (magnitude == jnp.uint32(_HEAD_BITS_INF))
+        )
+        return bits, lax.bitcast_convert_type(tail, jnp.uint32), inexact
+    if x.dtype == jnp.int64:
+        x = lax.bitcast_convert_type(x, jnp.uint64)
+    low = (x & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
+    high = (x >> jnp.uint64(32)).astype(jnp.uint32)
+    return low, high, jnp.bool_(False)
+
+
+@functools.cache
+def _plane_splitter():
+    """``_split_planes`` jitted: one program a (shape, dtype, sharding),
+    compiled by the first fetch that needs it and kept by jax."""
+    return _jax().jit(_split_planes)
+
+
+def _join_planes(first: np.ndarray, second: np.ndarray, dtype: np.dtype) -> np.ndarray:
+    """The host array of ``dtype`` that ``_split_planes``'s two planes stand
+    for, in one pass into one fresh array.
+
+    float64: ``float64(head) + float64(tail)``, one correctly rounded add of
+    two exactly represented numbers, which is what the runtime computes
+    when it fetches the pair itself. Integers: the words written side by
+    side. Large results are filled by a few threads, a slab each."""
+    out = np.empty(first.shape, dtype)
+    flat, a, b = out.reshape(-1), first.reshape(-1), second.reshape(-1)
+    if dtype == np.float64:
+        a, b = a.view(np.float32), b.view(np.float32)
+
+        def fill(lo: int, hi: int) -> None:
+            # a signalling NaN head is quieted, as it is by a direct fetch
+            with np.errstate(invalid="ignore"):
+                np.add(a[lo:hi], b[lo:hi], out=flat[lo:hi], dtype=np.float64)
+    else:
+        words = flat.view(np.uint32).reshape(-1, 2)
+        low, high = (0, 1) if sys.byteorder == "little" else (1, 0)
+
+        def fill(lo: int, hi: int) -> None:
+            words[lo:hi, low] = a[lo:hi]
+            words[lo:hi, high] = b[lo:hi]
+
+    n = flat.size
+    threads = min(_JOIN_THREADS, out.nbytes // _JOIN_MIN_BYTES_PER_THREAD)
+    if threads < 2:
+        fill(0, n)
+        return out
+    cuts = [n * i // threads for i in range(threads + 1)]
+    with ThreadPoolExecutor(threads) as pool:
+        # list(): a slab that raised raises here
+        list(pool.map(fill, cuts[:-1], cuts[1:]))
+    return out
 
 
 #: (platform, device_kind) -> whether float64 survives a round trip

@@ -104,9 +104,10 @@ def test_device_facts_small(capsys):
     assert facts["changed"] == 0  # the CPU holds a float64 as one
     for form in ("float64", "uint64", "float32"):
         assert all(rate > 0 for rate in facts[form])
-    assert facts["dispatch_us"] > 0
+    assert facts["float64_planes"] > 0 and facts["dispatch_us"] > 0
     out = capsys.readouterr().out
     assert "0 of 4096 values changed" in out and "device->host" in out
+    assert "as two 32-bit planes" in out
 
 
 def test_check_mesh_shares():
