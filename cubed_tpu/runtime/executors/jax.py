@@ -69,12 +69,13 @@ import functools
 import itertools
 import logging
 import math
+import re
 import sys
 import threading
 import time
 from collections import Counter
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Iterator, NamedTuple, Optional
 
 import numpy as np
 
@@ -242,6 +243,9 @@ class JaxExecutor(DagExecutor):
         #: fired, kept only while spans are recorded (see ``_task_end``)
         self._task_order: Optional[list] = None
         self._placement = None  # factorized placement mesh, built lazily
+        #: bytes of the arrays ``_admit`` pinned while the segment being
+        #: lowered was traced (see ``_lower_and_compile``)
+        self._pinned: Counter = Counter()
         #: execution-path counters for the last ``execute_dag`` call, reported
         #: via ``ComputeEndEvent.executor_stats``. Keys: ``segments_traced``,
         #: ``segments_compiled``, ``segment_cache_hits``, ``segment_struct_hits``,
@@ -256,7 +260,10 @@ class JaxExecutor(DagExecutor):
         #: / ``_to_host``), ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
         #: left as 32-bit planes), ``d2h_plane_no_room`` / ``d2h_plane_inexact``
         #: (fetches that qualified for planes and were made directly: no room
-        #: in HBM, values the split cannot vouch for), and the
+        #: in HBM, values the split cannot vouch for), ``mesh_devices`` (0
+        #: without a mesh) and the ``_MESH_COUNTERS`` of the segment programs
+        #: (``segment_collectives`` and its kinds, ``sharded_bytes`` /
+        #: ``replicated_bytes``; ``segment_hbm_footprint`` is per chip), and the
         #: failure counters ``eager_fallbacks`` / ``trace_failures`` /
         #: ``whole_array_errors`` / ``batched_errors`` / ``whole_select_errors``
         #: / ``jit_kernel_errors``
@@ -357,21 +364,26 @@ class JaxExecutor(DagExecutor):
         "host 0 writes everything". Constraining the kept outputs keeps
         ownership (and the write path of docs/multihost.md) split across
         hosts."""
-        jax = _jax()
-        if self.mesh is None or isinstance(value, dict) or target is None:
+        sharding = self._target_sharding(value, target)
+        if sharding is None:
             return value
+        return _jax().lax.with_sharding_constraint(value, sharding)
+
+    def _target_sharding(self, value, target):
+        """The mesh sharding of ``target``'s chunk grid if ``value`` is that
+        whole array; None without a mesh, for a record array (a dict) and
+        for a value of another shape."""
+        if self.mesh is None or isinstance(value, dict) or target is None:
+            return None
         shape = tuple(getattr(target, "shape", ()) or ())
         if not shape or tuple(value.shape) != shape:
-            return value
+            return None
         cs = (
             blockdims_from_blockshape(shape, target.chunks)
             if getattr(target, "chunks", None)
             else None
         )
-        sharding = self._sharding_for(shape, cs)
-        if sharding is None:
-            return value
-        return jax.lax.with_sharding_constraint(value, sharding)
+        return self._sharding_for(shape, cs)
 
     def _sharding_for(self, shape: tuple[int, ...], chunkset=None):
         """The chunk-grid-aligned sharding policy (parallel/mesh.py).
@@ -587,7 +599,10 @@ class JaxExecutor(DagExecutor):
         **kwargs,
     ) -> None:
         jax = _jax()
-        self.stats = Counter()
+        self.stats = Counter(
+            dict.fromkeys(_MESH_COUNTERS, 0),
+            mesh_devices=0 if self.mesh is None else self.mesh.devices.size,
+        )
         self._task_order = [] if spans_enabled() else None
         resident: Dict[str, _Resident] = {}
         self._resident = resident
@@ -1117,8 +1132,6 @@ class JaxExecutor(DagExecutor):
         # stream. Only the EXACT identifiers present in this plan's dag are
         # rewritten — a user string can collide only by literally equaling
         # one of this plan's own gensym names.
-        import re
-
         if not plan_names:
             return hashlib.sha256(buf.getvalue()).hexdigest()
         pattern = re.compile(
@@ -1201,10 +1214,10 @@ class JaxExecutor(DagExecutor):
                 _STRUCT_CACHE.get(skey) if skey is not None else None
             )
         if cached_struct is not None:
-            compiled, footprint = cached_struct
+            program = cached_struct
             self.stats["segment_struct_hits"] += 1
         else:
-            compiled, footprint = self._lower_and_compile(
+            program = self._lower_and_compile(
                 ops, resident, in_keys, in_vals, seeded, base_vals, keep,
                 keep_list,
             )
@@ -1212,17 +1225,20 @@ class JaxExecutor(DagExecutor):
                 with _CACHE_LOCK:
                     if len(_STRUCT_CACHE) >= 64:
                         _STRUCT_CACHE.pop(next(iter(_STRUCT_CACHE)))
-                    _STRUCT_CACHE[skey] = (compiled, footprint)
-        if footprint:
+                    _STRUCT_CACHE[skey] = program
+        if program.footprint:
             self.stats["segment_hbm_footprint"] = max(
-                self.stats.get("segment_hbm_footprint", 0), footprint
+                self.stats.get("segment_hbm_footprint", 0), program.footprint
             )
+        # what the program is, not what this call did: a structural hit
+        # reports the same collectives and placement as the miss before it
+        self.stats.update(program.placement)
         with scope_span(
             "jax.dispatch", cat="dispatch",
             struct_hit=cached_struct is not None,
         ):
             # returns when the program is enqueued, not when it has run
-            outs = compiled(in_vals, base_vals)
+            outs = program.compiled(in_vals, base_vals)
             for store, value in zip(keep_list, outs):
                 self._admit(resident, store, value, keep[store], budget)
         return True
@@ -1232,10 +1248,11 @@ class JaxExecutor(DagExecutor):
         keep_list,
     ):
         """Trace and lower the segment's ops as one function, and compile
-        it unless a program of the same HLO is cached: (compiled, HBM
-        footprint). The structural miss of ``_trace_segment``."""
+        it unless a program of the same HLO is cached: a
+        ``_SegmentProgram``. The structural miss of ``_trace_segment``."""
         jax = _jax()
         targets = {k: resident[k].target for k in in_keys}
+        self._pinned = Counter(sharded_bytes=0, replicated_bytes=0)
 
         def seg_fn(vals, bases):
             local = {
@@ -1289,16 +1306,21 @@ class JaxExecutor(DagExecutor):
             with scope_span("jax.compile", cat="dispatch"):
                 compiled = lowered.compile()
             self.stats["segments_compiled"] += 1
-            footprint = _hbm_footprint(compiled)
+            placement = dict(self._pinned)
+            if self.mesh is not None:
+                placement.update(_count_collectives(compiled))
+            program = _SegmentProgram(
+                compiled, _hbm_footprint(compiled), placement
+            )
             if key is not None:
                 with _CACHE_LOCK:
                     if len(_SEGMENT_CACHE) >= 64:
                         _SEGMENT_CACHE.pop(next(iter(_SEGMENT_CACHE)))
-                    _SEGMENT_CACHE[key] = (compiled, footprint)
+                    _SEGMENT_CACHE[key] = program
         else:
-            compiled, footprint = cached
+            program = cached
             self.stats["segment_cache_hits"] += 1
-        return compiled, footprint
+        return program
 
     # ------------------------------------------------------------------
     # blockwise
@@ -1637,9 +1659,24 @@ class JaxExecutor(DagExecutor):
             args = _unflatten_keys(td, list(flat))
             return fn(*args)
 
+        # under a mesh, buckets that tile the out grid as dense subgrids keep
+        # their grid dims (see ``_dense_subgrid``); ``grids`` is then the
+        # (lows, extents) of each bucket, else None and every bucket stacks.
+        # Without a mesh nothing is gained, and the stacked form is the one
+        # XLA generates in full tiles (PERF.md section 5, PR 28)
+        grids = (
+            self._bucket_grids(keys, buckets, task_leaves, leaf_meta, resident)
+            if self.mesh is not None
+            else None
+        )
+        ndim = len(out_nb)
+        regions: Dict[tuple, Any] = {}
+
         chunk_grid: Dict[tuple, Any] = {}
-        for tasks in buckets.values():
+        for sig, tasks in buckets.items():
             T = len(tasks)
+            # the leading dims of this bucket's stacked leaves and results
+            lead = grids[sig][1] if grids else (T,)
             stacked_leaves = []
             in_axes_leaves = []
             for i, (name, proxy, arr, chunkset) in enumerate(leaf_meta):
@@ -1663,7 +1700,7 @@ class JaxExecutor(DagExecutor):
                     rel = np.asarray(
                         [np.ravel_multi_index(c, arr.shape) for c in coords],
                         dtype=arr.dtype,
-                    ).reshape((T,) + (1,) * len(arr.shape))
+                    ).reshape(lead + (1,) * len(arr.shape))
                     if self._tracing and id(arr) in self._prepared_bases:
                         # seed rides a hoisted input; relative offsets are a
                         # seed-independent constant -> stable HLO across plans
@@ -1691,7 +1728,11 @@ class JaxExecutor(DagExecutor):
                     res.touch()
                     value = res.value
                     nb = tuple(len(c) for c in chunkset)
-                    if all(len(set(c)) == 1 for c in chunkset):
+                    if grids:
+                        stacked = _gather_subgrid(
+                            value, chunkset, coords, keep_grid=True
+                        )
+                    elif all(len(set(c)) == 1 for c in chunkset):
                         idx = np.asarray(
                             [np.ravel_multi_index(c, nb) for c in coords],
                             dtype=np.int32,
@@ -1727,8 +1768,33 @@ class JaxExecutor(DagExecutor):
             if all(ax is None for ax in in_axes_leaves):
                 return None
 
-            batched = jax.jit(jax.vmap(task_fn, in_axes=tuple(in_axes_leaves)))
-            out_stacked = batched(*stacked_leaves)
+            batched = task_fn
+            for _ in lead:
+                batched = jax.vmap(batched, in_axes=tuple(in_axes_leaves))
+            out_stacked = jax.jit(batched)(*stacked_leaves)
+
+            if grids:
+                # one region a bucket: the results' grid dims merge with
+                # their chunk dims, no chunk is sliced out and put back
+                first = tuple(keys[tasks[0]][1:])
+                outs = out_stacked if spec.writes_rest else (out_stacked,)
+                if not isinstance(outs, (tuple, list)) or len(outs) != len(
+                    spec.writes
+                ):
+                    return None
+                for w, stacked in zip(spec.writes, outs):
+                    cs_w = blockdims_from_blockshape(tuple(w.array.shape), w.chunks)
+                    expect = lead + chunk_shape_at(cs_w, first)
+                    # (the fields of a record array have its chunk's shape)
+                    if any(
+                        tuple(v.shape) != expect
+                        for v in jax.tree_util.tree_leaves(stacked)
+                    ):
+                        return None
+                regions[grids[sig][0]] = jax.tree_util.tree_map(
+                    lambda v: _merge_grid(v, ndim), out_stacked
+                )
+                continue
 
             for ti, t in enumerate(tasks):
                 out_coords = tuple(keys[t][1:])
@@ -1758,6 +1824,14 @@ class JaxExecutor(DagExecutor):
                         return None
                     chunk_grid[out_coords] = out_stacked[ti]
 
+        if grids:
+            # the buckets' regions tile the array as a coarse grid of their own
+            cuts = [sorted({lows[d] for lows in regions}) for d in range(ndim)]
+            chunk_grid = {
+                tuple(cuts[d].index(lo) for d, lo in enumerate(lows)): region
+                for lows, region in regions.items()
+            }
+            out_nb = tuple(len(c) for c in cuts)
         if spec.writes_rest:
             return tuple(
                 _assemble(
@@ -1769,6 +1843,38 @@ class JaxExecutor(DagExecutor):
         if not isinstance(value, dict) and tuple(value.shape) != out_shape:
             return None
         return value
+
+    def _bucket_grids(self, keys, buckets, task_leaves, leaf_meta, resident):
+        """``{bucket: (lows, extents)}`` when every bucket of a batched op is
+        a dense subgrid of the out grid (``_dense_subgrid``), the buckets
+        tile that grid as a product of cuts per dim, and each leaf that
+        varies over a bucket's tasks walks a subgrid of the same extents of
+        an offsets array or a resident one; None otherwise (the op stacks
+        its tasks along one dim, as it does without a mesh)."""
+        grids = {}
+        for sig, tasks in buckets.items():
+            grid = _dense_subgrid([tuple(keys[t][1:]) for t in tasks])
+            if grid is None:
+                return None
+            for i, (_, _, arr, _) in enumerate(leaf_meta):
+                coords = [tuple(task_leaves[t][i][1:]) for t in tasks]
+                if all(c == coords[0] for c in coords) or isinstance(
+                    arr, (VirtualEmptyArray, VirtualFullArray)
+                ):
+                    continue  # broadcast over the bucket
+                if not isinstance(arr, VirtualOffsetsArray) and (
+                    str(getattr(arr, "store", id(arr))) not in resident
+                ):
+                    return None
+                walked = _dense_subgrid(coords)
+                if walked is None or walked[1] != grid[1]:
+                    return None
+            grids[sig] = grid
+        # per dim, the distinct (low, extent) runs of the buckets
+        cuts = [set(runs) for runs in zip(*(zip(*grid) for grid in grids.values()))]
+        if len(grids) != math.prod(len(c) for c in cuts):
+            return None
+        return grids
 
     # ------------------------------------------------------------------
 
@@ -1999,13 +2105,21 @@ class JaxExecutor(DagExecutor):
     # ------------------------------------------------------------------
 
     def _admit(self, resident, store: str, value, target, budget: int) -> None:
+        nbytes = _value_nbytes(value)
         if self._tracing:
             # inside a traced segment this is where an array is "placed":
             # without the constraint a segment whose inputs are all virtual
             # (random arrays) carries no sharding at all and XLA compiles
             # it for ONE device of the mesh
-            value = self._keep_sharding_constraint(value, target)
-        nbytes = _value_nbytes(value)
+            sharding = self._target_sharding(value, target)
+            if sharding is not None:
+                value = _jax().lax.with_sharding_constraint(value, sharding)
+                # a grid nothing divides gets an empty spec: every chip
+                # then holds the whole array, and only this counter says so
+                divided = any(p is not None for p in sharding.spec)
+                self._pinned[
+                    "sharded_bytes" if divided else "replicated_bytes"
+                ] += nbytes
         self._evict(resident, budget - nbytes, exclude=store)
         resident[store] = _Resident(value, nbytes, target)
 
@@ -2118,14 +2232,57 @@ def _op_scope_name(position: int, primitive_op) -> str:
     return f"op{position:02d}.blockwise.{kernel}"
 
 
-#: in-process cache of (compiled segment program, HBM footprint) keyed by the
+class _SegmentProgram(NamedTuple):
+    """A compiled segment program with what was learned of it once, when it
+    was compiled: every later compute that finds it cached reports the same."""
+
+    compiled: Any
+    #: ``_hbm_footprint``: bytes on ONE device, also under a mesh
+    footprint: int
+    #: the ``_MESH_COUNTERS`` of this program
+    placement: Dict[str, int]
+
+
+#: the kinds of collective instruction ``_count_collectives`` counts, as XLA
+#: names them and as ``stats`` does (``segment_all_to_all``, ...)
+_COLLECTIVE_KINDS = ("all-to-all", "all-reduce", "all-gather", "collective-permute")
+_COLLECTIVE_INSTRUCTION = re.compile(
+    r"\s(" + "|".join(_COLLECTIVE_KINDS) + r")(?:-start)?\("
+)
+
+#: counters of a compute's segment programs under a mesh, each 0 (not
+#: absent) without one: the collective instructions by kind and in all, and
+#: the bytes of the arrays ``_admit`` pinned to a sharding that divides them
+#: and to one whose ``PartitionSpec`` came out empty
+_MESH_COUNTERS = (
+    "segment_collectives",
+    *("segment_" + kind.replace("-", "_") for kind in _COLLECTIVE_KINDS),
+    "sharded_bytes",
+    "replicated_bytes",
+)
+
+
+def _count_collectives(compiled) -> Dict[str, int]:
+    """Collective instructions of a compiled program by kind
+    (``segment_all_to_all``, ...) and in all (``segment_collectives``), read
+    once from the compiled module's text: the partitioner puts them in, so
+    no earlier form of the program has them. An asynchronous pair counts
+    once, at its start."""
+    counts: Counter = Counter()
+    for match in _COLLECTIVE_INSTRUCTION.finditer(compiled.as_text()):
+        counts["segment_" + match.group(1).replace("-", "_")] += 1
+        counts["segment_collectives"] += 1
+    return counts
+
+
+#: in-process cache of ``_SegmentProgram`` keyed by the
 #: sha256 hex digest of (lowered HLO text, device-id tuple): repeat computes
 #: of structurally equal plans on the same device set skip compilation (and
 #: re-analysis) entirely, while a different mesh/topology gets its own entry
 _SEGMENT_CACHE: Dict[str, Any] = {}
 
 
-#: structural-fingerprint cache: (compiled program, HBM footprint) keyed by
+#: structural-fingerprint cache: ``_SegmentProgram`` keyed by
 #: the pre-trace segment fingerprint (see JaxExecutor._structural_key) —
 #: repeat computes of structurally identical plans skip tracing entirely
 _STRUCT_CACHE: Dict[str, Any] = {}
@@ -2293,7 +2450,10 @@ def _float64_round_trips(device) -> bool:
 def _hbm_footprint(compiled) -> int:
     """XLA's own accounting of a program's device footprint (args + outputs
     + temps); 0 when the backend offers no analysis. Computed once per
-    compile — it never changes for a given executable."""
+    compile — it never changes for a given executable. Of a program
+    partitioned over a mesh this is what ONE device holds (its shards and
+    its temporaries), so ``stats["segment_hbm_footprint"]`` is per chip and
+    compares with one device's ``bytes_limit`` with or without a mesh."""
     try:
         ma = compiled.memory_analysis()
         return (
@@ -2420,7 +2580,7 @@ def _unflatten_keys(treedef, flat: list):
     return tuple(build(e) for e in entries)
 
 
-def _gather_subgrid(value, chunkset, coords):
+def _gather_subgrid(value, chunkset, coords, keep_grid=False):
     """Gather a bucket's blocks as ONE region slice + reshape.
 
     A shape-bucket over a ragged grid is a rectangular subgrid whose per-dim
@@ -2429,7 +2589,9 @@ def _gather_subgrid(value, chunkset, coords):
     (T, *chunk). Returns None when the coords don't form such a product
     (caller falls back to per-task slices). This keeps the traced program's
     memory traffic at one read of the region instead of one windowed read per
-    task, which XLA otherwise fails to fuse (~50x bytes-accessed blowup)."""
+    task, which XLA otherwise fails to fuse (~50x bytes-accessed blowup).
+    ``keep_grid`` leaves the blocks' grid dims apart, (*grid, *chunk): the
+    form a sharded region keeps its sharding in (see ``_dense_subgrid``)."""
     import jax.numpy as jnp
 
     ndim = len(chunkset)
@@ -2463,11 +2625,42 @@ def _gather_subgrid(value, chunkset, coords):
             inter.extend([n, c])
         r = region.reshape(tuple(inter))
         perm = list(range(0, 2 * ndim, 2)) + list(range(1, 2 * ndim, 2))
-        return r.transpose(perm).reshape((-1,) + chunk_shape)
+        blocks = r.transpose(perm)
+        return blocks if keep_grid else blocks.reshape((-1,) + chunk_shape)
 
     if isinstance(value, dict):
         return {k: one(v) for k, v in value.items()}
     return one(value)
+
+
+def _dense_subgrid(coords):
+    """``(lows, extents)`` when ``coords``, in the order given, are the
+    C-order product of one run of consecutive block indices per dim; None
+    otherwise.
+
+    Under a mesh the batched route keeps such a bucket's tasks as the dims of
+    their grid, (*extents, *chunk), instead of one stacked dim: merging the
+    grid dims into one puts the sharded dim anywhere but first, a layout no
+    sharding of the stacked dim describes, and the partitioner then moves
+    every chunk between chips (3,240 all-to-all in the vorticity program on
+    four chips, PERF.md section 6, PR 28). With the dims apart the chip that
+    holds a slab of the array holds the same slab of the grid."""
+    ndim = len(coords[0])
+    lows = tuple(min(c[d] for c in coords) for d in range(ndim))
+    extents = tuple(max(c[d] for c in coords) - lows[d] + 1 for d in range(ndim))
+    if len(coords) != math.prod(extents):
+        return None
+    dense = itertools.product(*(range(lo, lo + n) for lo, n in zip(lows, extents)))
+    if any(tuple(c) != d for c, d in zip(coords, dense)):
+        return None
+    return lows, extents
+
+
+def _merge_grid(value, ndim: int):
+    """(*grid, *chunk) -> the region the blocks tile, (grid[d] * chunk[d])."""
+    perm = [axis for d in range(ndim) for axis in (d, ndim + d)]
+    shape = tuple(value.shape[d] * value.shape[ndim + d] for d in range(ndim))
+    return value.transpose(perm).reshape(shape)
 
 
 def _gather_blocks(value, nb, chunk_shape, idx):

@@ -729,6 +729,6 @@ def test_mesh_segment_with_only_virtual_inputs_is_partitioned(spec, monkeypatch)
     value = float(expr.compute(executor=ex))
     assert 0.3 < value < 0.7
     assert ex.stats["segments_traced"] == 1 and not ex.stats.get("eager_fallbacks")
-    ((compiled, _),) = jx._SEGMENT_CACHE.values()
-    (out_sharding,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    (program,) = jx._SEGMENT_CACHE.values()
+    (out_sharding,) = jax.tree_util.tree_leaves(program.compiled.output_shardings)
     assert len(out_sharding.device_set) == len(jax.devices())
