@@ -28,7 +28,8 @@ also runs the mesh legs (``JaxExecutor(mesh=make_mesh())``: vorticity, and
 ``zarr_add``'s two ``to_zarr`` computes) and checks that every chip held its
 share. It ends with the raw device facts the timings are read against
 (``device_facts``: float64 round trip, transfer rates, the executor's
-plane route out, dispatch).
+plane route out, dispatch, the executor's streamed preload against the
+whole-array put).
 
 The reference for ``zarr_add`` is numpy on the host arrays the sources
 were written from, and outputs are read back from the store's files with
@@ -93,6 +94,8 @@ PATH_COUNTERS = (
     "f64_lossy_moves",
     "d2h_bytes",
     "d2h_plane_bytes",
+    "h2d_bytes",
+    "h2d_stream_bytes",
 )
 
 #: the sizes the upstream project itself calls real (see the module docstring)
@@ -444,13 +447,91 @@ def zarr_add_leg(
 # ---------------------------------------------------------------------------
 
 
+#: float64 values that a transfer is most likely to change: NaNs with a
+#: payload and a sign, zeros of both signs, infinities, the least and a larger
+#: subnormal, the extremes of float64's range and of float32's
+EDGE_VALUES = np.array(
+    [0x7FF8000000000123, 0xFFF0000000000ABC, 0x8000000000000000, 0,
+     0x7FF0000000000000, 0xFFF0000000000000, 1, 0x000FFFFFFFFFFFFF,
+     0x7FEFFFFFFFFFFFFF, 0x0010000000000000, 0x47EFFFFFE0000000,
+     0x3690000000000000],
+    dtype=np.uint64,
+).view(np.float64)
+
+
+def preload_stream_reading(n: int, *, seed: int) -> dict:
+    """A float64 Zarr array of (n, n) chunks, two whole and one ragged, with
+    ``EDGE_VALUES`` in every chunk, through ``JaxExecutor._preload`` (which
+    streams it chunk by chunk through the staging buffers) and through the
+    whole-array put of the same values, as numbers and as bit patterns.
+    Raises if the two device values, fetched, differ in any bit. The
+    seconds are smoke timings of one preload each."""
+    import jax
+
+    from cubed_tpu.storage.store import open_zarr_array
+
+    host = np.random.default_rng(seed).random((2 * n + n // 4, n))
+    for row in range(0, host.shape[0], max(1, n // 4)):
+        host[row, : EDGE_VALUES.size] = EDGE_VALUES
+        host[row, -EDGE_VALUES.size :] = EDGE_VALUES[::-1]
+    out = {}
+    with tempfile.TemporaryDirectory(prefix="chip_smoke-stream-") as root:
+        stored = open_zarr_array(
+            os.path.join(root, "source.zarr"), "w", shape=host.shape,
+            dtype=host.dtype, chunks=(n, n),
+        )
+        stored[...] = host
+        for form, carry_bits in (("float64", False), ("uint64", True)):
+            executor = JaxExecutor()
+            executor._carry_bits = carry_bits
+            resident: dict = {}
+            t0 = time.perf_counter()
+            if not executor._preload(stored, resident, executor._budget()):
+                raise AssertionError("the stored array was not preloaded")
+            (res,) = resident.values()
+            jax.block_until_ready(res.value)
+            t1 = time.perf_counter()
+            whole = executor._device_put(host, host.shape)
+            jax.block_until_ready(whole)
+            t2 = time.perf_counter()
+            stats = executor.stats
+            if stats["h2d_stream_bytes"] != 3 * n * n * 8:
+                raise AssertionError(f"the preload did not stream: {dict(stats)}")
+            streamed, put = np.asarray(res.value), np.asarray(whole)
+            if streamed.dtype != put.dtype or streamed.dtype.name != form:
+                raise AssertionError(f"{form}: {streamed.dtype} and {put.dtype} on the device")
+            differ = int(np.count_nonzero(
+                streamed.view(np.uint64) != put.view(np.uint64)
+            ))
+            if differ:
+                raise AssertionError(
+                    f"{form}: the streamed preload differs from the whole-array "
+                    f"put in {differ} of {host.size} values"
+                )
+            if carry_bits and streamed.tobytes() != host.tobytes():
+                raise AssertionError("bit patterns changed on the way to the device")
+            out[form] = (t1 - t0, t2 - t1)
+            _say(
+                f"{form} {host.nbytes / 1e9:.2f} GB in {stored.nchunks} chunks of "
+                f"{n * n * 8 / 1e6:.0f} MB, edge values in each: streamed preload "
+                f"{t1 - t0:.3f} s (h2d_stream_bytes={stats['h2d_stream_bytes']} of "
+                f"h2d_bytes={stats['h2d_bytes']}, staging buffers "
+                f"{[b.buffer.nbytes for b in executor._staging]}), whole-array put of "
+                f"the host array {t2 - t1:.3f} s; 0 of {host.size} values differ"
+            )
+            del res, resident, whole, streamed, put
+    return out
+
+
 def device_facts(device, n: int, readings: int, calls: int, *, seed: int) -> dict:
     """What the legs' timings are read against (PERF.md section 5), through
     jax alone: whether a float64 survives being held by the device, how fast
     an (n, n) array moves each way as float64, as its uint64 bit pattern and
     as float32, how fast the float64 leaves through the executor's own split
-    into 32-bit planes, and what one dispatch costs. Medians of ``readings``
-    transfers and of ``calls`` dispatches, on the host's clock."""
+    into 32-bit planes, what one dispatch costs, and (through the executor)
+    that the streamed preload puts on the device what the whole-array put
+    does. Medians of ``readings`` transfers and of ``calls`` dispatches, on
+    the host's clock."""
     import jax
 
     print(f"== device facts: ({n}, {n}) arrays, medians of {readings} "
@@ -524,6 +605,9 @@ def device_facts(device, n: int, readings: int, calls: int, *, seed: int) -> dic
         times.append(time.perf_counter() - t0)
     facts["dispatch_us"] = statistics.median(times) * 1e6
     _say(f"trivial jitted call to block_until_ready: {facts['dispatch_us']:.0f} us")
+    # the way in, as the executor's preload takes it: chunk by chunk through
+    # reused staging buffers, against the put of the whole array
+    facts["preload_stream"] = preload_stream_reading(n, seed=seed)
     return facts
 
 

@@ -6,6 +6,14 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   store path. Zarr is only touched at plan boundaries: sources are loaded once,
   and requested outputs are flushed at the end. Intermediates never hit
   storage (the reference pays a full storage round-trip per op).
+- **Streamed preload.** Without a mesh a stored source of several chunks is
+  never assembled on the host: each chunk file is read into one of two
+  staging buffers that the executor keeps, put on the device from there and
+  written into its place in one resident array that is updated in place
+  (``_stream_to_device``, ``_chunk_writer``), bit for bit what the put of
+  the whole array gives. The route is chosen from what ``_device_put``
+  observes (``_streams``); ``stats["h2d_stream_bytes"]`` counts what went
+  that way and ``stats["h2d_stream_declined"]`` what found no room.
 - **Whole-array fast path.** Ops whose kernel is shape-invariant (elementwise /
   broadcasting chains, including everything the optimizer fused) and whose
   block mapping is 1:1-with-broadcast run as ONE jitted call on whole resident
@@ -51,9 +59,10 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   (``runtime/dataflow.py`` is the multi-host fleet's analogue).
 - **Spans.** Each segment (with its preloads), eager op and flush runs in a
   task scope of the span pipeline (``observability/accounting.py``) and
-  times its phases with ``scope_span`` (``jax.preload``, ``jax.h2d``,
-  ``jax.struct_key``, ``jax.trace_lower``, ``jax.compile``,
-  ``jax.dispatch``, ``jax.flush``, ``jax.device_wait``, ``jax.d2h``): the
+  times its phases with ``scope_span`` (``jax.preload``, ``jax.h2d`` (one
+  a chunk of a streamed preload), ``jax.struct_key``, ``jax.trace_lower``,
+  ``jax.compile``, ``jax.dispatch``, ``jax.flush``, ``jax.device_wait``,
+  ``jax.d2h``): the
   phases' only clock (the task events keep their timestamps), a no-op unless a ``TraceCollector`` is attached or
   ``CUBED_TPU_TASK_SPANS=1`` (docs/observability.md, "Device executor
   spans").
@@ -69,6 +78,7 @@ import functools
 import itertools
 import logging
 import math
+import mmap
 import re
 import sys
 import threading
@@ -132,6 +142,44 @@ class _Resident:
 
     def touch(self):
         self.last_used = time.monotonic()
+
+
+class _Staging:
+    """One of the two host buffers that a streamed preload reads chunk files
+    into (``JaxExecutor._stream_to_device``). ``busy`` is a result of the
+    device update that consumed the bytes now in ``buffer``: until it is
+    ready the buffer may still be read by the transfer (the put is
+    asynchronous on the TPU, and on the CPU backend a put value may alias
+    the numpy memory), so it is not written."""
+
+    __slots__ = ("buffer", "busy")
+
+    def __init__(self):
+        self.buffer: Optional[np.ndarray] = None
+        self.busy = None
+
+    def release(self) -> None:
+        """Wait until the buffer may be written again."""
+        if self.busy is not None:
+            self.busy.block_until_ready()
+            self.busy = None
+
+    def sized(self, nbytes: int) -> np.ndarray:
+        """The buffer, free to be written, with room for ``nbytes``: made on
+        first use and again only for a larger chunk, so that after its
+        first chunk no read writes a fresh page."""
+        self.release()
+        if self.buffer is None or self.buffer.nbytes < nbytes:
+            # on a page boundary, as the page cache's pages are that the
+            # read copies from. numpy's own large arrays start 16 bytes
+            # into a page, and on the v5e's host that one offset costs a
+            # read of 200 MB 81 ms against 21 to 25 ms at any other: every
+            # load then follows a store to the same place of a page
+            # (PERF.md section 6, PR 29)
+            raw = np.empty(nbytes + mmap.PAGESIZE, np.uint8)
+            shift = -raw.ctypes.data % mmap.PAGESIZE
+            self.buffer = raw[shift : shift + nbytes]
+        return self.buffer
 
 
 def _moves_values(op) -> bool:
@@ -238,6 +286,10 @@ class JaxExecutor(DagExecutor):
         #: what ``_leaves_as_planes`` reads the room for its planes from
         self._resident: Dict[str, _Resident] = {}
         self._spilling = False
+        #: the host memory of a streamed preload: two chunk-sized buffers
+        #: that take turns, kept for every source this executor loads and
+        #: released with it
+        self._staging = (_Staging(), _Staging())
         self._prepared_bases: Dict[int, Any] = {}
         #: keys of the task events of this compute in the order they were
         #: fired, kept only while spans are recorded (see ``_task_end``)
@@ -257,7 +309,11 @@ class JaxExecutor(DagExecutor):
         #: of 64-bit floats through a device float64 that is not one),
         #: ``host_syncs`` (fetches in ``_to_host``, each of which blocks on the
         #: device), ``h2d_bytes`` / ``d2h_bytes`` (bytes moved by ``_device_put``
-        #: / ``_to_host``), ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
+        #: / ``_to_host``), ``h2d_stream_bytes`` (the part of ``h2d_bytes`` that
+        #: went chunk by chunk through the staging buffers; 0, not absent,
+        #: where nothing did), ``h2d_stream_declined`` (stored arrays that
+        #: qualified for the stream and were put whole for want of room in
+        #: HBM), ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
         #: left as 32-bit planes), ``d2h_plane_no_room`` / ``d2h_plane_inexact``
         #: (fetches that qualified for planes and were made directly: no room
         #: in HBM, values the split cannot vouch for), ``mesh_devices`` (0
@@ -433,7 +489,12 @@ class JaxExecutor(DagExecutor):
         array is read through ``make_array_from_callback``: each process
         materializes only the regions its addressable shards cover — the
         per-host Zarr IO sharding seam of docs/multihost.md (on one host
-        this degenerates to reading everything, shard by shard)."""
+        this degenerates to reading everything, shard by shard). Without
+        one, a stored array of several chunks goes chunk by chunk
+        (``_stream_to_device``) where HBM has the room (``_streams``), and
+        anything else is read whole on the host and put in one piece. All
+        routes share the representation rules here and give the same
+        device value, bit for bit."""
         jax = _jax()
         stored = not isinstance(value, (np.ndarray, np.generic))
         if value.dtype.fields is not None:
@@ -453,6 +514,8 @@ class JaxExecutor(DagExecutor):
 
         sharding = self._sharding_for(shape, chunkset)
         if sharding is None and stored:
+            if self._streams(value):
+                return self._stream_to_device(value, transferred)
             value = value[...] if value.shape else value[()]
             stored = False
         with scope_span("jax.h2d", cat="transfer") as sp:
@@ -465,6 +528,78 @@ class JaxExecutor(DagExecutor):
                 out = jax.device_put(transferred(value), sharding)
             sp.attrs["bytes"] = _value_nbytes(out)
         return out
+
+    def _streams(self, stored) -> bool:
+        """Whether ``_device_put`` sends the unsharded ``stored`` to the
+        device chunk by chunk: one of the package's own Zarr arrays (the
+        chunk-level read is theirs), of more than one chunk, with room in
+        HBM for the array and two chunks in flight beside what is resident.
+        A device that holds 64-bit elements as 32-bit pairs updates such an
+        array through a split copy of it and of the chunk (1.21 GB of
+        temporaries for an 800 MB float64 array and a 200 MB chunk, by the
+        v5e's compiler), so there the room is asked for twice, and every
+        update is a pass over the whole array, so there the chunks are few
+        (``_PAIR_STREAM_MAX_CHUNKS``). All of it observed in the call;
+        nothing selects the route from outside."""
+        if (
+            not isinstance(stored, ZarrV2Array)
+            or stored.nchunks < 2
+            or stored.size == 0
+        ):
+            return False
+        held = sum(r.nbytes for r in self._resident.values())
+        needed = stored.nbytes + 2 * stored._chunk_nbytes()
+        if stored.dtype in _PAIR_DTYPES and not _float64_round_trips(
+            self._first_device()
+        ):
+            if stored.nchunks > _PAIR_STREAM_MAX_CHUNKS:
+                return False
+            needed *= 2
+        if held + needed > self._budget():
+            self.stats["h2d_stream_declined"] += 1
+            return False
+        return True
+
+    def _stream_to_device(self, stored, transferred):
+        """``stored`` on the device without a copy of it on the host: each
+        chunk file is read into one of the executor's two staging buffers,
+        put on the device from there and written into its place in one
+        resident array of the full shape, which is allocated once and
+        updated in place (``_chunk_writer``).
+
+        The buffers take turns, so the read of chunk k + 1 overlaps the
+        transfer and update of chunk k; chunk k's span ends with the wait
+        for chunk k - 1's update, which frees the buffer that chunk k + 1 is
+        read into. A source's last update is waited for by whichever read
+        needs its buffer next. ``transferred`` is ``_device_put``'s: the
+        byte count and the bit-pattern view."""
+        jax = _jax()
+        chunk_nbytes = stored._chunk_nbytes()
+        write = _chunk_writer()
+        whole = None
+        chunkset = stored.chunkset()
+        grid = itertools.product(*(range(len(c)) for c in chunkset))
+        for k, idx in enumerate(grid):
+            stage, other = self._staging[k % 2], self._staging[1 - k % 2]
+            chunk = stored._read_chunk_into(idx, stage.sized(chunk_nbytes))
+            if chunk is None:
+                chunk = stored._empty_chunk()
+            # where the chunk goes, and what of it (stored padded) lies
+            # inside the array
+            sel = get_item(chunkset, idx)
+            start = tuple(s.start for s in sel)
+            extent = tuple(s.stop - s.start for s in sel)
+            with scope_span("jax.h2d", cat="transfer") as sp:
+                piece = jax.device_put(transferred(chunk))
+                if whole is None:
+                    whole = jax.numpy.zeros(stored.shape, piece.dtype)
+                whole, stage.busy = write(
+                    whole, piece, np.asarray(start, np.int32), extent
+                )
+                sp.attrs["bytes"] = piece.nbytes
+                other.release()
+            self.stats["h2d_stream_bytes"] += piece.nbytes
+        return whole
 
     def _to_host(self, value, dtype) -> np.ndarray:
         """Device -> host: a device value (or dict of record fields) as a
@@ -602,6 +737,7 @@ class JaxExecutor(DagExecutor):
         self.stats = Counter(
             dict.fromkeys(_MESH_COUNTERS, 0),
             mesh_devices=0 if self.mesh is None else self.mesh.devices.size,
+            h2d_stream_bytes=0,
         )
         self._task_order = [] if spans_enabled() else None
         resident: Dict[str, _Resident] = {}
@@ -844,8 +980,12 @@ class JaxExecutor(DagExecutor):
             if concrete.shape and getattr(concrete, "chunks", None)
             else None
         )
-        with scope_span("jax.preload", bytes=nbytes):
+        with scope_span(
+            "jax.preload", bytes=nbytes, chunks=concrete.nchunks
+        ) as sp:
+            streamed = self.stats["h2d_stream_bytes"]
             value = self._device_put(concrete, tuple(concrete.shape), cs)
+            sp.attrs["streamed"] = self.stats["h2d_stream_bytes"] > streamed
             self._admit(resident, key, value, arr, budget)
         return True
 
@@ -2302,6 +2442,20 @@ _CACHE_LOCK = threading.Lock()
 #: as they are
 _PLANE_DTYPES = frozenset(map(np.dtype, (np.float64, np.int64, np.uint64)))
 
+#: the dtypes of 64-bit elements, which a device without native float64
+#: holds as pairs of 32-bit ones and computes on through split copies
+_PAIR_DTYPES = _PLANE_DTYPES | {np.dtype(np.complex128)}
+
+#: the most chunks of a 64-bit array that a device of 32-bit pairs is sent
+#: one by one. There each update splits and recombines the whole array: 12.1
+#: ms for an 800 MB float64 or uint64 array whatever the chunk (10.5 ms with
+#: an 8 MB chunk), 15 ms a GB, against 0.9 to 1.8 ms for a 32-bit one
+#: updated in place; the host assembles and puts the whole array in 2.2 s a
+#: GB and reads it chunk by chunk in 0.1 to 0.4 s a GB (TPU v5e, PERF.md
+#: section 6, PR 29). The two routes meet near 145 chunks; the constant
+#: stands where the stream takes under half the whole route's time
+_PAIR_STREAM_MAX_CHUNKS = 64
+
 #: bytes at or above which a 64-bit value leaves the device as planes.
 #: TPU v5e hands over a 64-bit array at 0.21 GB/s whatever its size, a
 #: 32-bit one at 2.6 to 5.6 GB/s; the split costs one more dispatch and
@@ -2388,6 +2542,30 @@ def _plane_splitter():
     """``_split_planes`` jitted: one program a (shape, dtype, sharding),
     compiled by the first fetch that needs it and kept by jax."""
     return _jax().jit(_split_planes)
+
+
+@functools.cache
+def _chunk_writer():
+    """The device half of a streamed preload, jitted: ``piece`` (a padded
+    chunk, cut to its static ``extent`` inside the array) written into
+    ``whole`` at ``start``, in place, since ``whole`` is donated. One
+    program a (shape, chunk shape, extent, dtype), whatever the position.
+
+    A ``dynamic_update_slice`` and not a concatenate, which lowers to a
+    ``maximum`` on the v5e and then flushes subnormals and canonicalises
+    NaNs (``_split_planes``). The second result is one element of the
+    updated array: ``whole`` itself is gone with the next update, so the
+    host waits on this one to know that ``piece`` has been consumed."""
+    jax = _jax()
+    lax = jax.lax
+
+    def write(whole, piece, start, extent):
+        at = tuple(start[d] for d in range(whole.ndim))
+        piece = lax.slice(piece, (0,) * whole.ndim, extent)
+        updated = lax.dynamic_update_slice(whole, piece, at)
+        return updated, lax.dynamic_slice(updated, at, (1,) * whole.ndim)
+
+    return jax.jit(write, static_argnums=3, donate_argnums=0)
 
 
 def _join_planes(first: np.ndarray, second: np.ndarray, dtype: np.dtype) -> np.ndarray:

@@ -103,7 +103,9 @@ class _LocalIO:
     def exists(self, name: str) -> bool:
         return os.path.exists(os.path.join(self.root, name))
 
-    def read_bytes(self, name: str) -> bytes:
+    def _open_for_read(self, name: str, **kwargs):
+        """The object's file, open for reading, once the fault injector has
+        had its say (a throttle, a failed read)."""
         injector = get_injector()
         if injector is not None:
             if injector.storage_throttle_fault(_fault_key(self.root, name)):
@@ -112,8 +114,31 @@ class _LocalIO:
                 )
             if injector.storage_read_fault(_fault_key(self.root, name)):
                 raise FaultInjectedIOError(f"injected read failure: {name}")
-        with open(os.path.join(self.root, name), "rb") as f:
+        return open(os.path.join(self.root, name), "rb", **kwargs)
+
+    def read_bytes(self, name: str) -> bytes:
+        with self._open_for_read(name) as f:
             return f.read()
+
+    def readinto(self, name: str, buffer):
+        """``read_bytes`` into the caller's writable ``buffer``: the bytes
+        read, as a view of its head. A caller that hands over the same
+        buffer again pays for no fresh pages (200 MB: 160 to 225 ms into
+        fresh pages, 22 to 24 ms into touched ones on the v5e's host;
+        PERF.md section 6, PR 29). An object that does not fit is read as
+        ``read_bytes`` reads it, and nothing of ``buffer`` then holds it."""
+        with self._open_for_read(name, buffering=0) as f:
+            size = os.fstat(f.fileno()).st_size
+            view = memoryview(buffer).cast("B")
+            if size > len(view):
+                return f.read()
+            n = 0
+            while n < size:
+                got = f.readinto(view[n:size])
+                if not got:
+                    break
+                n += got
+            return view[:n]
 
     def write_bytes_atomic(self, name: str, data: bytes, inject: bool = True) -> None:
         path = os.path.join(self.root, name)
@@ -524,13 +549,14 @@ class ZarrV2Array:
         return prod(self.chunks) * self.dtype.itemsize if self.chunks else self.dtype.itemsize
 
     def _read_chunk(
-        self, idx: tuple[int, ...], allow_peer: bool = True
+        self, idx: tuple[int, ...], allow_peer: bool = True, into=None
     ) -> Optional[np.ndarray]:
         """Read the full (padded) chunk at block index *idx*, or None if
         absent. ``allow_peer=False`` skips the peer fast path — used after
         a sub-chunk range fetch already attempted (and missed/failed) the
         peer for this chunk, so one logical read never draws the fault
-        injector or counts a miss twice."""
+        injector or counts a miss twice. ``into``: see
+        ``_read_chunk_into``."""
         key = self._chunk_key(idx)
         # cooperative cancellation: between chunk reads is a safe abort
         # boundary — nothing half-written, resume is bitwise-correct
@@ -566,7 +592,7 @@ class ZarrV2Array:
                 )
             return None
         with scope_span("storage_read", cat="storage", key=key) as sp:
-            data = self._read_bytes_with_retries(key)
+            data = self._read_bytes_with_retries(key, into)
             sp.attrs["bytes"] = len(data)
         # IO bytes as stored (pre-decompression), attributed to the reading
         # task's scope when one is active (observability/accounting.py)
@@ -578,6 +604,21 @@ class ZarrV2Array:
             data = self._codec[1](data)
         arr = np.frombuffer(data, dtype=self.dtype)
         return arr.reshape(self.chunks if self.shape else ())
+
+    def _read_chunk_into(
+        self, idx: tuple[int, ...], staging: np.ndarray
+    ) -> Optional[np.ndarray]:
+        """``_read_chunk`` with the chunk's file read into ``staging``, a
+        writable buffer of at least ``_chunk_nbytes()`` bytes that the
+        caller owns and hands over again: the chunk returned is then a view
+        of it, valid until the caller's next read into it. Everything else
+        is ``_read_chunk``'s: the cancellation point, the injected faults,
+        retries and breaker pacing, the byte accounting, the
+        ``storage_read`` span, verification of the staged bytes with
+        quarantine, None for a chunk never written. A store with a codec, an
+        IO class without a ``readinto`` and the peer path hand over the
+        bytes they produce, and ``staging`` is left alone."""
+        return self._read_chunk(idx, into=staging)
 
     def _read_chunk_region(
         self, idx: tuple[int, ...], chunk_sel: tuple[slice, ...]
@@ -701,7 +742,7 @@ class ZarrV2Array:
                     integrity.quarantine_chunk(self._io, name, store=self.store)
         return valid, corrupt, True
 
-    def _read_bytes_with_retries(self, key: str) -> bytes:
+    def _read_bytes_with_retries(self, key: str, into=None) -> bytes:
         """Chunk reads retry transient IO errors at the storage layer.
 
         A flaky read inside a task would otherwise burn a whole task retry
@@ -713,7 +754,14 @@ class ZarrV2Array:
         anything else fails the task loudly. It must NOT read as "absent":
         silently substituting fill values for real data would complete the
         compute with wrong results.
+
+        With ``into`` (a writable buffer) the stored bytes land there and
+        come back as a view of it, where they are the chunk's own bytes (no
+        codec) and the IO class can read into a buffer.
         """
+        readinto = None
+        if into is not None and self._codec is None:
+            readinto = getattr(self._io, "readinto", None)
         policy = _read_retry_policy()
         breaker = _active_breaker(self.store)
         failures = 0
@@ -724,7 +772,10 @@ class ZarrV2Array:
                 # retry sleeps below run with the slot RELEASED, so a
                 # paced holder never idles the store's whole allowance
                 with _breaker_slot(breaker, key):
-                    data = self._io.read_bytes(key)
+                    if readinto is None:
+                        data = self._io.read_bytes(key)
+                    else:
+                        data = readinto(key, into)
                 if breaker is not None:
                     breaker.on_success()
                 return data

@@ -117,10 +117,66 @@ def test_zarr_add_yields_every_span_each_inside_its_parent(sources, tmp_path):
     assert parents_of["storage_write"] == {"jax.flush"}
     assert "storage_write" in parents_of["fsync"]
     by_name = {s["name"]: s for s in _spans(tc)}
-    assert by_name["jax.preload"]["attrs"]["bytes"] == NBYTES
-    assert by_name["jax.h2d"]["attrs"]["bytes"] == NBYTES
+    assert by_name["jax.preload"]["attrs"] == {
+        "bytes": NBYTES, "chunks": 4, "streamed": True,
+    }
+    assert by_name["jax.h2d"]["attrs"]["bytes"] == CHUNK * CHUNK * 8  # one a chunk
     assert by_name["jax.flush"]["attrs"] == {"bytes": NBYTES, "chunks": 4}
     assert by_name["jax.d2h"]["attrs"]["bytes"] == CHUNK * CHUNK * 8
+
+
+@pytest.mark.parametrize("mode", ["write", "verify"])
+def test_a_streamed_preload_is_one_span_a_source_with_a_read_and_a_put_a_chunk(
+    sources, tmp_path, mode
+):
+    """The contract of the streamed preload: one ``jax.preload`` a source,
+    marked ``streamed`` with its ``chunks``; directly inside it one
+    ``storage_read`` and then one ``jax.h2d`` a chunk (the read is not inside
+    the put, as it is under a mesh); in mode ``verify`` an
+    ``integrity_verify`` after every read."""
+    spec, paths = sources
+    spec = ct.Spec(work_dir=spec.work_dir, allowed_mem="500MB", integrity=mode)
+    tc = TraceCollector(trace_dir=None)
+    stats = _store((spec, paths), tmp_path, "c", [tc]).stats
+    (rec,) = [r for r in tc._records if any(s["name"] == "jax.preload" for s in r["spans"])]
+    preloads = [s for s in rec["spans"] if s["name"] == "jax.preload"]
+    assert len(preloads) == 2
+    per_chunk = ["storage_read", "integrity_verify", "jax.h2d"]
+    if mode == "write":
+        per_chunk.remove("integrity_verify")
+    for preload in preloads:
+        assert preload["attrs"] == {"bytes": NBYTES, "chunks": 4, "streamed": True}
+        inside = sorted(
+            (s for s in rec["spans"] if s.get("parent") == preload["id"]),
+            key=lambda s: s["id"],
+        )
+        assert [s["name"] for s in inside] == per_chunk * 4
+        reads = [s for s in inside if s["name"] == "storage_read"]
+        assert sorted(s["attrs"]["key"] for s in reads) == ["0.0", "0.1", "1.0", "1.1"]
+        for s in inside:
+            if s["name"] != "integrity_verify":
+                assert s["attrs"]["bytes"] == CHUNK * CHUNK * 8
+    assert stats["span_n"]["jax.preload"] == 2
+    assert stats["span_n"]["jax.h2d"] == stats["span_n"]["storage_read"] == 8
+    assert stats["span_n"].get("integrity_verify", 0) == (8 if mode == "verify" else 0)
+    assert stats["h2d_stream_bytes"] == stats["h2d_bytes"] == 2 * NBYTES
+    assert stats.get("chunks_verified", 0) == (8 if mode == "verify" else 0)
+
+
+def test_a_source_of_one_chunk_is_a_preload_that_did_not_stream(tmp_path):
+    spec = ct.Spec(work_dir=str(tmp_path / "work"), allowed_mem="500MB")
+    path = str(tmp_path / "one.zarr")
+    ct.to_zarr(ct.from_array(np.ones((CHUNK, CHUNK)), chunks=(CHUNK, CHUNK), spec=spec), path)
+    tc, cap = TraceCollector(trace_dir=None), _Capture()
+    ct.to_zarr(
+        xp.negative(ct.from_zarr(path, spec=spec)), str(tmp_path / "out.zarr"),
+        executor=JaxExecutor(), callbacks=[tc, cap],
+    )
+    (preload,) = [s for s in _spans(tc) if s["name"] == "jax.preload"]
+    assert preload["attrs"] == {"bytes": CHUNK * CHUNK * 8, "chunks": 1, "streamed": False}
+    (h2d,) = [s for s in _spans(tc) if s["name"] == "jax.h2d"]
+    assert h2d["attrs"]["bytes"] == CHUNK * CHUNK * 8
+    assert cap.stats["h2d_stream_bytes"] == 0 < cap.stats["h2d_bytes"]
 
 
 def test_first_compute_traces_and_compiles_the_second_is_a_struct_hit(
@@ -170,6 +226,9 @@ def test_unarmed_no_span_is_allocated_and_no_sync_is_added(
 
     monkeypatch.setattr(accounting.TaskScope, "add_span", never)
     monkeypatch.setattr(accounting, "_trace_annotation", never)
+    # the streamed preload waits on its own updates before it rewrites a
+    # staging buffer (``_Staging.release``): that wait is the route's, armed
+    # or not, and is no ``jax.block_until_ready``
     monkeypatch.setattr(jax, "block_until_ready", never)
     cap = _store(sources, tmp_path, "c", [])
     assert not {"span_s", "span_self_s", "span_n"} & set(cap.stats)

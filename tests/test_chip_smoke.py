@@ -108,6 +108,10 @@ def test_device_facts_small(capsys):
     out = capsys.readouterr().out
     assert "0 of 4096 values changed" in out and "device->host" in out
     assert "as two 32-bit planes" in out
+    # the streamed preload against the whole-array put, as numbers and as bits
+    assert set(facts["preload_stream"]) == {"float64", "uint64"}
+    assert out.count("streamed preload") == 2 and "0 of 9216 values differ" in out
+    assert f"h2d_stream_bytes={3 * 64 * 64 * 8}" in out
 
 
 def test_check_mesh_shares():

@@ -1,0 +1,588 @@
+"""A stored source goes to the device chunk by chunk through reused staging
+buffers.
+
+Without a mesh ``JaxExecutor._device_put`` walks a stored array's chunk grid
+(``_stream_to_device``): each chunk file is read into one of two host
+buffers that the executor keeps, put on the device from there and written
+into its place in one resident array, updated in place. The device value is
+bit for bit what the whole-array route gives (the array assembled on the
+host, put in one piece), which a stored array still takes where HBM lacks the
+room, and which one chunk, a 0-d array, a record array and a mesh always
+take. The reads stay the store's: cancellation, injected faults, retries and
+breaker pacing, byte accounting, verification with quarantine."""
+
+from __future__ import annotations
+
+import mmap
+import os
+
+import numpy as np
+import pytest
+
+import cubed_tpu as ct
+import cubed_tpu.array_api as xp
+import cubed_tpu.runtime.executors.jax as jx
+from cubed_tpu.observability.accounting import task_scope
+from cubed_tpu.observability.metrics import get_registry
+from cubed_tpu.runtime import faults
+from cubed_tpu.runtime.cancellation import CancellationToken, ComputeCancelledError
+from cubed_tpu.runtime.executors.jax import JaxExecutor
+from cubed_tpu.storage import health, integrity
+from cubed_tpu.storage.integrity import ChunkIntegrityError
+from cubed_tpu.storage.store import _LocalIO, open_zarr_array
+
+RNG = np.random.default_rng(29)
+
+#: float64 bit patterns that a careless route changes: NaNs with a payload
+#: and a sign, both zeros, both infinities, the least and the largest
+#: subnormal, the extremes of the normal range
+EDGE_BITS = np.array(
+    [0x7FF8000000000123, 0xFFF0000000000ABC, 0x8000000000000000, 0,
+     0x7FF0000000000000, 0xFFF0000000000000, 1, 0x000FFFFFFFFFFFFF,
+     0x7FEFFFFFFFFFFFFF, 0x0010000000000000],
+    np.uint64,
+)
+EDGE_BITS_32 = np.array(
+    [0x7FC00123, 0xFF800ABC, 0x80000000, 0, 0x7F800000, 0xFF800000, 1,
+     0x007FFFFF, 0x7F7FFFFF, 0x00800000],
+    np.uint32,
+)
+
+
+def _values(dtype, shape) -> np.ndarray:
+    """Values of ``dtype`` that fill its range, edge values among them."""
+    dtype = np.dtype(dtype)
+    n = int(np.prod(shape))
+    if dtype == np.bool_:
+        flat = RNG.integers(0, 2, n).astype(np.bool_)
+    elif dtype.kind in "iu":
+        info = np.iinfo(dtype)
+        flat = RNG.integers(info.min, info.max, n, dtype=dtype, endpoint=True)
+    elif dtype.kind == "c":
+        flat = (RNG.standard_normal(n) + 1j * RNG.standard_normal(n)).astype(dtype)
+        flat.view(np.float64)[: EDGE_BITS.size] = EDGE_BITS.view(np.float64)
+    else:
+        flat = (RNG.standard_normal(n) * 1e3).astype(dtype)
+        edges = EDGE_BITS.view(np.float64) if dtype == np.float64 else EDGE_BITS_32.view(np.float32)
+        # edge values at both ends, so that the first and the last chunk
+        # (a ragged one) both hold some
+        flat[: edges.size] = edges
+        flat[-edges.size :] = edges[::-1]
+    return flat.reshape(shape)
+
+
+def _stored(tmp_path, host, chunks, name="a", **kwargs):
+    z = open_zarr_array(
+        str(tmp_path / f"{name}.zarr"), "w", shape=host.shape, dtype=host.dtype,
+        chunks=chunks, **kwargs,
+    )
+    z[...] = host
+    return z
+
+
+def _put(z, executor=None, carry_bits=False):
+    """``z`` through ``_device_put`` as ``_preload`` calls it: (fetched device
+    value, the executor)."""
+    executor = executor or JaxExecutor()
+    executor._carry_bits = carry_bits
+    value = executor._device_put(z, tuple(z.shape), z.chunkset() if z.shape else None)
+    return np.asarray(value), executor
+
+
+def _whole(z, carry_bits=False):
+    """``z`` by the whole-array route: an executor whose budget holds the
+    array and nothing beside it declines the stream."""
+    executor = JaxExecutor(device_mem=max(z.nbytes, 1))
+    got, executor = _put(z, executor, carry_bits)
+    assert executor.stats["h2d_stream_bytes"] == 0
+    return got, executor
+
+
+def _same_bits(a: np.ndarray, b: np.ndarray) -> bool:
+    return a.dtype == b.dtype and a.shape == b.shape and a.tobytes() == b.tobytes()
+
+
+# -- the same device value, bit for bit ---------------------------------------
+
+
+@pytest.mark.parametrize(
+    "dtype, carry_bits",
+    [(np.float64, False), (np.float64, True), (np.float32, False),
+     (np.int64, False), (np.uint8, False), (np.bool_, False),
+     (np.complex128, False)],
+    ids=["float64", "float64_as_bits", "float32", "int64", "uint8", "bool",
+         "complex128"],
+)
+def test_streamed_value_is_the_whole_array_routes_bit_for_bit(tmp_path, dtype, carry_bits):
+    host = _values(dtype, (23, 17))
+    z = _stored(tmp_path, host, (8, 5))
+    streamed, ex = _put(z, carry_bits=carry_bits)
+    whole, declined = _whole(z, carry_bits=carry_bits)
+    assert _same_bits(streamed, whole)
+    on_device = np.uint64 if carry_bits else dtype
+    assert streamed.dtype == on_device
+    assert streamed.view(host.dtype).tobytes() == host.tobytes()
+    # every chunk went through the staging buffers, padded as it is stored
+    padded = z.nchunks * z._chunk_nbytes()
+    assert ex.stats["h2d_stream_bytes"] == ex.stats["h2d_bytes"] == padded
+    assert ex.stats["f64_as_bits"] == declined.stats["f64_as_bits"] == int(carry_bits)
+    assert declined.stats["h2d_bytes"] == host.nbytes
+    assert declined.stats["h2d_stream_declined"] == 1
+    assert "h2d_stream_declined" not in ex.stats
+
+
+@pytest.mark.parametrize(
+    "shape, chunks",
+    [((29,), (8,)), ((13, 22), (5, 8)), ((7, 9, 11), (3, 4, 5)), ((12, 9), (4, 3))],
+    ids=["1d", "2d", "3d", "2d_no_edge"],
+)
+@pytest.mark.parametrize("carry_bits", [False, True], ids=["numbers", "bits"])
+def test_ragged_edge_chunks_land_in_their_place(tmp_path, shape, chunks, carry_bits):
+    host = _values(np.float64, shape)
+    z = _stored(tmp_path, host, chunks)
+    streamed, ex = _put(z, carry_bits=carry_bits)
+    assert streamed.shape == shape
+    assert streamed.view(np.float64).tobytes() == host.tobytes()
+    assert _same_bits(streamed, _whole(z, carry_bits=carry_bits)[0])
+    assert ex.stats["h2d_stream_bytes"] == z.nchunks * z._chunk_nbytes()
+
+
+@pytest.mark.parametrize("case", range(EDGE_BITS.size))
+def test_an_edge_value_in_every_position_of_a_chunk_survives(tmp_path, case):
+    """One edge value fills the array: no zero written around it by the
+    allocation, no default beside it, can hide a changed bit."""
+    host = np.full((6, 10), EDGE_BITS[case], np.uint64).view(np.float64)
+    z = _stored(tmp_path, host, (4, 4))
+    for carry_bits in (False, True):
+        streamed, _ = _put(z, carry_bits=carry_bits)
+        assert streamed.view(np.uint64).tobytes() == host.tobytes()
+
+
+def test_a_chunk_never_written_reads_as_the_fill_value(tmp_path):
+    host = _values(np.float64, (8, 8))
+    z = _stored(tmp_path, host, (4, 4), fill_value=-7.5)
+    os.remove(os.path.join(z.store, "1.0"))
+    expected = host.copy()
+    expected[4:, :4] = -7.5
+    streamed, ex = _put(z)
+    assert streamed.tobytes() == expected.tobytes()
+    assert _same_bits(streamed, _whole(z)[0])
+    assert ex.stats["h2d_stream_bytes"] == ex.stats["h2d_bytes"] == host.nbytes
+
+
+def test_a_missing_chunk_that_the_manifest_lists_is_an_integrity_error(tmp_path):
+    with integrity.scoped("write"):
+        z = _stored(tmp_path, _values(np.float64, (8, 8)), (4, 4))
+    assert "1.0" in z._manifest()[0]
+    os.remove(os.path.join(z.store, "1.0"))
+    with integrity.scoped("verify"), task_scope():
+        with pytest.raises(ChunkIntegrityError) as info:
+            _put(z)
+    assert info.value.kind == "missing" and info.value.chunk_key == "1.0"
+
+
+@pytest.mark.parametrize("compressor", [{"id": "zlib", "level": 1}, {"id": "lzma"}],
+                         ids=["zlib", "lzma"])
+def test_a_compressed_store_streams_what_the_codec_hands_over(tmp_path, compressor):
+    host = _values(np.float64, (11, 10))
+    z = _stored(tmp_path, host, (4, 4), compressor=compressor)
+    streamed, ex = _put(z)
+    assert streamed.tobytes() == host.tobytes()
+    assert _same_bits(streamed, _whole(z)[0])
+    assert ex.stats["h2d_stream_bytes"] == ex.stats["h2d_bytes"] == z.nchunks * z._chunk_nbytes()
+
+
+def test_an_io_class_without_readinto_streams_the_bytes_it_reads(tmp_path, monkeypatch):
+    host = _values(np.int64, (9, 9))
+    z = _stored(tmp_path, host, (4, 4))
+    monkeypatch.delattr(_LocalIO, "readinto")
+    streamed, ex = _put(z)
+    assert streamed.tobytes() == host.tobytes()
+    assert ex.stats["h2d_stream_bytes"] == ex.stats["h2d_bytes"] > 0
+
+
+# -- what takes the whole-array route ---------------------------------------------
+
+
+def _one_chunk(tmp_path):
+    return _stored(tmp_path, _values(np.float64, (6, 5)), (6, 5))
+
+
+def _zero_d(tmp_path):
+    return _stored(tmp_path, np.array(2.5), ())
+
+
+def _record(tmp_path):
+    host = np.zeros((6, 4), dtype=[("x", np.float64), ("n", np.int32)])
+    host["x"], host["n"] = _values(np.float64, (6, 4)), np.arange(24).reshape(6, 4)
+    return _stored(tmp_path, host, (3, 2))
+
+
+def _empty(tmp_path):
+    return _stored(tmp_path, np.zeros((0, 6)), (1, 3))
+
+
+@pytest.mark.parametrize("make", [_one_chunk, _zero_d, _record, _empty],
+                         ids=["one_chunk", "zero_d", "record", "empty"])
+def test_what_is_not_several_plain_chunks_takes_the_whole_array_route(tmp_path, make):
+    z = make(tmp_path)
+    executor = JaxExecutor()
+    value = executor._device_put(z, tuple(z.shape), None)
+    host = z[...] if z.shape else z[()]
+    if isinstance(value, dict):
+        assert all(np.asarray(value[k]).tobytes() == np.ascontiguousarray(host[k]).tobytes()
+                   for k in host.dtype.names)
+    else:
+        assert np.asarray(value).tobytes() == np.asarray(host).tobytes()
+    assert executor.stats["h2d_stream_bytes"] == 0
+    assert executor.stats["h2d_bytes"] == host.nbytes
+    # not for want of room: nothing here qualified by kind
+    assert "h2d_stream_declined" not in executor.stats
+    assert all(stage.buffer is None for stage in executor._staging)
+
+
+def test_a_host_array_is_put_in_one_piece(tmp_path):
+    host = _values(np.float64, (8, 8))
+    executor = JaxExecutor()
+    assert np.asarray(executor._device_put(host, host.shape)).tobytes() == host.tobytes()
+    assert executor.stats["h2d_stream_bytes"] == 0 and executor.stats["h2d_bytes"] == host.nbytes
+
+
+def test_under_a_mesh_the_shard_by_shard_callback_stays(tmp_path):
+    import jax
+
+    from cubed_tpu.parallel.mesh import make_mesh
+
+    host = _values(np.float64, (16, 8))
+    z = _stored(tmp_path, host, (4, 4))
+    executor = JaxExecutor(mesh=make_mesh(devices=jax.devices()[:4]))
+    value = executor._device_put(z, tuple(z.shape), z.chunkset())
+    assert len(value.sharding.device_set) == 4
+    assert np.asarray(value).tobytes() == host.tobytes()
+    assert executor.stats["h2d_stream_bytes"] == 0
+    assert "h2d_stream_declined" not in executor.stats
+    assert all(stage.buffer is None for stage in executor._staging)
+
+
+@pytest.mark.parametrize("short_by", [1, 4 * 4 * 8], ids=["a_byte", "a_chunk"])
+def test_no_room_for_the_array_and_two_chunks_takes_it_and_is_counted(tmp_path, short_by):
+    host = _values(np.float64, (8, 8))
+    z = _stored(tmp_path, host, (4, 4))
+    needed = z.nbytes + 2 * z._chunk_nbytes()
+    roomy, tight = JaxExecutor(device_mem=needed), JaxExecutor(device_mem=needed - short_by)
+    streamed, _ = _put(z, roomy)
+    whole, _ = _put(z, tight)
+    assert _same_bits(streamed, whole)
+    assert roomy.stats["h2d_stream_bytes"] == host.nbytes
+    assert "h2d_stream_declined" not in roomy.stats
+    assert tight.stats["h2d_stream_bytes"] == 0 and tight.stats["h2d_stream_declined"] == 1
+
+
+def test_what_is_resident_counts_against_the_room(tmp_path):
+    z = _stored(tmp_path, _values(np.float64, (8, 8)), (4, 4))
+    executor = JaxExecutor(device_mem=z.nbytes + 2 * z._chunk_nbytes() + 100)
+    executor._resident = {"held": jx._Resident(None, 101, None)}
+    _put(z, executor)
+    assert executor.stats["h2d_stream_declined"] == 1
+    executor._resident = {"held": jx._Resident(None, 100, None)}
+    _put(z, executor)
+    assert executor.stats["h2d_stream_declined"] == 1
+    assert executor.stats["h2d_stream_bytes"] == z.nbytes
+
+
+@pytest.mark.parametrize("dtype, twice", [(np.float64, True), (np.int64, True),
+                                          (np.complex128, True), (np.float32, False),
+                                          (np.complex64, False)])
+def test_a_pair_device_needs_the_room_twice_for_64_bit_elements(
+    tmp_path, monkeypatch, dtype, twice
+):
+    """A device that holds 64-bit elements as pairs updates through a split
+    copy of the array and of the chunk."""
+    monkeypatch.setattr(jx, "_float64_round_trips", lambda device: False)
+    z = _stored(tmp_path, _values(dtype, (8, 8)), (4, 4))
+    needed = (z.nbytes + 2 * z._chunk_nbytes()) * (2 if twice else 1)
+    for budget, streams in ((needed, True), (needed - 1, False)):
+        executor = JaxExecutor(device_mem=budget)
+        assert executor._streams(z) is streams
+        assert executor.stats["h2d_stream_declined"] == int(not streams)
+
+
+def test_a_pair_device_is_not_sent_a_64_bit_array_in_many_chunks(tmp_path, monkeypatch):
+    """Every update of a 64-bit array is a pass over all of it there."""
+    fine = _stored(tmp_path, _values(np.float64, (65, 4)), (1, 4), name="fine")
+    coarse = _stored(tmp_path, _values(np.float64, (64, 4)), (1, 4), name="coarse")
+    narrow = _stored(tmp_path, _values(np.float32, (65, 4)), (1, 4), name="narrow")
+    assert (fine.nchunks, coarse.nchunks) == (65, jx._PAIR_STREAM_MAX_CHUNKS)
+    assert all(JaxExecutor()._streams(z) for z in (fine, coarse, narrow))
+    monkeypatch.setattr(jx, "_float64_round_trips", lambda device: False)
+    executor = JaxExecutor()
+    assert not executor._streams(fine)
+    assert executor._streams(coarse) and executor._streams(narrow)
+    got, _ = _put(fine, executor)
+    assert got.tobytes() == fine[...].tobytes()
+    assert executor.stats["h2d_stream_bytes"] == 0
+    assert "h2d_stream_declined" not in executor.stats  # not for want of room
+
+
+# -- through a compute -------------------------------------------------------------
+
+
+class _Capture:
+    stats = None
+
+    def on_compute_end(self, event):
+        self.stats = event.executor_stats
+
+
+@pytest.fixture
+def sources(tmp_path):
+    spec = ct.Spec(work_dir=str(tmp_path / "work"), allowed_mem="200MB")
+    # plain values: what an add makes of edge values is not the stream's doing
+    hosts = [RNG.standard_normal((30, 20)) for _ in "ab"]
+    paths = []
+    for name, host in zip("ab", hosts):
+        paths.append(_stored(tmp_path, host, (8, 8), name=name).store)
+    return spec, paths, hosts
+
+
+def test_a_compute_streams_every_source_and_says_so(sources, tmp_path):
+    spec, (pa, pb), (a, b) = sources
+    cap = _Capture()
+    executor = JaxExecutor()
+    ct.to_zarr(
+        xp.add(ct.from_zarr(pa, spec=spec), ct.from_zarr(pb, spec=spec)),
+        str(tmp_path / "c.zarr"), executor=executor, callbacks=[cap],
+    )
+    got = ct.from_zarr(str(tmp_path / "c.zarr"), spec=spec).compute()
+    np.testing.assert_array_equal(got, a + b)
+    padded = 2 * 4 * 3 * 8 * 8 * 8
+    assert cap.stats["h2d_stream_bytes"] == cap.stats["h2d_bytes"] == padded
+    assert not cap.stats.get("h2d_stream_declined")
+    assert cap.stats["bytes_read"] == padded and cap.stats["chunks_read"] == 24
+    # both sources went through the same two buffers, each one chunk large
+    assert [stage.buffer.nbytes for stage in executor._staging] == [8 * 8 * 8] * 2
+
+
+def test_a_copy_of_a_stored_array_carries_its_bits_chunk_by_chunk(tmp_path, monkeypatch):
+    """A compute that only moves values streams float64 as uint64."""
+    spec = ct.Spec(work_dir=str(tmp_path / "work"), allowed_mem="200MB")
+    host = _values(np.float64, (20, 12))
+    z = _stored(tmp_path, host, (8, 8))
+    cap = _Capture()
+    # the CPU holds a real float64 and so carries nothing as bits by itself
+    monkeypatch.setattr(jx, "_float64_round_trips", lambda device: False)
+    ct.to_zarr(
+        ct.from_zarr(z.store, spec=spec).rechunk((10, 6)), str(tmp_path / "out.zarr"),
+        executor=JaxExecutor(), callbacks=[cap],
+    )
+    out = open_zarr_array(str(tmp_path / "out.zarr"), "r")
+    assert out[...].tobytes() == host.tobytes()
+    assert cap.stats["f64_as_bits"] >= 1
+    assert cap.stats["h2d_stream_bytes"] == cap.stats["h2d_bytes"] == z.nchunks * z._chunk_nbytes()
+
+
+def test_the_counter_is_present_and_zero_where_nothing_streamed(tmp_path):
+    spec = ct.Spec(work_dir=str(tmp_path / "work"), allowed_mem="200MB")
+    a = ct.from_array(np.arange(36.0).reshape(6, 6), chunks=(3, 3), spec=spec)
+    cap = _Capture()
+    assert float(xp.sum(a).compute(executor=JaxExecutor(), callbacks=[cap])) == 630.0
+    assert "h2d_stream_bytes" in cap.stats and cap.stats["h2d_stream_bytes"] == 0
+
+
+# -- the reads are still the store's ----------------------------------------------
+
+
+@pytest.fixture
+def _fresh_breakers():
+    health.reset_breakers()
+    yield
+    health.reset_breakers()
+
+
+def test_injected_read_faults_are_retried_in_place(tmp_path, _fresh_breakers):
+    host = _values(np.float64, (16, 16))
+    z = _stored(tmp_path, host, (4, 4))
+    retries = get_registry().counter("storage_read_retries")
+    before = retries.value
+    with faults.scoped(faults.FaultConfig(seed=3, storage_read_failure_rate=0.3)):
+        with task_scope() as scope:
+            streamed, ex = _put(z)
+    assert streamed.tobytes() == host.tobytes()
+    assert retries.value > before
+    assert scope.chunks_read == 16 and scope.bytes_read == host.nbytes
+    assert ex.stats["h2d_stream_bytes"] == host.nbytes
+
+
+def test_a_read_that_keeps_failing_raises_out_of_the_stream(tmp_path, _fresh_breakers):
+    z = _stored(tmp_path, _values(np.float64, (8, 8)), (4, 4))
+    with faults.scoped(faults.FaultConfig(seed=3, storage_read_failure_rate=1.0)):
+        with task_scope(), pytest.raises(faults.FaultInjectedIOError):
+            _put(z)
+
+
+def test_injected_throttles_are_paced_by_the_breaker(tmp_path, _fresh_breakers):
+    host = _values(np.float64, (16, 16))
+    z = _stored(tmp_path, host, (4, 4))
+    with faults.scoped(faults.FaultConfig(seed=23, storage_throttle_rate=0.25)):
+        with task_scope() as scope:
+            streamed, _ = _put(z)
+    assert streamed.tobytes() == host.tobytes()
+    assert scope.counters.get("store_throttled", 0) > 0
+    assert health.store_breaker(z.store).state != "closed"
+
+
+def test_mode_verify_checks_the_staged_bytes_and_quarantines_a_corrupted_chunk(tmp_path):
+    host = _values(np.float64, (8, 8))
+    with integrity.scoped("write"):
+        z = _stored(tmp_path, host, (4, 4))
+    with integrity.scoped("verify"), task_scope() as scope:
+        streamed, _ = _put(z)
+    assert streamed.tobytes() == host.tobytes()
+    assert scope.counters["chunks_verified"] == 4
+    # one flipped bit in the third chunk
+    path = os.path.join(z.store, "1.0")
+    raw = bytearray(open(path, "rb").read())
+    raw[17] ^= 0x10
+    open(path, "wb").write(bytes(raw))
+    with integrity.scoped("verify"), task_scope() as scope:
+        with pytest.raises(ChunkIntegrityError) as info:
+            _put(z)
+    assert info.value.kind == "checksum" and info.value.chunk_key == "1.0"
+    assert scope.counters["chunks_quarantined"] == 1
+    assert not os.path.exists(path)
+    assert any(n.startswith("1.0.quarantine.") for n in os.listdir(z.store))
+    # unverified, the flipped bit would have gone through: the check is the
+    # staged bytes' and not a formality
+    open(path, "wb").write(bytes(raw))
+    with integrity.scoped("write"), task_scope():
+        assert _put(z)[0].tobytes() != host.tobytes()
+
+
+def test_a_file_longer_than_a_chunk_is_not_cut_to_fit_the_buffer(tmp_path):
+    z = _stored(tmp_path, _values(np.float64, (8, 8)), (4, 4))
+    with open(os.path.join(z.store, "0.1"), "ab") as f:
+        f.write(b"\0" * 8)
+    with pytest.raises(ValueError):
+        _put(z)
+
+
+def test_a_cancel_lands_between_chunks(sources, tmp_path, monkeypatch):
+    spec, (pa, pb), _ = sources
+    token = CancellationToken()
+    reads = []
+    real = _LocalIO.readinto
+
+    def readinto(self, name, buffer):
+        reads.append(name)
+        if len(reads) == 3:
+            token.cancel("the test asked")
+        return real(self, name, buffer)
+
+    monkeypatch.setattr(_LocalIO, "readinto", readinto)
+    with pytest.raises(ComputeCancelledError):
+        ct.to_zarr(
+            xp.add(ct.from_zarr(pa, spec=spec), ct.from_zarr(pb, spec=spec)),
+            str(tmp_path / "c.zarr"), executor=JaxExecutor(), cancellation=token,
+        )
+    # the read in flight finished; the next chunk's was never started
+    assert len(reads) == 3
+
+
+# -- the staging buffers -----------------------------------------------------------
+
+
+def _spy_on_reads(monkeypatch):
+    """Records (address of the buffer, chunk key) of every read-into."""
+    seen = []
+    real = _LocalIO.readinto
+
+    def readinto(self, name, buffer):
+        seen.append((np.asarray(buffer).__array_interface__["data"][0], name))
+        return real(self, name, buffer)
+
+    monkeypatch.setattr(_LocalIO, "readinto", readinto)
+    return seen
+
+
+def test_two_buffers_take_turns_across_chunks_and_sources(tmp_path, monkeypatch):
+    a = _stored(tmp_path, _values(np.float64, (12, 8)), (4, 4), name="a")
+    b = _stored(tmp_path, _values(np.float64, (8, 8)), (4, 4), name="b")
+    seen = _spy_on_reads(monkeypatch)
+    executor = JaxExecutor()
+    got_a, _ = _put(a, executor)
+    first = [stage.buffer for stage in executor._staging]
+    got_b, _ = _put(b, executor)
+    assert got_a.tobytes() == a[...].tobytes() and got_b.tobytes() == b[...].tobytes()
+    assert all(stage.buffer is kept for stage, kept in zip(executor._staging, first))
+    addresses = [address for address, _ in seen]
+    assert len(addresses) == 6 + 4 and len(set(addresses)) == 2
+    # they alternate within a source; each source starts with the first
+    assert addresses[:6] == addresses[:2] * 3 and addresses[6:] == addresses[:2] * 2
+    assert all(stage.buffer.nbytes == 4 * 4 * 8 for stage in executor._staging)
+    # each starts on a page boundary, as the page cache's pages do
+    assert all(address % mmap.PAGESIZE == 0 for address in addresses)
+
+
+def test_the_buffers_grow_to_the_largest_chunk_seen_and_go_with_the_executor(tmp_path):
+    import gc
+    import weakref
+
+    small = _stored(tmp_path, _values(np.float64, (8, 8)), (4, 4), name="small")
+    large = _stored(tmp_path, _values(np.float64, (16, 16)), (8, 8), name="large")
+    executor = JaxExecutor()
+    _put(small, executor)
+    assert [s.buffer.nbytes for s in executor._staging] == [128, 128]
+    _put(large, executor)
+    assert [s.buffer.nbytes for s in executor._staging] == [512, 512]
+    kept = [s.buffer for s in executor._staging]
+    _put(small, executor)  # a smaller chunk reuses the larger buffers
+    assert all(s.buffer is k for s, k in zip(executor._staging, kept))
+    refs = [weakref.ref(s.buffer) for s in executor._staging]
+    other = JaxExecutor()
+    assert all(s.buffer is None for s in other._staging)  # nothing is shared
+    # a put value may alias a buffer on the CPU backend: wait the updates out
+    for stage in executor._staging:
+        stage.release()
+    del executor, kept, stage
+    gc.collect()
+    assert all(ref() is None for ref in refs)
+
+
+def test_a_buffer_is_not_rewritten_before_the_update_that_read_it_is_ready(
+    tmp_path, monkeypatch
+):
+    """The reads made instant (a memcpy from memory, no file), so that the
+    host is always ahead of the device: were a buffer rewritten while its
+    transfer or update still read it, some chunk would hold another's
+    values. The waits are counted too: every update but a source's last is
+    waited for inside the stream, and that one before its buffer's next use."""
+    host = _values(np.float64, (64, 96))
+    z = _stored(tmp_path, host, (8, 8))
+    files = {name: open(os.path.join(z.store, name), "rb").read()
+             for name in os.listdir(z.store) if not name.startswith(".")}
+
+    def readinto(self, name, buffer):
+        view = memoryview(buffer).cast("B")[: len(files[name])]
+        view[:] = files[name]
+        return view
+
+    monkeypatch.setattr(_LocalIO, "readinto", readinto)
+    waits = []
+    real_release = jx._Staging.release
+
+    def release(self):
+        waits.append(self.busy is not None)
+        real_release(self)
+        assert self.busy is None
+
+    monkeypatch.setattr(jx._Staging, "release", release)
+    executor = JaxExecutor()
+    for _ in range(3):
+        got, _ = _put(z, executor)
+        assert got.tobytes() == host.tobytes()
+    assert len({id(s.buffer) for s in executor._staging}) == 2
+    # a release ahead of every read and one at the end of every chunk's span
+    assert len(waits) == 3 * 2 * z.nchunks
+    # and a real wait for every update: none is left unwaited but the last
+    assert sum(waits) == 3 * z.nchunks - 1
+    assert sum(s.busy is not None for s in executor._staging) == 1
