@@ -67,16 +67,13 @@ import cubed_tpu.random
 from cubed_tpu.parallel.mesh import make_mesh
 from cubed_tpu.runtime.executors.jax import JaxExecutor
 
-#: a non-zero count means some op left the fused device path (an exception
-#: was swallowed and the op re-ran per chunk or eagerly, or a segment was
-#: refused for memory): the run fails
+#: a non-zero count means some op left the fused device path by one of the
+#: executor's designed aborts (a segment ran eagerly, a kernel that needs
+#: concrete values ran un-jitted, a segment was refused for memory): the run
+#: fails. Anything else that goes wrong on that path raises
 FAILURE_COUNTERS = (
     "eager_fallbacks",
     "trace_failures",
-    "whole_array_errors",
-    "batched_errors",
-    "whole_select_errors",
-    "jit_kernel_errors",
     "segment_mem_aborts",
 )
 

@@ -18,10 +18,19 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   broadcasting chains, including everything the optimizer fused) and whose
   block mapping is 1:1-with-broadcast run as ONE jitted call on whole resident
   arrays — XLA fuses the entire chain; intermediates stay in registers/HBM.
-- **Chunked fallback.** Any other op (tree-reduce combines, map_direct,
+- **Chunked route.** Any other op (tree-reduce combines, map_direct,
   index, reshape, block_id kernels) runs per output chunk: inputs are sliced
   from resident arrays on device (XLA slice, no host transfer), the chunk
   kernel is jitted once per shape, and results assemble by concatenation.
+- **An exception is an error.** A route is chosen from what the plan says
+  and declines by returning ``None``; what it raises reaches the caller from
+  where it was raised, but for two designed aborts, each a type handled at
+  one site. ``_TraceAbort`` (a source that is not resident, or a flush,
+  inside a trace) sends the segment to the eager route (``_run_segment``).
+  What JAX raises when a kernel asks a tracer for a concrete value
+  (``_needs_concrete_value``) does the same inside a trace, and at an eager
+  op runs the kernel un-jitted on concrete chunks, once
+  (``_exec_blockwise``, ``stats["host_kernel_ops"]``).
 - **Rechunk is free.** Resident arrays are whole arrays, so a rechunk op is
   pure metadata (an alias). Under a device mesh the corresponding physical
   movement is a resharding (``device_put`` with a new NamedSharding), which
@@ -127,6 +136,22 @@ def _jax():
     import jax
 
     return jax
+
+
+def _needs_concrete_value() -> tuple:
+    """What JAX raises when a kernel asks a tracer for a concrete value, as
+    upstream's ``map_blocks`` functions on numpy blocks do: with
+    ``_TraceAbort`` all that route code handles. Named one by one: their
+    bases ``JAXTypeError`` and ``JAXIndexError`` also hold
+    ``UnexpectedTracerError`` and ``KeyReuseError``, a kernel's bugs."""
+    errors = _jax().errors
+    return (
+        errors.TracerArrayConversionError,  # np.asarray(x), np.sort(x)
+        # float(x), x.item(); its subclass TracerBoolConversionError: if x > 0
+        errors.ConcretizationTypeError,
+        errors.TracerIntegerConversionError,  # range(x), x as a Python index
+        errors.NonConcreteBooleanIndexError,  # x[mask]: the shape is a value
+    )
 
 
 class _Resident:
@@ -320,10 +345,10 @@ class JaxExecutor(DagExecutor):
         #: without a mesh) and the ``_MESH_COUNTERS`` of the segment programs
         #: (``segment_collectives`` and its kinds, ``sharded_bytes`` /
         #: ``replicated_bytes``; ``segment_hbm_footprint`` is per chip), and the
-        #: failure counters ``eager_fallbacks`` / ``trace_failures`` /
-        #: ``whole_array_errors`` / ``batched_errors`` / ``whole_select_errors``
-        #: / ``jit_kernel_errors``
-        #: (``eager_fallbacks`` must stay 0 on fused-path plans — tests pin it)
+        #: counters of the designed aborts: ``trace_failures`` (segments sent
+        #: to the eager route), ``host_kernel_ops`` (eager ops whose kernel
+        #: ran un-jitted), ``eager_fallbacks`` (both; must stay 0 on
+        #: fused-path plans, tests pin it)
         self.stats: Counter = Counter()
 
     @property
@@ -1008,7 +1033,6 @@ class JaxExecutor(DagExecutor):
     def _run_segment(
         self, ops, dag, resident, budget, requested_stores, callbacks
     ) -> None:
-        jax = _jax()
         t0 = time.time()
         for name, node in ops:
             callbacks_on(
@@ -1023,6 +1047,7 @@ class JaxExecutor(DagExecutor):
         # a retryable task, so enforcement (which degrades via retry) makes
         # no sense here — but the host-RSS measurement still feeds the
         # projected-vs-measured summary and observe-mode warnings
+        from ...observability.collect import record_decision
         from ..memory import task_guard
 
         seg_key = ",".join(name for name, _ in ops)
@@ -1030,30 +1055,26 @@ class JaxExecutor(DagExecutor):
             f"segment:{seg_key}", observe_only=True
         ) as guard:
             traced = False
-            if len(ops) > 0:
-                try:
-                    traced = self._trace_segment(
-                        ops, dag, resident, budget, requested_stores
-                    )
-                    if traced:
-                        self.stats["segments_traced"] += 1
-                    else:
-                        self.stats["segment_mem_aborts"] += 1
-                        from ...observability.collect import record_decision
-
-                        record_decision(
-                            "jax_segment_mem_abort", segment=seg_key
-                        )
-                except Exception:
-                    logger.exception(
-                        "segment trace failed; falling back to eager"
-                    )
-                    self.stats["trace_failures"] += 1
-                    self.stats["eager_fallbacks"] += 1
-                    from ...observability.collect import record_decision
-
-                    record_decision("jax_eager_fallback", segment=seg_key)
-                    traced = False
+            try:
+                traced = self._trace_segment(
+                    ops, dag, resident, budget, requested_stores
+                )
+            except (_TraceAbort, *_needs_concrete_value()) as abort:
+                # the two designed aborts and nothing else: a cancel, a
+                # kernel's own exception, a storage or integrity error of a
+                # preload, a bug in a route reach the caller as they are
+                self.stats["trace_failures"] += 1
+                self.stats["eager_fallbacks"] += 1
+                record_decision(
+                    "jax_eager_fallback", segment=seg_key,
+                    reason=type(abort).__name__,
+                )
+            else:
+                if traced:
+                    self.stats["segments_traced"] += 1
+                else:
+                    self.stats["segment_mem_aborts"] += 1
+                    record_decision("jax_segment_mem_abort", segment=seg_key)
             if not traced:
                 for name, node in ops:
                     primitive_op = node["primitive_op"]
@@ -1299,9 +1320,8 @@ class JaxExecutor(DagExecutor):
         """Trace every op in the segment into one jitted program and run it.
 
         Returns False when the segment should run eagerly instead (memory
-        pre-check failed); raises on trace failure (caller falls back)."""
-        jax = _jax()
-
+        pre-check failed); raises on trace failure (the caller falls back
+        on a designed abort alone)."""
         preload, offsets_arrays = self._segment_sources(ops)
         for arr in preload:
             self._preload(arr, resident, budget)
@@ -1516,9 +1536,7 @@ class JaxExecutor(DagExecutor):
                 ws = getattr(spec.function, "whole_select", None)
                 if ws is not None:
                     value = self._apply_whole_select(res.value, ws)
-                    if value is not None and (
-                        isinstance(value, dict) or tuple(value.shape) == out_shape
-                    ):
+                    if isinstance(value, dict) or tuple(value.shape) == out_shape:
                         res.touch()
                         self._admit(resident, out_store, value, target, budget)
                         return
@@ -1532,52 +1550,18 @@ class JaxExecutor(DagExecutor):
                 if skey in resident:
                     self._flush(resident[skey])
 
-        inputs = self._whole_inputs(spec, resident)
-
-        value = None
-        if (
-            spec.shape_invariant
-            and not spec.writes_rest
-            and not getattr(spec.function, "needs_block_id", False)
-        ):
-            mapping = self._probe_one_to_one(spec, op)
-            if mapping and inputs is not None:
-                try:
-                    fn = jax.jit(spec.function)
-                    full = [inputs[n] for n in mapping]
-                    value = fn(*full)
-                    if not isinstance(value, dict) and tuple(value.shape) != out_shape:
-                        value = None  # kernel wasn't truly shape-invariant
-                    else:
-                        self.stats["whole_array_hits"] += 1
-                except _TraceAbort:
-                    raise
-                except Exception:
-                    logger.exception("whole-array path failed; falling back")
-                    self.stats["whole_array_errors"] += 1
-                    self.stats["eager_fallbacks"] += 1
-                    value = None
-
-        if (
-            value is None
-            and not getattr(spec.function, "needs_block_id", False)
-            and not getattr(spec.function, "host_block_id", False)
-        ):
-            try:
-                value = self._exec_batched(op, spec, resident)
-                if value is not None:
-                    self.stats["batched_ops"] += 1
-            except _TraceAbort:
-                raise
-            except Exception:
-                logger.exception("batched path failed; falling back")
-                self.stats["batched_errors"] += 1
-                self.stats["eager_fallbacks"] += 1
-                value = None
-
-        if value is None:
-            value = self._exec_chunked(op, spec, resident)
-            self.stats["chunked_ops"] += 1
+        try:
+            value = self._exec_routes(op, spec, resident, out_shape)
+        except _needs_concrete_value() as abort:
+            if self._tracing:
+                raise  # the segment level's: the whole segment runs eagerly
+            # the kernel wants concrete values: it gets concrete chunks, once
+            logger.info(
+                "%s: kernel of %s runs un-jitted", type(abort).__name__, out_store
+            )
+            self.stats["eager_fallbacks"] += 1
+            self.stats["host_kernel_ops"] += 1
+            value = self._exec_chunked(op, spec, resident, jit=False)
 
         if spec.writes_rest:
             # multi-output: value is one device array per output proxy
@@ -1601,42 +1585,63 @@ class JaxExecutor(DagExecutor):
             )
         self._admit(resident, out_store, value, target, budget)
 
+    def _exec_routes(self, op, spec: BlockwiseSpec, resident, out_shape):
+        """The op's value by the first route that takes it: one jitted call
+        on whole arrays, one vmapped call a bucket of chunks, one jitted
+        call a chunk. A route declines (``None``) from what it reads in the
+        plan or in the shape it produced; what a route raises is not caught
+        here."""
+        inputs = self._whole_inputs(spec, resident)
+        host_bound = getattr(spec.function, "needs_block_id", False)
+        value = None
+        if spec.shape_invariant and not spec.writes_rest and not host_bound:
+            mapping = self._probe_one_to_one(spec, op)
+            if mapping and inputs is not None:
+                value = _jax().jit(spec.function)(*(inputs[n] for n in mapping))
+                if isinstance(value, dict) or tuple(value.shape) == out_shape:
+                    self.stats["whole_array_hits"] += 1
+                else:
+                    value = None  # kernel wasn't truly shape-invariant
+        if (
+            value is None
+            and not host_bound
+            and not getattr(spec.function, "host_block_id", False)
+        ):
+            value = self._exec_batched(op, spec, resident)
+            if value is not None:
+                self.stats["batched_ops"] += 1
+        if value is None:
+            value = self._exec_chunked(op, spec, resident)
+        return value
+
     def _apply_whole_select(self, value, selections):
         """Apply a per-axis orthogonal selection to a resident array on device."""
-        jax = _jax()
-        jnp = jax.numpy
-        try:
-            v = value
-            for ax, s in enumerate(selections):
-                if isinstance(s, tuple):  # resolved slice (start, stop, step)
-                    s0, s1, st = s
-                    if st < 0 and s1 < 0:
-                        # .indices() reports "walked past index 0" as stop=-1,
-                        # which a literal slice bound would wrap to the end
-                        s1 = None
-                    sel = (slice(None),) * ax + (slice(s0, s1, st),)
-                    v = (
-                        {k: vv[sel] for k, vv in v.items()}
-                        if isinstance(v, dict)
-                        else v[sel]
-                    )
-                else:
-                    idx = jnp.asarray(np.asarray(s))
-                    v = (
-                        {k: jnp.take(vv, idx, axis=ax) for k, vv in v.items()}
-                        if isinstance(v, dict)
-                        else jnp.take(v, idx, axis=ax)
-                    )
-            return v
-        except Exception:
-            logger.exception("whole-select fast path failed")
-            self.stats["whole_select_errors"] += 1
-            self.stats["eager_fallbacks"] += 1
-            return None
+        jnp = _jax().numpy
+        v = value
+        for ax, s in enumerate(selections):
+            if isinstance(s, tuple):  # resolved slice (start, stop, step)
+                s0, s1, st = s
+                if st < 0 and s1 < 0:
+                    # .indices() reports "walked past index 0" as stop=-1,
+                    # which a literal slice bound would wrap to the end
+                    s1 = None
+                sel = (slice(None),) * ax + (slice(s0, s1, st),)
+                v = (
+                    {k: vv[sel] for k, vv in v.items()}
+                    if isinstance(v, dict)
+                    else v[sel]
+                )
+            else:
+                idx = jnp.asarray(np.asarray(s))
+                v = (
+                    {k: jnp.take(vv, idx, axis=ax) for k, vv in v.items()}
+                    if isinstance(v, dict)
+                    else jnp.take(v, idx, axis=ax)
+                )
+        return v
 
     def _whole_inputs(self, spec: BlockwiseSpec, resident) -> Optional[Dict[str, Any]]:
         """Whole arrays for every input, from residency or storage."""
-        jax = _jax()
         out = {}
         for name, proxy in spec.reads_map.items():
             arr = proxy.array
@@ -1680,10 +1685,7 @@ class JaxExecutor(DagExecutor):
             return None
         names: Optional[list[str]] = None
         for out_key in keys:
-            try:
-                structure = spec.block_function(out_key)
-            except Exception:
-                return None
+            structure = spec.block_function(out_key)
             out_coords = out_key[1:]
             cur = []
             for entry in structure:
@@ -2018,9 +2020,10 @@ class JaxExecutor(DagExecutor):
 
     # ------------------------------------------------------------------
 
-    def _exec_chunked(self, op, spec: BlockwiseSpec, resident):
-        """Per-output-chunk execution with on-device slicing."""
-        jax = _jax()
+    def _exec_chunked(self, op, spec: BlockwiseSpec, resident, jit=True):
+        """Per-output-chunk execution with on-device slicing; with ``jit``
+        false the kernel is called as it is, on concrete chunks."""
+        self.stats["chunked_ops"] += 1
         target = spec.write.array
         out_shape = tuple(target.shape)
         chunkset = (
@@ -2031,10 +2034,10 @@ class JaxExecutor(DagExecutor):
         nb = tuple(len(c) for c in chunkset)
         needs_block_id = getattr(spec.function, "needs_block_id", False)
 
-        jitted = _JitCache(spec.function, self.stats)
+        jitted = _JitCache(spec.function, jit)
         region_fn = getattr(spec.function, "combine_region", None)
         jitted_region = (
-            _JitCache(region_fn, self.stats) if region_fn is not None else None
+            _JitCache(region_fn, jit) if region_fn is not None else None
         )
 
         traced_offsets = self._tracing and getattr(
@@ -2860,15 +2863,16 @@ def _gather_blocks(value, nb, chunk_shape, idx):
 
 
 class _JitCache:
-    """jit a chunk kernel lazily, falling back to eager on trace failure."""
+    """A chunk kernel, jitted on first use unless it is host-bound."""
 
-    def __init__(self, function, stats: Optional[Counter] = None):
+    def __init__(self, function, jit: bool = True):
         self.function = function
-        self.stats = stats
         self._jitted = None
         # host-bound kernels (block_id sync, closed-over host data) can't jit
-        self._use_eager = getattr(function, "host_block_id", False) or bool(
-            getattr(function, "host_data_nbytes", 0)
+        self._use_eager = (
+            not jit
+            or getattr(function, "host_block_id", False)
+            or bool(getattr(function, "host_data_nbytes", 0))
         )
 
     def __call__(self, *args):
@@ -2877,18 +2881,9 @@ class _JitCache:
             isinstance(a, Iterator) or isinstance(a, list) for a in args
         ):
             return self.function(*args)
-        jax = _jax()
         if self._jitted is None:
-            self._jitted = jax.jit(self.function)
-        try:
-            return self._jitted(*args)
-        except Exception:
-            logger.exception("chunk-kernel jit failed; running eagerly")
-            if self.stats is not None:
-                self.stats["jit_kernel_errors"] += 1
-                self.stats["eager_fallbacks"] += 1
-            self._use_eager = True
-            return self.function(*args)
+            self._jitted = _jax().jit(self.function)
+        return self._jitted(*args)
 
 
 def _assemble(chunk_grid: Dict[tuple, Any], nb: tuple[int, ...]):

@@ -60,11 +60,11 @@ def test_zarr_add_leg_small(tmp_path, compile_log, make_executor):
 def test_measured_fails_when_an_op_left_the_device_path(compile_log):
     def compute(callbacks):
         class _Event:
-            executor_stats = {"segments_traced": 1, "batched_errors": 1}
+            executor_stats = {"segments_traced": 1, "trace_failures": 1}
 
         callbacks[0].on_compute_end(_Event())
 
-    with pytest.raises(RuntimeError, match="batched_errors"):
+    with pytest.raises(RuntimeError, match="trace_failures"):
         chip_smoke.measured(compute, compile_log)
 
 

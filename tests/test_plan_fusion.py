@@ -230,7 +230,7 @@ def test_stats_vorticity_plan_fully_fused(spec):
     assert ex.stats["segments_traced"] == 1
     assert ex.stats["trace_failures"] == 0
     assert ex.stats["eager_fallbacks"] == 0
-    assert ex.stats["whole_select_errors"] == 0
+    assert ex.stats["host_kernel_ops"] == 0
 
 
 def test_stats_segment_cache_hit_on_recompute(spec):

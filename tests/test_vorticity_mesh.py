@@ -70,7 +70,7 @@ def test_mesh_result_agrees_with_the_blockwise_reference(tmp_path, reference, n)
     query.check(DEPLOY, {"seed": SEED}, result, None, None, True)
     assert query.compare(result, reference) <= query.RELATIVE_TOLERANCE
     assert ex.stats["segments_traced"] == 1 and ex.stats["mesh_devices"] == n
-    assert not ex.stats.get("eager_fallbacks") and not ex.stats.get("batched_errors")
+    assert not ex.stats.get("eager_fallbacks") and not ex.stats.get("host_kernel_ops")
 
 
 @pytest.mark.parametrize("n", MESHES)
