@@ -133,7 +133,7 @@ def check_array_specs(arrays: Sequence) -> Optional[Spec]:
     return first
 
 
-def compute(
+def execute(
     *arrays,
     executor=None,
     callbacks: Optional[Sequence[Callback]] = None,
@@ -141,12 +141,14 @@ def compute(
     optimize_function=None,
     resume: Optional[bool] = None,
     **kwargs,
-) -> list[np.ndarray]:
-    """Compute multiple arrays in one plan execution; return numpy results."""
+) -> None:
+    """Run the arrays' combined plan to the end and read nothing back: when
+    this returns every array is in its store. ``compute`` adds the read;
+    ``to_zarr`` and ``store`` (core/ops.py) stop here."""
     from .plan import arrays_to_plan
 
     if not arrays:
-        return []
+        return
     spec = check_array_specs(arrays)
     plan = arrays_to_plan(*arrays)
     if executor is None:
@@ -165,6 +167,16 @@ def compute(
         spec=spec,
         **kwargs,
     )
+
+
+def compute(*arrays, **kwargs) -> list[np.ndarray]:
+    """Compute multiple arrays in one plan execution; return numpy results.
+
+    The whole of every array is read from its store into the client's
+    memory: the call for results small enough to hold. To compute into a
+    store without reading it back, use ``to_zarr`` or ``store``. Keyword
+    arguments are ``execute``'s."""
+    execute(*arrays, **kwargs)
     return [a._read_stored() for a in arrays]
 
 

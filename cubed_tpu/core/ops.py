@@ -42,7 +42,7 @@ from ..utils import (
     offset_to_block_id,
     to_chunksize,
 )
-from .array import CoreArray, check_array_specs, compute
+from .array import CoreArray, check_array_specs, execute
 from .plan import Plan, gensym, new_temp_path
 
 
@@ -132,6 +132,12 @@ def to_zarr(
 ) -> None:
     """Compute the array and write it to a new Zarr store (eagerly).
 
+    Computes for the side effect and returns ``None``: when it returns every
+    chunk of the target is durable (written by atomic rename, its checksum
+    in the manifest where the integrity mode keeps one), and nothing of the
+    target has been read back, so the client never holds more than the
+    plan's tasks do. ``compute()`` is the call that returns values.
+
     ``compressor`` is a Zarr v2 compressor config (e.g.
     ``{"id": "zlib", "level": 1}``; stdlib codecs zlib/gzip/bz2/lzma). The
     target metadata is stamped up front, so every chunk write — any
@@ -150,17 +156,18 @@ def to_zarr(
             storage_options=storage_options,
             compressor=compressor,
         )
-    out = _store_op(x, target, storage_options)
-    out.compute(executor=executor, **kwargs)
+    execute(_store_op(x, target, storage_options), executor=executor, **kwargs)
 
 
 def store(sources, targets, executor=None, **kwargs) -> None:
-    """Compute multiple arrays into multiple existing stores."""
+    """Compute multiple arrays into multiple existing stores, in one plan
+    execution. Like ``to_zarr`` it returns ``None`` once the targets are
+    durable and reads none of them back."""
     if isinstance(sources, CoreArray):
         sources = [sources]
         targets = [targets]
     outs = [_store_op(s, t, None) for s, t in zip(sources, targets)]
-    compute(*outs, executor=executor, **kwargs)
+    execute(*outs, executor=executor, **kwargs)
 
 
 def _store_op(x: CoreArray, store, storage_options) -> CoreArray:

@@ -6,6 +6,7 @@ Reference parity: cubed/tests/test_core.py (behavioral).
 import numpy as np
 import pytest
 
+import chip_smoke
 import cubed_tpu as ct
 import cubed_tpu.array_api as xp
 from cubed_tpu.core.optimization import fuse_all_optimize_dag, simple_optimize_dag
@@ -255,3 +256,125 @@ def test_unify_chunks_extent_mismatch_raises(spec):
     b = ct.from_array(np.arange(7.0), chunks=(2,), spec=spec)
     with pytest.raises(ValueError):
         xp.add(a, b)
+
+
+# -- to_zarr / store end when the target is durable; compute() reads back --
+
+
+_STORED_EXECUTORS = [e for e in all_executors() if e.name in ("single-threaded", "jax")]
+
+
+def _assert_durable(target, expected):
+    """The values as a client with numpy alone reads them, and every chunk
+    file present with a manifest entry whose CRC32 is the file's. Returns
+    the bytes the chunk files hold (edge chunks are stored whole)."""
+    from math import prod
+
+    from cubed_tpu.storage.store import open_zarr_array
+
+    assert np.array_equal(chip_smoke.read_zarr_v2(target), expected)
+    arr = open_zarr_array(target, mode="r")
+    valid, corrupt, verified = arr.verify_chunks(quarantine=False, count=False)
+    assert verified and not corrupt and len(valid) == arr.nchunks
+    return arr.nchunks * prod(arr.chunks) * arr.dtype.itemsize
+
+
+@pytest.mark.parametrize("call", ["to_zarr", "store"])
+@pytest.mark.parametrize("executor", _STORED_EXECUTORS, ids=lambda e: e.name)
+def test_stored_without_read_back(spec, executor, call, tmp_path, monkeypatch):
+    from cubed_tpu.core.array import CoreArray
+    from cubed_tpu.observability import reset_store_totals, store_totals
+
+    an = np.arange(35.0).reshape(7, 5)
+    bn = an[::-1].copy()
+    sources = {str(tmp_path / "a.zarr"): an, str(tmp_path / "b.zarr"): bn}
+    for path, values in sources.items():
+        ct.to_zarr(ct.from_array(values, chunks=(3, 2), spec=spec), path)
+    a, b = (ct.from_zarr(path, spec=spec) for path in sources)
+
+    def no_read_back(self):
+        raise AssertionError("to_zarr / store read the target back")
+
+    monkeypatch.setattr(CoreArray, "_read_stored", no_read_back)
+    reset_store_totals()
+    if call == "to_zarr":
+        sum_path = str(tmp_path / "sum.zarr")
+        targets = {sum_path: an + bn}
+        returned = ct.to_zarr(xp.add(a, b), sum_path, executor=executor)
+    else:
+        targets = {
+            str(tmp_path / "inc.zarr"): an + 1.0,
+            str(tmp_path / "neg.zarr"): -bn,
+        }
+        returned = ct.store(
+            [xp.add(a, 1.0), xp.negative(b)], list(targets), executor=executor
+        )
+    totals = store_totals()
+
+    assert returned is None
+    for target, expected in targets.items():
+        stored = _assert_durable(target, expected)
+        assert totals[target] == {"bytes_read": 0, "bytes_written": stored}
+    # each source feeds one fused op: read once, whoever executes
+    for path, values in sources.items():
+        stored = _assert_durable(path, values)
+        assert totals[path] == {"bytes_read": stored, "bytes_written": 0}
+
+
+@pytest.mark.parametrize("executor", _STORED_EXECUTORS, ids=lambda e: e.name)
+def test_compute_reads_back_and_keywords_pass_both_ways(spec, executor, tmp_path):
+    an = np.arange(36.0).reshape(6, 6)
+
+    def expr():
+        a = ct.from_array(an, chunks=(2, 2), spec=spec)
+        return xp.add(xp.add(a, 1.0), 1.0)
+
+    assert np.array_equal(expr().compute(executor=executor), an + 2.0)
+    (both,) = ct.compute(expr(), executor=executor)
+    assert np.array_equal(both, an + 2.0)
+
+    # callbacks, optimize_graph and resume reach the plan from either call
+    def tasks(run, **kwargs):
+        counter = TaskCounter()
+        run(callbacks=[counter], executor=executor, **kwargs)
+        return counter.value
+
+    c = expr()
+    target = str(tmp_path / "out.zarr")
+    stored = expr()
+    for run in (c.compute, lambda **kw: ct.to_zarr(stored, target, **kw)):
+        fused = tasks(run)
+        unfused = tasks(run, optimize_graph=False)
+        assert 0 < fused < unfused
+        assert tasks(run, optimize_graph=False, resume=True) < unfused
+    _assert_durable(target, an + 2.0)
+
+
+def test_to_zarr_never_holds_the_whole_target(tmp_path):
+    # what the bound on memory per task is for: 64 chunks of 128 KB under an
+    # allowed_mem of a fifth of the 8 MB target, and the client never holds
+    # anything near the target's size
+    import tracemalloc
+
+    spec = ct.Spec(work_dir=str(tmp_path), allowed_mem=1_600_000, reserved_mem=0)
+
+    def stored(n, name):
+        a = xp.ones((n, n), chunks=(128, 128), spec=spec)
+        target = str(tmp_path / name)
+        ct.to_zarr(xp.add(a, 1.0), target)
+        return target, a.nbytes
+
+    stored(256, "warm.zarr")  # imports and first-use caches are not the array
+    tracemalloc.start()
+    try:
+        target, nbytes = stored(1024, "out.zarr")
+        _, peak_stored = tracemalloc.get_traced_memory()
+        tracemalloc.reset_peak()
+        value = ct.from_zarr(target, spec=spec).compute()
+        _, peak_computed = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+
+    assert value.nbytes == nbytes == 8 * 1024 * 1024
+    assert peak_computed >= nbytes  # the yardstick sees a whole-array read
+    assert peak_stored < nbytes / 4, peak_stored
