@@ -35,6 +35,15 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   pure metadata (an alias). Under a device mesh the corresponding physical
   movement is a resharding (``device_put`` with a new NamedSharding), which
   XLA lowers to all-to-all over ICI — not a storage round-trip.
+  ``_exec_rechunk`` has four routes, each counted: the alias
+  (``stats["rechunk_alias"]``, the route of a traced segment), a virtual
+  source made on the device (``rechunk_virtual``), a stored source under
+  half the budget read whole on the host and put (``rechunk_host_whole``),
+  and any other stored source copied chunk by chunk on the host by the
+  primitive's own function, never touching the device
+  (``rechunk_host_copy``; it creates its destination, which residency left
+  uncreated). What the routes count while a segment is traced is kept with
+  the compiled program, so a structural hit reports what the miss did.
 - **Mesh / SPMD.** With ``mesh`` set, resident arrays are placed with a
   ``NamedSharding`` over the chunk grid's largest dim and whole-array kernels
   run under that sharding; XLA's partitioner inserts the collectives
@@ -51,7 +60,9 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   arithmetic plans compute in the device's float64 as before. The choice
   is made once per compute (``_execute_dag_inner``) and acted on in one
   pair of functions, ``_device_put`` and ``_to_host``, which every
-  transfer in either direction goes through. It is all or nothing: a
+  transfer in either direction goes through
+  (``stats["h2d_bits_bytes"]`` counts the bytes that entered as bit
+  patterns). It is all or nothing: a
   movement op that shares its compute with arithmetic, or with a
   complex128 array, moves in the device's float64, and
   ``stats["f64_lossy_moves"]`` counts those. Such a device also hands
@@ -71,7 +82,7 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   times its phases with ``scope_span`` (``jax.preload``, ``jax.h2d`` (one
   a chunk of a streamed preload), ``jax.struct_key``, ``jax.trace_lower``,
   ``jax.compile``, ``jax.dispatch``, ``jax.flush``, ``jax.device_wait``,
-  ``jax.d2h``): the
+  ``jax.d2h``, and ``jax.rechunk`` around a rechunk through storage): the
   phases' only clock (the task events keep their timestamps), a no-op unless a ``TraceCollector`` is attached or
   ``CUBED_TPU_TASK_SPANS=1`` (docs/observability.md, "Device executor
   spans").
@@ -329,8 +340,12 @@ class JaxExecutor(DagExecutor):
         #: ``segment_mem_aborts``, ``segment_hbm_footprint``,
         #: ``whole_array_hits``, ``whole_concat_hits``, ``batched_ops``,
         #: ``chunked_ops``, ``rechunk_alias`` (zero-copy), ``rechunk_virtual``
-        #: (materialized), ``eager_ops``, ``f64_as_bits`` (float64 arrays
-        #: moved to the device as bit patterns), ``f64_lossy_moves`` (copies
+        #: (materialized), ``rechunk_host_whole`` / ``rechunk_host_copy`` (a
+        #: stored source read whole on the host and put / copied on the host
+        #: chunk by chunk; 0, not absent, where none was), ``eager_ops``,
+        #: ``f64_as_bits`` (float64 arrays moved to the device as bit
+        #: patterns), ``h2d_bits_bytes`` (the part of ``h2d_bytes`` that
+        #: entered that way; 0, not absent), ``f64_lossy_moves`` (copies
         #: of 64-bit floats through a device float64 that is not one),
         #: ``host_syncs`` (fetches in ``_to_host``, each of which blocks on the
         #: device), ``h2d_bytes`` / ``d2h_bytes`` (bytes moved by ``_device_put``
@@ -348,7 +363,10 @@ class JaxExecutor(DagExecutor):
         #: counters of the designed aborts: ``trace_failures`` (segments sent
         #: to the eager route), ``host_kernel_ops`` (eager ops whose kernel
         #: ran un-jitted), ``eager_fallbacks`` (both; must stay 0 on
-        #: fused-path plans, tests pin it)
+        #: fused-path plans, tests pin it). The counters that routes move
+        #: while a segment is traced (``whole_array_hits``, ``rechunk_alias``,
+        #: ...) are of this compute's segments, traced in this compute or
+        #: found compiled (``_SegmentProgram.routes``)
         self.stats: Counter = Counter()
 
     @property
@@ -535,7 +553,10 @@ class JaxExecutor(DagExecutor):
         def transferred(data):
             data = np.asarray(data)
             self.stats["h2d_bytes"] += data.nbytes
-            return data.view(np.uint64) if as_bits else data
+            if not as_bits:
+                return data
+            self.stats["h2d_bits_bytes"] += data.nbytes
+            return data.view(np.uint64)
 
         sharding = self._sharding_for(shape, chunkset)
         if sharding is None and stored:
@@ -763,6 +784,9 @@ class JaxExecutor(DagExecutor):
             dict.fromkeys(_MESH_COUNTERS, 0),
             mesh_devices=0 if self.mesh is None else self.mesh.devices.size,
             h2d_stream_bytes=0,
+            h2d_bits_bytes=0,
+            rechunk_host_whole=0,
+            rechunk_host_copy=0,
         )
         self._task_order = [] if spans_enabled() else None
         resident: Dict[str, _Resident] = {}
@@ -1391,8 +1415,10 @@ class JaxExecutor(DagExecutor):
                 self.stats.get("segment_hbm_footprint", 0), program.footprint
             )
         # what the program is, not what this call did: a structural hit
-        # reports the same collectives and placement as the miss before it
+        # reports the same collectives, placement and routes as the miss
+        # before it
         self.stats.update(program.placement)
+        self.stats.update(program.routes)
         with scope_span(
             "jax.dispatch", cat="dispatch",
             struct_hit=cached_struct is not None,
@@ -1443,8 +1469,15 @@ class JaxExecutor(DagExecutor):
                 for k in keep_list
             ]
 
+        before = Counter(self.stats)
         with scope_span("jax.trace_lower", cat="dispatch"):
             lowered = jax.jit(seg_fn).lower(in_vals, base_vals)
+        # what the routes counted while the ops were traced (counters only
+        # rise) is the program's, not this call's: taken back here, kept
+        # with the program, and added by ``_trace_segment`` in this compute
+        # and in every later one that finds the program compiled
+        routes = dict(self.stats - before)
+        self.stats.subtract(routes)
         try:
             import hashlib
 
@@ -1470,7 +1503,7 @@ class JaxExecutor(DagExecutor):
             if self.mesh is not None:
                 placement.update(_count_collectives(compiled))
             program = _SegmentProgram(
-                compiled, _hbm_footprint(compiled), placement
+                compiled, _hbm_footprint(compiled), placement, routes
             )
             if key is not None:
                 with _CACHE_LOCK:
@@ -1478,7 +1511,9 @@ class JaxExecutor(DagExecutor):
                         _SEGMENT_CACHE.pop(next(iter(_SEGMENT_CACHE)))
                     _SEGMENT_CACHE[key] = program
         else:
-            program = cached
+            # the same HLO from another plan shape (an alias lowers to
+            # nothing): the program is shared, the routes are this trace's
+            program = cached._replace(routes=routes)
             self.stats["segment_cache_hits"] += 1
         return program
 
@@ -2234,14 +2269,24 @@ class JaxExecutor(DagExecutor):
             opened = src.open() if hasattr(src, "open") else src
         except FileNotFoundError:
             opened = None
-        if opened is not None and opened.nbytes < budget // 2:
-            data = opened[...] if opened.shape else opened[()]
-            value = self._device_put(data, data.shape)
-            self._admit(resident, dst_key, value, dst, budget)
-        else:
-            # bounded host-side copy (the spill path)
-            for m in op.pipeline.mappable:
-                op.pipeline.function(m, config=config)
+        whole = opened is not None and opened.nbytes < budget // 2
+        route = "host_whole" if whole else "host_copy"
+        self.stats["rechunk_" + route] += 1
+        with scope_span("jax.rechunk", route=route, bytes=dst.nbytes):
+            if whole:
+                data = opened[...] if opened.shape else opened[()]
+                value = self._device_put(data, data.shape)
+                self._admit(resident, dst_key, value, dst, budget)
+            else:
+                # bounded host-side copy (the spill path), never touching
+                # the device. Residency leaves an array that nobody asked
+                # for uncreated (``run_eager``), so the route that does
+                # write to storage makes sure its destination is there, as
+                # ``_flush`` does
+                if isinstance(dst, LazyZarrArray):
+                    dst.create(mode="a")
+                for m in op.pipeline.mappable:
+                    op.pipeline.function(m, config=config)
 
     # ------------------------------------------------------------------
     # residency bookkeeping
@@ -2384,6 +2429,10 @@ class _SegmentProgram(NamedTuple):
     footprint: int
     #: the ``_MESH_COUNTERS`` of this program
     placement: Dict[str, int]
+    #: what the routes counted while the program's ops were traced
+    #: (``rechunk_alias``, ``whole_array_hits``, ``batched_ops``, ...): of
+    #: the plan shape that found or compiled the program, not of its HLO
+    routes: Dict[str, int]
 
 
 #: the kinds of collective instruction ``_count_collectives`` counts, as XLA
