@@ -29,7 +29,7 @@ also runs the mesh legs (``JaxExecutor(mesh=make_mesh())``: vorticity, and
 share. It ends with the raw device facts the timings are read against
 (``device_facts``: float64 round trip, transfer rates, the executor's
 plane route out, dispatch, the executor's streamed preload against the
-whole-array put).
+whole-array put, a column slab's way out as planes against a block's).
 
 The reference for ``zarr_add`` is numpy on the host arrays the sources
 were written from, and outputs are read back from the store's files with
@@ -91,6 +91,7 @@ PATH_COUNTERS = (
     "f64_lossy_moves",
     "d2h_bytes",
     "d2h_plane_bytes",
+    "d2h_plane_strided_bytes",
     "h2d_bytes",
     "h2d_stream_bytes",
 )
@@ -520,6 +521,79 @@ def preload_stream_reading(n: int, *, seed: int) -> dict:
     return out
 
 
+def fetch_layout_reading(device, n: int, readings: int, *, seed: int) -> dict:
+    """What a chunk's shape does to its way out as planes: from a resident
+    (n, n) array, as uint64 and as float64, a column slab (n, n/4) and a
+    block (n/2, n/2) of the same bytes, each cut on the device, split by the
+    executor's own program (``_plane_program_of``), fetched and joined. It
+    prints the order in which the planes reached the host (strides,
+    contiguity: a plane that is not C-contiguous is read strided by the
+    join, and ``stats["d2h_plane_strided_bytes"]`` counts it in a compute),
+    the device layout the compiled program gives them (major to minor),
+    what the program holds on the device, and the four steps in
+    milliseconds, medians of ``readings``: smoke timings on a shared host,
+    not benchmark numbers. On the v5e the device's own layout for the slab's
+    planes is column-major, which cost the join 530 ms against the block's
+    97 (PERF.md section 6, PRs 32 and 33). Raises if a joined value differs
+    from the direct fetch in any bit (float64 only on a device that holds
+    one as a float32 pair)."""
+    import jax
+
+    from cubed_tpu.runtime.executors.jax import (
+        _float64_round_trips,
+        _join_planes,
+        _plane_program_of,
+        _planes_strided,
+    )
+
+    host = np.random.default_rng(seed).random((n, n))
+    cuts = {
+        "slab": (slice(0, n), slice(0, n // 4)),
+        "block": (slice(0, n // 2), slice(0, n // 2)),
+    }
+    out = {}
+    for form in ("uint64", "float64"):
+        value = jax.device_put(host.view(form), device)
+        jax.block_until_ready(value)
+        for name, sel in cuts.items():
+            split, held = _plane_program_of(value[sel])  # compiled here
+            steps = []
+            for _ in range(readings):
+                t0 = time.perf_counter()
+                piece = value[sel]
+                jax.block_until_ready(piece)
+                t1 = time.perf_counter()
+                planes = split(piece)
+                jax.block_until_ready(planes)
+                t2 = time.perf_counter()
+                first, second, inexact = jax.device_get(planes)
+                t3 = time.perf_counter()
+                joined = _join_planes(first, second, np.dtype(form))
+                t4 = time.perf_counter()
+                steps.append((t1 - t0, t2 - t1, t3 - t2, t4 - t3))
+            if inexact:
+                raise AssertionError(f"{form} {name}: uniform values raised the split's flag")
+            # a device with a real float64 loses bits to the split into pairs
+            exact = form == "uint64" or not _float64_round_trips(device)
+            if exact and joined.tobytes() != np.asarray(piece).tobytes():
+                raise AssertionError(f"{form} {name}: the planes' join differs from the direct fetch")
+            ms = [1e3 * statistics.median(step) for step in zip(*steps)]
+            out[form, name] = {
+                "strided": _planes_strided(first, second), "strides": first.strides, "ms": ms,
+            }
+            _say(
+                f"{form} {name} {first.shape}, {joined.nbytes / 1e6:.0f} MB: planes reach the "
+                f"host with strides {first.strides} (C-contiguous {first.flags.c_contiguous}, "
+                f"F-contiguous {first.flags.f_contiguous}), the program lays them out "
+                f"{split.output_formats[0].layout.major_to_minor}, the split holds {held / 1e6:.0f} MB "
+                f"on the device; cut {ms[0]:.1f} split {ms[1]:.1f} fetch {ms[2]:.1f} "
+                f"join {ms[3]:.1f} ms"
+            )
+            del piece, planes, first, second, joined
+        del value
+    return out
+
+
 def device_facts(device, n: int, readings: int, calls: int, *, seed: int) -> dict:
     """What the legs' timings are read against (PERF.md section 5), through
     jax alone: whether a float64 survives being held by the device, how fast
@@ -527,8 +601,9 @@ def device_facts(device, n: int, readings: int, calls: int, *, seed: int) -> dic
     as float32, how fast the float64 leaves through the executor's own split
     into 32-bit planes, what one dispatch costs, and (through the executor)
     that the streamed preload puts on the device what the whole-array put
-    does. Medians of ``readings`` transfers and of ``calls`` dispatches, on
-    the host's clock."""
+    does, and in which order a slab's and a block's planes reach the host
+    (``fetch_layout_reading``). Medians of ``readings`` transfers and of
+    ``calls`` dispatches, on the host's clock."""
     import jax
 
     print(f"== device facts: ({n}, {n}) arrays, medians of {readings} "
@@ -568,10 +643,10 @@ def device_facts(device, n: int, readings: int, calls: int, *, seed: int) -> dic
         )
     # the float64 array again, the way the executor fetches it: split into
     # 32-bit planes on the device, one fetch, joined on the host
-    from cubed_tpu.runtime.executors.jax import _join_planes, _plane_splitter
+    from cubed_tpu.runtime.executors.jax import _join_planes, _plane_program_of
 
-    split, fetch = _plane_splitter(), []
-    jax.block_until_ready(split(jax.device_put(host, device)))  # compiled here
+    # compiled here
+    (split, _), fetch = _plane_program_of(jax.device_put(host, device)), []
     for _ in range(readings):
         on_device = jax.device_put(host, device)
         on_device.block_until_ready()
@@ -605,6 +680,8 @@ def device_facts(device, n: int, readings: int, calls: int, *, seed: int) -> dic
     # the way in, as the executor's preload takes it: chunk by chunk through
     # reused staging buffers, against the put of the whole array
     facts["preload_stream"] = preload_stream_reading(n, seed=seed)
+    # the way out by the chunk's shape: a column slab against a block
+    facts["fetch_layout"] = fetch_layout_reading(device, 2 * n, readings, seed=seed)
     return facts
 
 

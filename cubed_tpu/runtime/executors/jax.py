@@ -70,7 +70,10 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   ``_to_host`` splits a large 64-bit value into two 32-bit planes on the
   device (the words of an integer, the float32 pair of a float64), fetches
   both at once and joins them on the host, bit for bit the direct fetch.
-  ``stats["d2h_plane_bytes"]`` counts the bytes that left that way.
+  The planes leave in a row-major device layout, so that the host receives
+  them in the order it writes the result in (``_plane_program``).
+  ``stats["d2h_plane_bytes"]`` counts the bytes that left that way, and
+  ``stats["d2h_plane_strided_bytes"]`` those that arrived in another order.
 - **Scheduling.** This executor always keeps op ordering and ignores
   ``Spec(scheduler="dataflow")``: whole (fused) segments compile to single
   XLA programs over HBM-resident arrays, so there is no per-chunk task
@@ -105,7 +108,7 @@ import threading
 import time
 from collections import Counter
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, Iterator, NamedTuple, Optional
+from typing import Any, Dict, Iterator, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -354,7 +357,9 @@ class JaxExecutor(DagExecutor):
         #: where nothing did), ``h2d_stream_declined`` (stored arrays that
         #: qualified for the stream and were put whole for want of room in
         #: HBM), ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
-        #: left as 32-bit planes), ``d2h_plane_no_room`` / ``d2h_plane_inexact``
+        #: left as 32-bit planes), ``d2h_plane_strided_bytes`` (the part of
+        #: that whose planes reached the host in another order than
+        #: row-major; 0, not absent), ``d2h_plane_no_room`` / ``d2h_plane_inexact``
         #: (fetches that qualified for planes and were made directly: no room
         #: in HBM, values the split cannot vouch for), ``mesh_devices`` (0
         #: without a mesh) and the ``_MESH_COUNTERS`` of the segment programs
@@ -668,16 +673,18 @@ class JaxExecutor(DagExecutor):
             if sp.recording:
                 _jax().block_until_ready(value)
         with scope_span("jax.d2h", cat="transfer") as sp:
-            host = self._fetch_as_planes(value)
+            host, strided = self._fetch_as_planes(value)
             planes = sp.attrs["planes"] = host is not None
+            sp.attrs["strided"] = strided
             if not planes:
                 host = np.asarray(value)
             if self._carry_bits and host.dtype == np.uint64 and dtype == np.float64:
                 host = host.view(np.float64)
             sp.attrs["bytes"] = host.nbytes
         self.stats["d2h_bytes"] += host.nbytes
-        # present, and 0, where nothing left as planes
+        # present, and 0, where nothing left as planes, or none strided
         self.stats["d2h_plane_bytes"] += host.nbytes if planes else 0
+        self.stats["d2h_plane_strided_bytes"] += host.nbytes if strided else 0
         return host
 
     def _leaves_as_planes(self, value) -> bool:
@@ -695,30 +702,35 @@ class JaxExecutor(DagExecutor):
             or _float64_round_trips(self._first_device())
         ):
             return False
-        # the planes are a second chunk-sized temporary beside the slice
-        # being fetched. A flush that runs because HBM is over budget must
-        # not be what tips it over, and one at the end of a compute takes
-        # them only where the residency accounting leaves room for both
+        # what the split holds on the device (the slice being fetched, the
+        # planes, a relayout's temporary) comes on top of what is resident.
+        # A flush that runs because HBM is over budget must not be what
+        # tips it over, and one at the end of a compute takes the planes
+        # only where the residency accounting leaves room for the program
         held = sum(r.nbytes for r in self._resident.values())
-        if self._spilling or held + 2 * value.nbytes > self._budget():
+        if self._spilling or held + _plane_program_of(value)[1] > self._budget():
             self.stats["d2h_plane_no_room"] += 1
             return False
         return True
 
-    def _fetch_as_planes(self, value) -> Optional[np.ndarray]:
+    def _fetch_as_planes(self, value) -> Tuple[Optional[np.ndarray], bool]:
         """``value`` through ``_split_planes`` on the device, one fetch of
-        both planes, ``_join_planes`` on the host. None where it does not
-        leave as planes (``_leaves_as_planes``) or the device reports
-        values that the split cannot reproduce (counted): the caller then
-        fetches the value itself."""
+        both planes, ``_join_planes`` on the host; and whether a plane
+        arrived in another order than row-major, so that the join read it
+        strided. (None, False) where it does not leave as planes
+        (``_leaves_as_planes``) or the device reports values that the split
+        cannot reproduce (counted): the caller then fetches the value
+        itself."""
         if not self._leaves_as_planes(value):
-            return None
-        first, second, inexact = _jax().device_get(_plane_splitter()(value))
+            return None, False
+        split, _ = _plane_program_of(value)
+        first, second, inexact = _jax().device_get(split(value))
         if inexact:
             self.stats["d2h_plane_inexact"] += 1
             self.stats["host_syncs"] += 1
-            return None
-        return _join_planes(first, second, np.dtype(value.dtype))
+            return None, False
+        joined = _join_planes(first, second, np.dtype(value.dtype))
+        return joined, _planes_strided(first, second)
 
     # ------------------------------------------------------------------
 
@@ -2532,6 +2544,12 @@ _HEAD_BITS_2_POW_MINUS_73 = (127 - 73) << 23
 #: float32 bit pattern of infinity, less its sign
 _HEAD_BITS_INF = 0x7F800000
 
+#: the most a split with row-major planes may hold on the device, in bytes
+#: of the value: the value, its planes and a relayout's temporary are three,
+#: the tile's padding of a well-shaped value a few hundredths more (3.06 for
+#: a (10000, 2500) slab, 2.03 for a (5000, 5000) block; 33 for (3125000, 8))
+_PLANES_ROW_MAJOR_MAX = 4
+
 #: threads of ``_join_planes`` and the least bytes of result each one
 #: takes. The host pays for the result's fresh pages by the page (200 ms
 #: for 200 MB on the v5e's host, 33 ms into pages already touched), and
@@ -2589,11 +2607,53 @@ def _split_planes(x):
     return low, high, jnp.bool_(False)
 
 
-@functools.cache
-def _plane_splitter():
-    """``_split_planes`` jitted: one program a (shape, dtype, sharding),
-    compiled by the first fetch that needs it and kept by jax."""
-    return _jax().jit(_split_planes)
+@functools.lru_cache(maxsize=256)
+def _plane_program(shape: tuple, dtype: np.dtype, sharding):
+    """``_split_planes`` compiled for values of one (shape, dtype,
+    sharding), and the bytes it holds on the device while it runs; made by
+    the first fetch that needs it and kept.
+
+    The host receives a value in the order of its device layout, and the
+    device's own choice goes by which dimension pads less to its tile: the
+    planes of a (10000, 2500) slab come column-major (10000 pads 1.1% to a
+    multiple of 128, 2500 pads 2.4%), those of a (5000, 5000) block
+    row-major. So both planes are pinned to a row-major device layout: the
+    device lays 2 x 100 MB out again in under a millisecond, and the join
+    that takes 97 ms for row-major planes takes 138 ms reading them strided
+    (and took 530 ms through a transposing copy; TPU v5e, PERF.md section 6,
+    PR 33). The layout keeps shape and sharding, so under a mesh the planes
+    stay where the value is. It is the integer planes that are laid out
+    again, after the split: a relayout of the float64 could change its bits
+    (``_split_planes``).
+
+    Row-major planes whose rows are short pad each row to 128 elements, in
+    HBM and on the way out: those of a (3125000, 8) value hold 6.6 GB for
+    200 MB and fetch in 238 ms against 72. Where the pinned program holds
+    more than ``_PLANES_ROW_MAJOR_MAX`` times the value's bytes the device
+    keeps its own layout, and ``_join_planes`` reads what comes strided.
+
+    The bytes are XLA's own accounting (``_hbm_footprint``: argument,
+    planes, temporaries) of one device, times the devices the value lies
+    on, and never under the value and its two planes. A relayout adds a
+    temporary the size of the planes (612 MB against 405 MB for a 200 MB
+    slab): ``_leaves_as_planes`` reads the room from here."""
+    jax = _jax()
+    from jax.experimental.layout import Format, Layout
+
+    value = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    nbytes = math.prod(shape) * dtype.itemsize
+    row_major = Format(Layout(major_to_minor=tuple(range(len(shape)))), sharding)
+    for planes in (row_major, None):
+        split = jax.jit(_split_planes, out_shardings=(planes, planes, None))
+        compiled = split.lower(value).compile()
+        held = _hbm_footprint(compiled) * len(sharding.device_set)
+        if held <= _PLANES_ROW_MAJOR_MAX * nbytes:
+            break
+    return compiled, max(held, 2 * nbytes)
+
+
+def _plane_program_of(value):
+    return _plane_program(tuple(value.shape), np.dtype(value.dtype), value.sharding)
 
 
 @functools.cache
@@ -2622,30 +2682,43 @@ def _chunk_writer():
 
 def _join_planes(first: np.ndarray, second: np.ndarray, dtype: np.dtype) -> np.ndarray:
     """The host array of ``dtype`` that ``_split_planes``'s two planes stand
-    for, in one pass into one fresh array.
+    for, in one pass into one fresh C-contiguous array.
 
     float64: ``float64(head) + float64(tail)``, one correctly rounded add of
     two exactly represented numbers, which is what the runtime computes
     when it fetches the pair itself. Integers: the words written side by
-    side. Large results are filled by a few threads, a slab each."""
+    side. Large results are filled by a few threads, a slab each.
+
+    The planes are read where they lie. Row-major ones (what
+    ``_plane_program`` asks the device for) are cut as flat views; any
+    other order (``_planes_strided``) is cut along the longest axis and
+    read strided by every thread: a ``reshape(-1)`` of such a plane would
+    be a transposing copy on one thread, 230 ms for 100 MB (PERF.md
+    section 6, PR 32)."""
     out = np.empty(first.shape, dtype)
-    flat, a, b = out.reshape(-1), first.reshape(-1), second.reshape(-1)
+    if _planes_strided(first, second):
+        flat, a, b, axis = out, first, second, int(np.argmax(first.shape))
+    else:
+        flat, a, b, axis = out.reshape(-1), first.reshape(-1), second.reshape(-1), 0
+    span = (slice(None),) * axis
     if dtype == np.float64:
         a, b = a.view(np.float32), b.view(np.float32)
 
         def fill(lo: int, hi: int) -> None:
+            at = span + (slice(lo, hi),)
             # a signalling NaN head is quieted, as it is by a direct fetch
             with np.errstate(invalid="ignore"):
-                np.add(a[lo:hi], b[lo:hi], out=flat[lo:hi], dtype=np.float64)
+                np.add(a[at], b[at], out=flat[at], dtype=np.float64)
     else:
-        words = flat.view(np.uint32).reshape(-1, 2)
+        words = flat.view(np.uint32).reshape(flat.shape + (2,))
         low, high = (0, 1) if sys.byteorder == "little" else (1, 0)
 
         def fill(lo: int, hi: int) -> None:
-            words[lo:hi, low] = a[lo:hi]
-            words[lo:hi, high] = b[lo:hi]
+            at = span + (slice(lo, hi),)
+            words[at + (..., low)] = a[at]
+            words[at + (..., high)] = b[at]
 
-    n = flat.size
+    n = flat.shape[axis]
     threads = min(_JOIN_THREADS, out.nbytes // _JOIN_MIN_BYTES_PER_THREAD)
     if threads < 2:
         fill(0, n)
@@ -2655,6 +2728,12 @@ def _join_planes(first: np.ndarray, second: np.ndarray, dtype: np.dtype) -> np.n
         # list(): a slab that raised raises here
         list(pool.map(fill, cuts[:-1], cuts[1:]))
     return out
+
+
+def _planes_strided(first: np.ndarray, second: np.ndarray) -> bool:
+    """Whether a plane reached the host in another order than row-major, so
+    that ``_join_planes`` reads it strided."""
+    return not (first.flags.c_contiguous and second.flags.c_contiguous)
 
 
 #: (platform, device_kind) -> whether float64 survives a round trip

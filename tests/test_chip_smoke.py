@@ -112,6 +112,14 @@ def test_device_facts_small(capsys):
     assert set(facts["preload_stream"]) == {"float64", "uint64"}
     assert out.count("streamed preload") == 2 and "0 of 9216 values differ" in out
     assert f"h2d_stream_bytes={3 * 64 * 64 * 8}" in out
+    # a column slab's way out as planes against a block's, as words and as pairs
+    assert set(facts["fetch_layout"]) == {
+        (form, cut) for form in ("uint64", "float64") for cut in ("slab", "block")
+    }
+    for reading in facts["fetch_layout"].values():
+        assert reading["strided"] is False and len(reading["ms"]) == 4
+    assert out.count("planes reach the host with strides") == 4
+    assert "uint64 slab (128, 32)" in out and "float64 block (64, 64)" in out
 
 
 def test_check_mesh_shares():

@@ -42,7 +42,8 @@ def _through_planes(host: np.ndarray):
     """(joined host array, the split's flag) of ``host`` put on the device."""
     import jax
 
-    first, second, inexact = jax.device_get(jx._plane_splitter()(jax.device_put(host)))
+    on_device = jax.device_put(host)
+    first, second, inexact = jax.device_get(jx._plane_program_of(on_device)[0](on_device))
     assert first.dtype == second.dtype == np.uint32
     return jx._join_planes(first, second, host.dtype), bool(inexact)
 
@@ -105,22 +106,66 @@ def test_heads_whose_tail_the_split_cannot_vouch_for_raise_the_flag(value):
     assert inexact
 
 
+def _finite_planes(shape, dtype):
+    """Two uint32 planes of ``shape``; for float64, bits of finite float32s."""
+    first = RNG.integers(0, 2**32, size=shape, dtype=np.uint32)
+    second = RNG.integers(0, 2**32, size=shape, dtype=np.uint32)
+    if dtype == np.float64:
+        first &= np.uint32(0xBF7FFFFF)
+        second &= np.uint32(0xBF7FFFFF)
+    return first, second
+
+
+def _joined_by_numpy(first, second, dtype) -> np.ndarray:
+    """What the two planes stand for, by whole-array numpy."""
+    if dtype == np.float64:
+        return first.view(np.float32).astype(np.float64) + second.view(np.float32)
+    return ((second.astype(np.uint64) << np.uint64(32)) | first).view(dtype)
+
+
 @pytest.mark.parametrize("dtype", [np.float64, np.int64, np.uint64])
 def test_join_in_threads_equals_join_in_one(dtype, monkeypatch):
     n = 3 * jx._JOIN_MIN_BYTES_PER_THREAD // 8 + 5  # three slabs, ragged
-    first = RNG.integers(0, 2**32, size=n, dtype=np.uint32)
-    second = RNG.integers(0, 2**32, size=n, dtype=np.uint32)
-    if dtype == np.float64:  # finite float32s
-        first &= np.uint32(0xBF7FFFFF)
-        second &= np.uint32(0xBF7FFFFF)
+    first, second = _finite_planes(n, dtype)
     threaded = jx._join_planes(first, second, np.dtype(dtype))
     monkeypatch.setattr(jx, "_JOIN_THREADS", 1)
     assert jx._join_planes(first, second, np.dtype(dtype)).tobytes() == threaded.tobytes()
-    if dtype == np.float64:
-        want = first.view(np.float32).astype(np.float64) + second.view(np.float32)
-    else:
-        want = ((second.astype(np.uint64) << np.uint64(32)) | first).view(dtype)
-    assert threaded.tobytes() == want.tobytes()
+    assert threaded.tobytes() == _joined_by_numpy(first, second, dtype).tobytes()
+
+
+def _strided_view(plane: np.ndarray) -> np.ndarray:
+    """``plane``'s values as a view of a larger array, contiguous in no order."""
+    base = np.zeros((2 * plane.shape[0], 3 * plane.shape[1]), plane.dtype)
+    base[::2, ::3] = plane
+    return base[::2, ::3]
+
+
+#: the orders in which a pair of planes can reach the host
+PLANE_ORDERS = {
+    "c_contiguous": (np.ascontiguousarray, np.ascontiguousarray),
+    "f_contiguous": (np.asfortranarray, np.asfortranarray),
+    "strided_view": (_strided_view, _strided_view),
+    "one_of_each": (np.asfortranarray, np.ascontiguousarray),
+}
+#: a result of 8.6 MB, which two threads fill, and one that a single call fills
+JOIN_SHAPES = {"threaded": (1200, 900), "single": (40, 30)}
+
+
+@pytest.mark.parametrize("size", sorted(JOIN_SHAPES))
+@pytest.mark.parametrize("order", sorted(PLANE_ORDERS))
+@pytest.mark.parametrize("dtype", [np.float64, np.int64, np.uint64])
+def test_join_reads_planes_of_any_order_into_a_row_major_result(dtype, order, size):
+    shape = JOIN_SHAPES[size]
+    threads = min(jx._JOIN_THREADS, int(np.prod(shape)) * 8 // jx._JOIN_MIN_BYTES_PER_THREAD)
+    assert (threads >= 2) == (size == "threaded")
+    planes = _finite_planes(shape, dtype)
+    first, second = (lay(plane) for lay, plane in zip(PLANE_ORDERS[order], planes))
+    assert (first.tobytes(), second.tobytes()) == (planes[0].tobytes(), planes[1].tobytes())
+    assert jx._planes_strided(first, second) == (order != "c_contiguous")
+    joined = jx._join_planes(first, second, np.dtype(dtype))
+    assert joined.flags.c_contiguous and joined.shape == shape and joined.dtype == dtype
+    assert joined.tobytes() == jx._join_planes(*planes, np.dtype(dtype)).tobytes()
+    assert joined.tobytes() == _joined_by_numpy(*planes, dtype).tobytes()
 
 
 # ---------------------------------------------------------------------------
@@ -289,14 +334,19 @@ def test_a_flush_that_makes_room_takes_the_direct_fetch(tmp_path, spec, pair_dev
 
 def test_without_room_for_the_planes_the_last_flush_is_direct(tmp_path, spec, pair_device):
     """The accounting at the end of a compute: three arrays resident fill the
-    budget to within less than two chunks."""
+    budget to within less than what the split's program holds, which is the
+    chunk and its two planes at least."""
+    import jax
+
     chunk = CHUNKS[0] * CHUNKS[1] * 8
+    _, needed = jx._plane_program_of(jax.device_put(GRID_A[: CHUNKS[0], : CHUNKS[1]]))
+    assert needed >= 2 * chunk
     got, stats = _stored(_add, tmp_path, spec, "tight",
-                         device_mem=3 * GRID_A.nbytes + 2 * chunk - 1)
+                         device_mem=3 * GRID_A.nbytes + needed - 1)
     assert got.tobytes() == (GRID_A + GRID_B).tobytes()
     assert stats["d2h_plane_no_room"] == N_CHUNKS and stats["d2h_plane_bytes"] == 0
     got, stats = _stored(_add, tmp_path, spec, "fits",
-                         device_mem=3 * GRID_A.nbytes + 2 * chunk)
+                         device_mem=3 * GRID_A.nbytes + needed)
     assert not stats.get("d2h_plane_no_room") and stats["d2h_plane_bytes"] == got.nbytes
 
 
@@ -310,6 +360,7 @@ def test_the_d2h_span_says_which_way_the_value_left(tmp_path, spec, monkeypatch,
     fetches = [s for rec in tc._records for s in rec["spans"] if s["name"] == "jax.d2h"]
     assert len(fetches) == N_CHUNKS
     assert all(s["attrs"]["planes"] is planes for s in fetches)
+    assert all(s["attrs"]["strided"] is False for s in fetches)
     assert all(s["attrs"]["bytes"] == CHUNKS[0] * CHUNKS[1] * 8 for s in fetches)
 
 
@@ -320,3 +371,67 @@ def test_under_a_mesh_the_planes_come_back_whole(tmp_path, spec, pair_device, me
     got, stats = _stored(_add, tmp_path, spec, "meshed", mesh=make_mesh() if mesh else None)
     assert got.tobytes() == (GRID_A + GRID_B).tobytes()
     assert stats["d2h_plane_bytes"] == got.nbytes
+
+
+TALL = {
+    # what a rechunk to column slabs cuts: many rows, few columns
+    "float64": _pairs(RNG.standard_normal((600, 24)) * 1e3, RNG.standard_normal((600, 24)) * 1e-6),
+    "uint64": RNG.integers(0, 2**64, size=(600, 24), dtype=np.uint64),
+    "int64_3d": RNG.integers(-(2**63), 2**63, size=(50, 6, 8), dtype=np.int64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TALL))
+def test_a_tall_slab_is_fetched_row_major_and_nothing_is_read_strided(case, pair_device):
+    import jax
+
+    host = TALL[case]
+    ex = JaxExecutor()
+    fetched, strided = ex._fetch_as_planes(jax.device_put(host))
+    assert strided is False
+    assert fetched.flags.c_contiguous and fetched.shape == host.shape
+    assert fetched.dtype == host.dtype and fetched.tobytes() == host.tobytes()
+    assert ex._to_host(jax.device_put(host), host.dtype).tobytes() == host.tobytes()
+    assert ex.stats["d2h_plane_bytes"] == host.nbytes
+    assert "d2h_plane_strided_bytes" in ex.stats and ex.stats["d2h_plane_strided_bytes"] == 0
+
+
+@pytest.fixture
+def planes_arrive_column_major(monkeypatch):
+    """``device_get`` hands every plane back in Fortran order, as the v5e's
+    runtime did for a (10000, 2500) slab before the layout was pinned."""
+    import jax
+
+    fetch = jax.device_get
+
+    def column_major(tree):
+        return jax.tree.map(
+            lambda leaf: np.asfortranarray(leaf) if leaf.dtype == np.uint32 else leaf,
+            fetch(tree),
+        )
+
+    monkeypatch.setattr(jax, "device_get", column_major)
+
+
+@pytest.mark.parametrize("pipeline", sorted(PIPELINES))
+def test_planes_that_arrive_column_major_are_joined_bit_for_bit_and_counted(
+    pipeline, tmp_path, spec, monkeypatch, planes_arrive_column_major
+):
+    build = PIPELINES[pipeline]
+    direct, direct_stats = _stored(build, tmp_path, spec, "direct")
+    assert direct_stats["d2h_plane_strided_bytes"] == 0
+    monkeypatch.setattr(jx, "_float64_round_trips", lambda device: False)
+    monkeypatch.setattr(jx, "_PLANES_MIN_BYTES", 64)
+    tc = TraceCollector(trace_dir=None)
+    ex = JaxExecutor()
+    out = str(tmp_path / "strided.zarr")
+    ct.to_zarr(build(tmp_path, spec), out, executor=ex, callbacks=[tc])
+    got = open_zarr_array(out, mode="r")[...]
+    assert got.dtype == direct.dtype and got.tobytes() == direct.tobytes()
+    assert ex.stats["d2h_plane_bytes"] > 0
+    assert ex.stats["d2h_plane_strided_bytes"] == ex.stats["d2h_plane_bytes"]
+    fetches = [s for rec in tc._records for s in rec["spans"] if s["name"] == "jax.d2h"]
+    assert fetches and all(s["attrs"]["strided"] is s["attrs"]["planes"] for s in fetches)
+    assert sum(s["attrs"]["bytes"] for s in fetches if s["attrs"]["strided"]) == (
+        ex.stats["d2h_plane_strided_bytes"]
+    )
