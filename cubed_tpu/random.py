@@ -22,11 +22,15 @@ making its streams exactly match the numpy-backend oracle (``Philox(seed=
 root + block_offset)``, the reference's own contract). Blocks larger than
 ``_PHILOX_MAX_BLOCK_BYTES`` stay fused threefry even on CPU: the
 callback's copy/materialization cost scales with block bytes and crosses
-over around there (see the constant's measured table). Under a device mesh
+over around there (see the constant's comment). Under a device mesh
 the executor forces threefry (callbacks don't partition across a
 multi-controller SPMD program); a heterogeneous CPU+TPU fleet must pin one
 stream via ``CUBED_TPU_RNG`` if cross-platform per-block reproducibility
 matters.
+
+``random`` draws float64 or float32, as declared (``dtype=``): the array has
+that dtype in the plan and every route generates in it, one named kernel a
+width. The per-block contract for both widths is in ``random``'s docstring.
 """
 
 from __future__ import annotations
@@ -48,13 +52,12 @@ _MODE_OVERRIDE: contextvars.ContextVar = contextvars.ContextVar(
 )
 
 
-#: auto-mode block-size crossover, measured on the bench configs (same
-#: machine state, best-of-2, framework warm): philox-callback wins 1.1-2.5x
-#: for <=8 MB blocks (reduce 1.98x, vorticity_f32 2.49x, elemwise 1.39x,
-#: matmul 1.37x, addsum 1.14x, vorticity 1.08x) but LOSES 1.8x on the 32
-#: MB-block addsum_scaled config: the callback's copy/materialization cost
-#: scales with block bytes while fused threefry never materializes the
-#: generation at all. Crossover set between the measured points.
+#: auto-mode block-size crossover on the CPU: the callback's copy and
+#: materialization cost scales with block bytes, while fused threefry never
+#: materializes the generation at all, so small blocks go to the Philox
+#: callback and large ones stay fused. Set between the block sizes at which
+#: each route won on an earlier installation's CPU; no ratio from there is
+#: quoted here, and none of it says anything about the chip (PERF.md).
 _PHILOX_MAX_BLOCK_BYTES = 16 * 2**20
 
 
@@ -203,38 +206,77 @@ from .storage.virtual import virtual_empty, VirtualOffsetsArray
 from .utils import to_chunksize
 
 
-def random(size, *, diagnostics=None, chunks=None, spec=None):
-    """Uniform [0, 1) float64 array with per-block reproducible randomness."""
+def random(size, *, diagnostics=None, chunks=None, spec=None, dtype=np.float64):
+    """Uniform [0, 1) array of ``dtype`` (float64, the default, or float32)
+    with per-block reproducible randomness.
+
+    The array has ``dtype`` in the plan (chunk memory and ``projected_mem``
+    follow from it) and every generation route draws in it; nothing is
+    drawn wider and cast. Block ``k`` of the chunk grid, counted in C
+    order, of an array whose root seed is ``root`` (30 bits from Python's
+    ``random``, drawn when the array is declared) is, for either width,
+
+    - fused threefry (the accelerator route):
+      ``jax.random.uniform(fold_in(key(0), root + k), block_shape, dtype)``
+      with ``jax_threefry_partitionable`` on;
+    - the numpy backend and the Philox callback of the jax backend on a CPU:
+      ``Generator(Philox(root + k)).random(block_shape, dtype=dtype)``.
+
+    A float32 block is not the float64 block rounded: each width draws its
+    own stream (32 random bits a value against 64). Beyond upstream, whose
+    ``cubed.random.random`` is float64 only; numpy's ``Generator.random``
+    takes the same two dtypes."""
+    try:
+        kernel = _RANDOM_KERNELS[np.dtype(dtype)]
+    except (KeyError, TypeError):
+        raise TypeError(
+            f"random() draws float64 or float32, not {dtype!r}"
+        ) from None
     return _distribution(
-        size, chunks, spec, kernel=_random_block, op_name="random",
-        params=None, dtype=np.float64,
+        size, chunks, spec, kernel=kernel, op_name="random",
+        params=None, dtype=dtype,
     )
 
 
-def _random_block(chunk, seeded_offset):
-    """One random block; ``seeded_offset`` is data, so the HLO has no
-    per-plan constants."""
-    # (attribute set below: the kernel accepts a traced offset, letting the
-    # fused-plan tracer hoist the seed to a program input)
+def _uniform_block(chunk, seeded_offset, dtype):
+    """One random block of the declared ``dtype``; ``seeded_offset`` is
+    data, so the HLO has no per-plan constants."""
     if BACKEND == "jax":
         import jax
 
         routed = _maybe_philox(
-            chunk.shape, seeded_offset, np.float64,
-            lambda rng, shape: rng.random(shape, dtype=np.float64),
+            chunk.shape, seeded_offset, dtype,
+            lambda rng, shape: rng.random(shape, dtype=dtype),
         )
         if routed is not None:
             return routed
         _ensure_partitionable_threefry()
         off = seeded_offset.ravel()[0]
         key = jax.random.fold_in(jax.random.key(0), off)
-        return jax.random.uniform(key, chunk.shape, dtype=np.float64)
+        return jax.random.uniform(key, chunk.shape, dtype=dtype)
     off = int(np.asarray(seeded_offset).ravel()[0])
     rng = np.random.Generator(np.random.Philox(seed=off))
-    return rng.random(chunk.shape, dtype=np.float64)
+    return rng.random(chunk.shape, dtype=dtype)
+
+
+# one named kernel a width (the name is in the traced op's scope, and the
+# two never share a compiled program); both accept a traced offset, letting
+# the fused-plan tracer hoist the seed to a program input
+def _random_block(chunk, seeded_offset):
+    return _uniform_block(chunk, seeded_offset, np.float64)
+
+
+def _random_block_f32(chunk, seeded_offset):
+    return _uniform_block(chunk, seeded_offset, np.float32)
 
 
 _random_block.traced_offsets = True
+_random_block_f32.traced_offsets = True
+
+_RANDOM_KERNELS = {
+    np.dtype(np.float64): _random_block,
+    np.dtype(np.float32): _random_block_f32,
+}
 
 
 def normal(size, *, mean=0.0, stddev=1.0, chunks=None, spec=None):

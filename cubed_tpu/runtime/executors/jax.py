@@ -371,7 +371,12 @@ class JaxExecutor(DagExecutor):
         #: fused-path plans, tests pin it). The counters that routes move
         #: while a segment is traced (``whole_array_hits``, ``rechunk_alias``,
         #: ...) are of this compute's segments, traced in this compute or
-        #: found compiled (``_SegmentProgram.routes``)
+        #: found compiled (``_SegmentProgram.routes``). So are
+        #: ``device_f32_bytes``, ``device_f64_bytes`` and ``device_f16_bytes``
+        #: (bfloat16 and float16 together; each 0, not absent): the bytes of
+        #: the floating-point values the segments' ops produce, op by op, by
+        #: the dtype the traced value has, which under ``compute_dtype`` is
+        #: not the one the plan declares (``_FLOAT_BYTES``)
         self.stats: Counter = Counter()
 
     @property
@@ -793,7 +798,7 @@ class JaxExecutor(DagExecutor):
     ) -> None:
         jax = _jax()
         self.stats = Counter(
-            dict.fromkeys(_MESH_COUNTERS, 0),
+            dict.fromkeys((*_MESH_COUNTERS, *_FLOAT_BYTES.values()), 0),
             mesh_devices=0 if self.mesh is None else self.mesh.devices.size,
             h2d_stream_bytes=0,
             h2d_bits_bytes=0,
@@ -1434,6 +1439,7 @@ class JaxExecutor(DagExecutor):
         with scope_span(
             "jax.dispatch", cat="dispatch",
             struct_hit=cached_struct is not None,
+            widest_float=_widest_float(program.routes),
         ):
             # returns when the program is enqueued, not when it has run
             outs = program.compiled(in_vals, base_vals)
@@ -2307,6 +2313,12 @@ class JaxExecutor(DagExecutor):
     def _admit(self, resident, store: str, value, target, budget: int) -> None:
         nbytes = _value_nbytes(value)
         if self._tracing:
+            # what precision the program computes in, whatever the plan says
+            jnp = _jax().numpy
+            for leaf in value.values() if isinstance(value, dict) else (value,):
+                counter = _FLOAT_BYTES.get(leaf.dtype.itemsize)
+                if counter and jnp.issubdtype(leaf.dtype, jnp.floating):
+                    self.stats[counter] += _value_nbytes(leaf)
             # inside a traced segment this is where an array is "placed":
             # without the constraint a segment whose inputs are all virtual
             # (random arrays) carries no sharding at all and XLA compiles
@@ -2442,8 +2454,9 @@ class _SegmentProgram(NamedTuple):
     #: the ``_MESH_COUNTERS`` of this program
     placement: Dict[str, int]
     #: what the routes counted while the program's ops were traced
-    #: (``rechunk_alias``, ``whole_array_hits``, ``batched_ops``, ...): of
-    #: the plan shape that found or compiled the program, not of its HLO
+    #: (``rechunk_alias``, ``whole_array_hits``, ``batched_ops``, ..., and
+    #: the ``_FLOAT_BYTES`` of the values its ops produce): of the plan
+    #: shape that found or compiled the program, not of its HLO
     routes: Dict[str, int]
 
 
@@ -2464,6 +2477,22 @@ _MESH_COUNTERS = (
     "sharded_bytes",
     "replicated_bytes",
 )
+
+
+#: counters of the floating-point values a compute's segment programs
+#: produce, by the bytes of one value as traced (``_admit``): float32,
+#: float64, and bfloat16 and float16 together. Each is 0, not absent, where
+#: nothing counts; they are kept with ``_SegmentProgram.routes``
+_FLOAT_BYTES = {
+    4: "device_f32_bytes", 8: "device_f64_bytes", 2: "device_f16_bytes",
+}
+
+
+def _widest_float(routes: Dict[str, int]) -> Optional[str]:
+    """The widest floating-point dtype a program's ops produce (a
+    ``jax.dispatch`` span's ``widest_float``), None where they produce none."""
+    widths = [w for w, name in _FLOAT_BYTES.items() if routes.get(name)]
+    return f"float{8 * max(widths)}" if widths else None
 
 
 def _count_collectives(compiled) -> Dict[str, int]:
