@@ -194,6 +194,34 @@ class TaskScope:
             span["attrs"] = attrs
         self.spans.append(span)
 
+    def fold(self, other: "TaskScope") -> None:
+        """Add what ``other`` recorded to this scope: the scope of a helper
+        thread that did a part of this task's work (the device executor's
+        flush writes chunks on one), which found no scope of its own
+        through ``current_scope()``, that being per thread. Byte and chunk
+        counts and named counters add up. ``other``'s spans get fresh ids
+        above this scope's, so that parents stay parents, and those that
+        had no parent become children of the span open here now. Call it
+        when ``other`` is closed, from the thread that owns this scope."""
+        self.bytes_read += other.bytes_read
+        self.bytes_written += other.bytes_written
+        self.chunks_read += other.chunks_read
+        self.chunks_written += other.chunks_written
+        self.virtual_bytes_read += other.virtual_bytes_read
+        for name, n in other.counters.items():
+            self.counters[name] = self.counters.get(name, 0) + n
+        base, parent = self._next_id, self._open[-1] if self._open else None
+        self._next_id += other._next_id
+        room = max(0, self.max_spans - len(self.spans))
+        for span in other.spans[:room]:
+            span = dict(span, id=span["id"] + base)
+            if "parent" in span:
+                span["parent"] += base
+            elif parent is not None:
+                span["parent"] = parent
+            self.spans.append(span)
+        self.spans_dropped += other.spans_dropped + max(0, len(other.spans) - room)
+
     def stats(self) -> dict:
         return {
             "bytes_read": self.bytes_read,

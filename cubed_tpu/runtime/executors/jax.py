@@ -14,6 +14,14 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   the whole array gives. The route is chosen from what ``_device_put``
   observes (``_streams``); ``stats["h2d_stream_bytes"]`` counts what went
   that way and ``stats["h2d_stream_declined"]`` what found no room.
+- **Streamed flush.** The way out is the mirror image
+  (``_flush_chunks``): a chunk that leaves as planes is joined into one of
+  the same two buffers and written from there (the store hands the file a
+  view, ``ZarrV2Array._write_chunk``), by a second thread, while this one
+  fetches the next chunk into the other buffer; one writer, grid order, and
+  ``_flush`` returns when every chunk is durable.
+  ``stats["flush_stream_bytes"]`` counts what reached the store that way
+  and ``stats["encode_copy_bytes"]`` what the store had to copy.
 - **Whole-array fast path.** Ops whose kernel is shape-invariant (elementwise /
   broadcasting chains, including everything the optimizer fused) and whose
   block mapping is 1:1-with-broadcast run as ONE jitted call on whole resident
@@ -97,6 +105,7 @@ Reference parity: replaces cubed's serverless executors
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import functools
 import itertools
 import logging
@@ -116,7 +125,12 @@ from ...chunks import blockdims_from_blockshape
 from ...primitive.blockwise import BlockwiseSpec, apply_blockwise
 from ...primitive.rechunk import copy_read_to_write
 from ...core.plan import create_zarr_array
-from ...observability.accounting import scope_span, spans_enabled, task_scope
+from ...observability.accounting import (
+    current_scope,
+    scope_span,
+    spans_enabled,
+    task_scope,
+)
 from ...storage.store import ZarrV2Array
 from ...storage.virtual import (
     VirtualEmptyArray,
@@ -184,12 +198,15 @@ class _Resident:
 
 
 class _Staging:
-    """One of the two host buffers that a streamed preload reads chunk files
-    into (``JaxExecutor._stream_to_device``). ``busy`` is a result of the
-    device update that consumed the bytes now in ``buffer``: until it is
-    ready the buffer may still be read by the transfer (the put is
-    asynchronous on the TPU, and on the CPU backend a put value may alias
-    the numpy memory), so it is not written."""
+    """One of the two host buffers that a chunk passes through between the
+    store and the device: a streamed preload reads chunk files into them
+    (``JaxExecutor._stream_to_device``), and a flush joins the planes of a
+    chunk into them and writes the chunk file from there
+    (``JaxExecutor._flush_chunks``). ``busy`` is a result of the device
+    update that consumed the bytes now in ``buffer``: until it is ready the
+    buffer may still be read by the transfer (the put is asynchronous on
+    the TPU, and on the CPU backend a put value may alias the numpy
+    memory), so it is not written."""
 
     __slots__ = ("buffer", "busy")
 
@@ -219,6 +236,16 @@ class _Staging:
             shift = -raw.ctypes.data % mmap.PAGESIZE
             self.buffer = raw[shift : shift + nbytes]
         return self.buffer
+
+    def array(self, shape, dtype: np.dtype) -> np.ndarray:
+        """The head of the buffer, free to be written, as a C-contiguous
+        array of ``shape`` and ``dtype``."""
+        nbytes = math.prod(shape) * dtype.itemsize
+        return self.sized(nbytes)[:nbytes].view(dtype).reshape(shape)
+
+    def holds(self, host: np.ndarray) -> bool:
+        """Whether ``host`` lies in the buffer."""
+        return self.buffer is not None and np.may_share_memory(host, self.buffer)
 
 
 def _moves_values(op) -> bool:
@@ -325,9 +352,11 @@ class JaxExecutor(DagExecutor):
         #: what ``_leaves_as_planes`` reads the room for its planes from
         self._resident: Dict[str, _Resident] = {}
         self._spilling = False
-        #: the host memory of a streamed preload: two chunk-sized buffers
-        #: that take turns, kept for every source this executor loads and
-        #: released with it
+        #: the host memory of a streamed preload and of a flush: two
+        #: chunk-sized buffers that take turns, kept for every source this
+        #: executor loads and every array it stores, and released with it.
+        #: One pair serves both ways: the executor's units run one after
+        #: another, so no flush runs while a stream holds a buffer
         self._staging = (_Staging(), _Staging())
         self._prepared_bases: Dict[int, Any] = {}
         #: keys of the task events of this compute in the order they were
@@ -354,7 +383,11 @@ class JaxExecutor(DagExecutor):
         #: device), ``h2d_bytes`` / ``d2h_bytes`` (bytes moved by ``_device_put``
         #: / ``_to_host``), ``h2d_stream_bytes`` (the part of ``h2d_bytes`` that
         #: went chunk by chunk through the staging buffers; 0, not absent,
-        #: where nothing did), ``h2d_stream_declined`` (stored arrays that
+        #: where nothing did), ``flush_stream_bytes`` / ``encode_copy_bytes``
+        #: (the part of ``d2h_bytes`` that reached the store from a staging
+        #: buffer with no copy on the host after the join, and the bytes the
+        #: store had to copy in a flush's writes; each 0, not absent),
+        #: ``h2d_stream_declined`` (stored arrays that
         #: qualified for the stream and were put whole for want of room in
         #: HBM), ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
         #: left as 32-bit planes), ``d2h_plane_strided_bytes`` (the part of
@@ -657,13 +690,18 @@ class JaxExecutor(DagExecutor):
             self.stats["h2d_stream_bytes"] += piece.nbytes
         return whole
 
-    def _to_host(self, value, dtype) -> np.ndarray:
+    def _to_host(self, value, dtype, stage: Optional[_Staging] = None) -> np.ndarray:
         """Device -> host: a device value (or dict of record fields) as a
         host array of the target's ``dtype``; undoes ``_device_put``.
 
         A large 64-bit value on a device without native float64 leaves as
         two 32-bit planes (``_fetch_as_planes``); everything else is fetched
-        as it is. Either way the host array is the same, bit for bit."""
+        as it is. Either way the host array is the same, bit for bit. The
+        planes are joined into ``stage``'s buffer where the caller gives
+        one, and the host array is then a view of it (``stage.holds``),
+        valid until the caller next hands that buffer over; a value fetched
+        as it is, and a record, is the runtime's own array or a fresh one
+        and leaves the buffer alone."""
         if isinstance(value, dict):
             fields = {k: self._to_host(value[k], dtype[k]) for k in dtype.names}
             rec = np.empty(next(iter(fields.values())).shape, dtype=dtype)
@@ -678,7 +716,7 @@ class JaxExecutor(DagExecutor):
             if sp.recording:
                 _jax().block_until_ready(value)
         with scope_span("jax.d2h", cat="transfer") as sp:
-            host, strided = self._fetch_as_planes(value)
+            host, strided = self._fetch_as_planes(value, stage)
             planes = sp.attrs["planes"] = host is not None
             sp.attrs["strided"] = strided
             if not planes:
@@ -718,9 +756,12 @@ class JaxExecutor(DagExecutor):
             return False
         return True
 
-    def _fetch_as_planes(self, value) -> Tuple[Optional[np.ndarray], bool]:
+    def _fetch_as_planes(
+        self, value, stage: Optional[_Staging] = None
+    ) -> Tuple[Optional[np.ndarray], bool]:
         """``value`` through ``_split_planes`` on the device, one fetch of
-        both planes, ``_join_planes`` on the host; and whether a plane
+        both planes, ``_join_planes`` on the host (into ``stage``'s buffer
+        where there is one, else into a fresh array); and whether a plane
         arrived in another order than row-major, so that the join read it
         strided. (None, False) where it does not leave as planes
         (``_leaves_as_planes``) or the device reports values that the split
@@ -734,8 +775,9 @@ class JaxExecutor(DagExecutor):
             self.stats["d2h_plane_inexact"] += 1
             self.stats["host_syncs"] += 1
             return None, False
-        joined = _join_planes(first, second, np.dtype(value.dtype))
-        return joined, _planes_strided(first, second)
+        dtype = np.dtype(value.dtype)
+        out = None if stage is None else stage.array(first.shape, dtype)
+        return _join_planes(first, second, dtype, out), _planes_strided(first, second)
 
     # ------------------------------------------------------------------
 
@@ -801,6 +843,8 @@ class JaxExecutor(DagExecutor):
             dict.fromkeys((*_MESH_COUNTERS, *_FLOAT_BYTES.values()), 0),
             mesh_devices=0 if self.mesh is None else self.mesh.devices.size,
             h2d_stream_bytes=0,
+            flush_stream_bytes=0,
+            encode_copy_bytes=0,
             h2d_bits_bytes=0,
             rechunk_host_whole=0,
             rechunk_host_copy=0,
@@ -2369,7 +2413,32 @@ class JaxExecutor(DagExecutor):
             sp.attrs["chunks"] = self._flush_chunks(res.value, concrete)
 
     def _flush_chunks(self, value, concrete) -> int:
-        """``_flush``'s fetches and writes; the number of chunks written."""
+        """``_flush``'s fetches and writes; the number of chunks written.
+
+        A pipeline of depth two over the chunk grid, in grid order, the
+        mirror image of ``_stream_to_device``: this thread slices chunk
+        k + 1 on the device, fetches it and joins its planes into staging
+        buffer (k + 1) mod 2 while a second thread writes chunk k from
+        buffer k mod 2 (``ZarrV2Array.__setitem__``: file write, fsync,
+        rename, directory fsync, CRC-32, manifest line, all of which let
+        the other thread run). Chunk k + 1's write starts once chunk k's
+        has returned, so one writer enters the chunks in grid order, a
+        buffer is rewritten only after the write that read it has
+        returned, and when this returns every chunk is durable and in the
+        manifest. The first error of either side, a cancellation among
+        them, is raised from here: no chunk is started after it and no
+        thread is left. On the device one chunk's slice and planes exist
+        at a time, as before.
+
+        The writer finds the compute's cancellation token through a copy of
+        this thread's context and works in a task scope of its own, since
+        spans, byte counts and injected storage faults find theirs through
+        the calling thread; each write's scope is folded into this
+        thread's. ``stats["flush_stream_bytes"]`` counts the bytes that
+        reached the store from a staging buffer with no copy on the host
+        after the join, ``stats["encode_copy_bytes"]`` those the store had
+        to copy (``encode_copy_bytes`` of the write's scope). A value that
+        does not leave as planes takes the same pipeline and no buffer."""
         shape = tuple(concrete.shape)
         if not shape:
             concrete[()] = self._to_host(value, concrete.dtype)
@@ -2406,18 +2475,64 @@ class JaxExecutor(DagExecutor):
                         "(parallel.mesh.sharding_for_chunks prefers one)"
                     )
             coords_iter = iter(mine)
-        chunks = 0
-        for idx in coords_iter:
-            sel = get_item(chunkset, idx)
-            # the device slice is not bound to a name here: it would stay
-            # alive on the device while the next chunk is sliced
-            concrete[sel] = self._to_host(
-                {k: v[sel] for k, v in value.items()}
-                if isinstance(value, dict)
-                else value[sel],
-                concrete.dtype,
+        outer = current_scope()
+
+        def write(sel, host):
+            """On the writer's thread: (the write's scope, what it raised)."""
+            scoped = (
+                task_scope(_SCOPE_SPANS) if outer is not None
+                else contextlib.nullcontext()
             )
-            chunks += 1
+            with scoped as inner:
+                try:
+                    concrete[sel] = host
+                except BaseException as error:  # raised again by ``settle``
+                    return inner, error
+            return inner, None
+
+        def settle(pending: list) -> None:
+            """Wait for the write in flight, if there is one: its records
+            into this thread's scope, its error raised."""
+            if not pending:
+                return
+            future, staged_nbytes = pending.pop()
+            inner, error = future.result()
+            copied = None  # not observed without a scope
+            if inner is not None:
+                copied = inner.counters.get("encode_copy_bytes", 0)
+                outer.fold(inner)
+                self.stats["encode_copy_bytes"] += copied
+            if error is not None:
+                raise error
+            if copied == 0:
+                self.stats["flush_stream_bytes"] += staged_nbytes
+
+        chunks = 0
+        pending: list = []  # at most the one write in flight
+        with ThreadPoolExecutor(1, thread_name_prefix="cubed-tpu-flush") as pool:
+            try:
+                for k, idx in enumerate(coords_iter):
+                    sel = get_item(chunkset, idx)
+                    stage = self._staging[k % 2]
+                    # the device slice is not bound to a name here: it would
+                    # stay alive on the device while the next chunk is sliced
+                    host = self._to_host(
+                        {f: v[sel] for f, v in value.items()}
+                        if isinstance(value, dict)
+                        else value[sel],
+                        concrete.dtype,
+                        stage,
+                    )
+                    settle(pending)
+                    pending.append((
+                        pool.submit(contextvars.copy_context().run, write, sel, host),
+                        host.nbytes if stage.holds(host) else 0,
+                    ))
+                    chunks += 1
+            finally:
+                # also on an error of this side: the write in flight is
+                # waited for and its records kept
+                settle(pending)
         return chunks
 
 
@@ -2709,9 +2824,15 @@ def _chunk_writer():
     return jax.jit(write, static_argnums=3, donate_argnums=0)
 
 
-def _join_planes(first: np.ndarray, second: np.ndarray, dtype: np.dtype) -> np.ndarray:
+def _join_planes(
+    first: np.ndarray, second: np.ndarray, dtype: np.dtype,
+    out: Optional[np.ndarray] = None,
+) -> np.ndarray:
     """The host array of ``dtype`` that ``_split_planes``'s two planes stand
-    for, in one pass into one fresh C-contiguous array.
+    for, in one pass into ``out``, a C-contiguous array of the planes' shape
+    and of ``dtype`` that the caller keeps (a fresh one where it gives
+    none: 200 MB of pages never touched cost 200 ms to fill, touched ones
+    33).
 
     float64: ``float64(head) + float64(tail)``, one correctly rounded add of
     two exactly represented numbers, which is what the runtime computes
@@ -2724,7 +2845,8 @@ def _join_planes(first: np.ndarray, second: np.ndarray, dtype: np.dtype) -> np.n
     read strided by every thread: a ``reshape(-1)`` of such a plane would
     be a transposing copy on one thread, 230 ms for 100 MB (PERF.md
     section 6, PR 32)."""
-    out = np.empty(first.shape, dtype)
+    if out is None:
+        out = np.empty(first.shape, dtype)
     if _planes_strided(first, second):
         flat, a, b, axis = out, first, second, int(np.argmax(first.shape))
     else:

@@ -331,14 +331,16 @@ class FaultInjector:
             "storage_throttle", key, self.config.storage_throttle_rate
         )
 
-    def storage_corrupt_fault(self, key: str, data: bytes) -> Optional[bytes]:
+    def storage_corrupt_fault(self, key: str, data) -> Optional[bytes]:
         """Corrupted bytes for this chunk write, or None to write faithfully.
+        ``data`` is ``bytes`` or a view of the writer's memory (flat,
+        unsigned bytes); what comes back is a copy either way.
 
         The corruption itself is a pure function of ``(seed, key)`` — a
         single bit-flip at a seeded position, or truncation to half length —
         so a replayed chaos run corrupts identically; *whether* a given
         write is corrupted rolls per occurrence like every other site."""
-        if not data or current_scope() is None:
+        if len(data) == 0 or current_scope() is None:
             return None
         # corruption targets CHUNK files only (digit-dotted names): rotting
         # .zarray/manifest sidecars models a different failure (covered by
@@ -357,7 +359,7 @@ class FaultInjector:
             out = bytearray(data)
             out[pos] ^= 1 << (digest[5] % 8)
             return bytes(out)
-        return data[: len(data) // 2]
+        return bytes(data[: len(data) // 2])
 
     # -- task bodies ----------------------------------------------------
 

@@ -294,6 +294,10 @@ class ChunkCache:
         n = len(data)
         if n > self.max_bytes:
             return False
+        # the cache keeps what it is given, and a writer hands over a view
+        # of memory that is its own again after the call: an entry is
+        # immutable bytes (``bytes`` of ``bytes`` is the same object)
+        data = bytes(data)
         evicted = 0
         with self._lock:
             ck = (str(store), str(key))
@@ -856,19 +860,23 @@ def end_task_produced() -> List[tuple]:
     return produced or []
 
 
-def note_chunk_written(store: str, key: str, data: bytes) -> None:
+def note_chunk_written(store: str, key: str, data) -> int:
     """Storage write hook: cache the stored bytes and record the
     advertisement. A no-op outside an armed fleet worker — and always
-    AFTER the durable write, so the store remains the sole durable tier."""
+    AFTER the durable write, so the store remains the sole durable tier.
+    ``data`` is ``bytes`` or a view of the writer's memory, of which the
+    cache keeps a copy; returns the bytes it copied to keep them (0 for
+    ``bytes``, and where nothing was cached)."""
     rt = _runtime
     cfg = _armed
     if rt is None or cfg is None or not cfg.enabled:
-        return
+        return 0
     if not rt.cache.put(store, key, data):
-        return  # over budget: advertising an uncached chunk is a lie
+        return 0  # over budget: advertising an uncached chunk is a lie
     produced = getattr(_tls, "produced", None)
     if produced is not None:
         produced.append((str(store), str(key), len(data)))
+    return 0 if isinstance(data, bytes) else len(data)
 
 
 def _verify(data: bytes, entry: dict) -> bool:

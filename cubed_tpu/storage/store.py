@@ -433,6 +433,14 @@ def _codec_from_meta(comp: Optional[dict]):
     )
 
 
+def _copied(made: np.ndarray, given) -> bool:
+    """Whether ``made``, numpy's conversion of ``given``, is a copy of it
+    and not a view of the same memory."""
+    return made.size > 0 and not (
+        isinstance(given, np.ndarray) and np.may_share_memory(made, given)
+    )
+
+
 def _encode_fill(fill_value: Any, dtype: np.dtype) -> Any:
     if fill_value is None:
         return None
@@ -826,18 +834,33 @@ class ZarrV2Array:
                     ):
                         time.sleep(delay)
 
-    def _write_chunk(self, idx: tuple[int, ...], arr: np.ndarray) -> None:
+    def _write_chunk(
+        self, idx: tuple[int, ...], arr: np.ndarray, copied: bool = False
+    ) -> None:
+        """One whole chunk, durable and in the manifest when this returns.
+
+        The bytes are written from where they lie: a C-contiguous ``arr``
+        of the store's dtype is handed to the file, the checksum and the
+        codec as a view, and is the caller's again once this returns (what
+        keeps bytes beyond that, the peer cache and an injected corruption,
+        takes a copy of its own). Any other input is made contiguous
+        first, which is the one copy. ``copied``: the caller made ``arr``
+        by copying what it was given (``__setitem__``: a padded or merged
+        chunk, a converted dtype); either way the chunk's bytes count as
+        ``encode_copy_bytes``, and the ``chunk_encode`` span says so."""
         # cooperative cancellation: checked BEFORE the write starts — an
         # abort never interrupts an atomic chunk write mid-flight, so the
         # store/manifest/journal stay consistent for resume
         cancellation.check_current()
         key = self._chunk_key(idx)
         with scope_span("chunk_encode", cat="storage", key=key) as sp:
-            arr = np.ascontiguousarray(arr, dtype=self.dtype)
-            data = arr.tobytes()
+            given, arr = arr, np.ascontiguousarray(arr, dtype=self.dtype)
+            copied = sp.attrs["copied"] = copied or _copied(arr, given)
+            data = memoryview(arr.reshape(-1).view(np.uint8))
             if self._codec is not None:
                 data = self._codec[0](data)
             sp.attrs["bytes"] = len(data)
+        kept = 0
         with scope_span(
             "storage_write", cat="storage", key=key, bytes=len(data)
         ):
@@ -860,8 +883,12 @@ class ZarrV2Array:
                 # the cached copy costs a store read, never data. Only
                 # checksummed writes are cached: readers refuse peer bytes
                 # they cannot verify against the manifest
-                p2p.note_chunk_written(self.store, key, data)
+                kept = p2p.note_chunk_written(self.store, key, data)
         record_bytes_written(self.store, len(data))
+        if copied or kept:
+            record_scoped_counter(
+                "encode_copy_bytes", (arr.nbytes if copied else 0) + kept
+            )
 
     def _write_bytes_throttle_paced(self, key: str, data: bytes) -> None:
         """Atomic chunk write with breaker-paced in-place retries for
@@ -994,14 +1021,15 @@ class ZarrV2Array:
         return out
 
     def __setitem__(self, key, value) -> None:
+        given, value = value, np.asarray(value, dtype=self.dtype)
+        converted = _copied(value, given)
         if self.ndim == 0:
-            self._write_chunk((), np.asarray(value, dtype=self.dtype))
+            self._write_chunk((), value, converted)
             return
         sel = self._normalize_key(key)
         if any((s.step or 1) != 1 for s in sel):
             raise IndexError("strided writes not supported")
         region_shape = tuple(s.stop - s.start for s in sel)
-        value = np.asarray(value, dtype=self.dtype)
         value = np.broadcast_to(value, region_shape)
 
         for cidx in self._chunks_overlapping(sel):
@@ -1023,12 +1051,12 @@ class ZarrV2Array:
                 for cs, clen, ext in zip(c_starts, self.chunks, self.shape)
             )
             if full_cover and covered_extent == self.chunks:
-                self._write_chunk(cidx, piece)
+                self._write_chunk(cidx, piece, converted)
             elif full_cover:
                 # edge chunk fully covered within array bounds: pad to chunk shape
                 chunk = self._empty_chunk()
                 chunk[tuple(slice(0, e) for e in covered_extent)] = piece
-                self._write_chunk(cidx, chunk)
+                self._write_chunk(cidx, chunk, True)
             else:
                 chunk = self._read_chunk(cidx)
                 if chunk is None:
@@ -1036,7 +1064,7 @@ class ZarrV2Array:
                 else:
                     chunk = chunk.copy()
                 chunk[tuple(chunk_sel)] = piece
-                self._write_chunk(cidx, chunk)
+                self._write_chunk(cidx, chunk, True)
 
     def _chunks_overlapping(self, sel: tuple[slice, ...]):
         ranges = []

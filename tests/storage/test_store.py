@@ -472,3 +472,235 @@ def test_vanished_chunk_read_fails_loudly_not_fill(tmp_path, monkeypatch):
     monkeypatch.setattr(_LocalIO, "read_bytes", gone)
     with pytest.raises(FileNotFoundError):
         z[...]
+
+
+# -- a chunk's bytes are written from where they lie ------------------------
+#
+# ``_write_chunk`` hands the file, the checksum and the codec a view of a
+# C-contiguous array of the store's dtype: no ``tobytes()`` copy. What keeps
+# bytes past the call (the peer cache, an injected corruption) takes its own.
+
+
+def _spy_on_chunk_writes(monkeypatch):
+    """What ``write_bytes_atomic`` was handed for every chunk file."""
+    from cubed_tpu.storage.store import _LocalIO
+
+    handed = []
+    real = _LocalIO.write_bytes_atomic
+
+    def write(self, name, data, inject=True):
+        if not name.startswith("."):
+            handed.append(data)
+        return real(self, name, data, inject)
+
+    monkeypatch.setattr(_LocalIO, "write_bytes_atomic", write)
+    return handed
+
+
+_WRITTEN = {
+    # name: (what is assigned, shape, chunks, dtype of the store, copied?)
+    "contiguous": (lambda: np.arange(24.0).reshape(4, 6), (4, 6), (4, 6), "f8", False),
+    "a_view_of_rows": (lambda: np.arange(48.0).reshape(8, 6)[4:], (4, 6), (4, 6), "f8", False),
+    "transposed": (lambda: np.arange(24.0).reshape(6, 4).T, (4, 6), (4, 6), "f8", True),
+    "other_dtype": (lambda: np.arange(24, dtype=np.int32).reshape(4, 6), (4, 6), (4, 6), "f8", True),
+    "a_list": (lambda: [[1.0, 2.0], [3.0, 4.0]], (2, 2), (2, 2), "f8", True),
+    "zero_d": (lambda: np.array(2.5), (), (), "f8", False),
+    # the store writes the one chunk that the empty region touches, all padding
+    "zero_size": (lambda: np.zeros((0, 6)), (0, 6), (1, 6), "f8", True),
+    "a_record": (
+        lambda: np.array([(1, 2.5), (3, 4.5)], dtype=[("n", "<i4"), ("x", "<f8")]),
+        (2,), (2,), [("n", "<i4"), ("x", "<f8")], False,
+    ),
+}
+
+
+@pytest.mark.parametrize("compressor", [None, {"id": "zlib", "level": 1}], ids=["raw", "zlib"])
+@pytest.mark.parametrize("case", sorted(_WRITTEN))
+def test_a_chunk_is_written_from_where_it_lies(tmp_path, monkeypatch, case, compressor):
+    import zlib
+
+    from cubed_tpu.observability.accounting import task_scope
+    from cubed_tpu.storage import integrity
+
+    make, shape, chunks, dtype, copies = _WRITTEN[case]
+    value = make()
+    handed = _spy_on_chunk_writes(monkeypatch)
+    z = open_zarr_array(str(tmp_path / "a.zarr"), "w", shape=shape, dtype=dtype,
+                        chunks=chunks, compressor=compressor)
+    with integrity.scoped("write"), task_scope() as scope:
+        if shape:
+            z[...] = value
+        else:
+            z[()] = value
+    # the same file, checksum and manifest entry as the copying write gave
+    raw = np.ascontiguousarray(value, dtype=np.dtype(dtype)).tobytes() or bytes(48)
+    want = zlib.compress(raw, 1) if compressor else raw
+    (name,) = [n for n in os.listdir(z.store) if not n.startswith(".")]
+    with open(os.path.join(z.store, name), "rb") as f:
+        assert f.read() == want
+    entry = open_zarr_array(z.store, "r")._manifest()[0][name]
+    assert (entry["c"], entry["n"]) == (zlib.crc32(want), len(want))
+    assert scope.bytes_written == len(want) and scope.chunks_written == 1
+    (data,) = handed
+    assert bytes(data) == want
+    assert scope.counters.get("encode_copy_bytes", 0) == (len(raw) if copies else 0)
+    if compressor is None and not copies:
+        # not bytes made for the call: the caller's own memory
+        assert isinstance(data, memoryview) and data.format == "B" and data.ndim == 1
+        assert np.shares_memory(np.frombuffer(data, np.uint8), np.asarray(value))
+    readback = open_zarr_array(z.store, "r")
+    got = readback[...] if shape else readback[()]
+    assert got.tobytes() == (raw if got.size else b"")
+
+
+@pytest.mark.parametrize("shape, chunks, edge", [((5, 6), (4, 6), True), ((8, 6), (4, 6), False)],
+                         ids=["ragged", "aligned"])
+def test_a_padded_or_merged_chunk_counts_as_copied(tmp_path, shape, chunks, edge):
+    from cubed_tpu.observability.accounting import task_scope
+
+    z = open_zarr_array(str(tmp_path / "a.zarr"), "w", shape=shape, dtype="f8", chunks=chunks)
+    value = np.arange(float(np.prod(shape))).reshape(shape)
+    with task_scope() as scope:
+        z[...] = value
+    chunk = 4 * 6 * 8
+    # a full chunk cut from rows is contiguous and goes as it is; the edge
+    # chunk is copied into its padding
+    assert scope.counters.get("encode_copy_bytes", 0) == (chunk if edge else 0)
+    assert scope.bytes_written == 2 * chunk
+    with task_scope() as scope:
+        z[1:3, 2:4] = -1.0  # read, merged, written
+    assert scope.counters["encode_copy_bytes"] == chunk
+    value[1:3, 2:4] = -1.0
+    np.testing.assert_array_equal(z[...], value)
+
+
+def test_the_encode_span_says_whether_the_chunk_was_copied(tmp_path, monkeypatch):
+    from cubed_tpu.observability import accounting
+    from cubed_tpu.observability.accounting import task_scope
+
+    monkeypatch.setenv(accounting.SPANS_ENV_VAR, "1")
+    z = open_zarr_array(str(tmp_path / "a.zarr"), "w", shape=(4, 4), dtype="f8", chunks=(4, 4))
+    with task_scope() as scope:
+        z[...] = np.ones((4, 4))
+        z[...] = np.ones((4, 4)).T
+        z[...] = np.ones((4, 4), np.float32)
+    encodes = [s for s in scope.spans if s["name"] == "chunk_encode"]
+    assert [s["attrs"]["copied"] for s in encodes] == [False, True, True]
+    assert all(s["attrs"]["bytes"] == 128 for s in encodes)
+
+
+@pytest.fixture
+def peer_cache():
+    """This process armed as a fleet worker with a peer cache."""
+    from cubed_tpu.runtime import transfer
+
+    runtime = transfer.PeerRuntime("w-test", max_cache_bytes=1 << 20)
+    transfer.set_worker_runtime(runtime)
+    transfer.arm_from_wire(transfer.PeerConfig(enabled=True).to_wire())
+    try:
+        yield runtime.cache
+    finally:
+        transfer.arm_from_wire(None)
+        transfer.set_worker_runtime(None)
+
+
+@pytest.mark.parametrize("armed", [False, True], ids=["no_peer_cache", "peer_cache_armed"])
+def test_the_caller_may_overwrite_its_array_once_the_write_returned(
+    tmp_path, request, armed
+):
+    import zlib
+
+    from cubed_tpu.observability.accounting import task_scope
+    from cubed_tpu.storage import integrity
+
+    cache = request.getfixturevalue("peer_cache") if armed else None
+    z = open_zarr_array(str(tmp_path / "a.zarr"), "w", shape=(8, 4), dtype="f8", chunks=(4, 4))
+    buffer = np.empty((4, 4))
+    written = []
+    with integrity.scoped("write"), task_scope() as scope:
+        for k in range(2):
+            buffer[...] = np.arange(16.0).reshape(4, 4) + 100 * k
+            written.append(buffer.tobytes())
+            z[4 * k : 4 * k + 4, :] = buffer
+            buffer[...] = np.nan  # the caller's again
+    for k, name in enumerate(("0.0", "1.0")):
+        with open(os.path.join(z.store, name), "rb") as f:
+            assert f.read() == written[k]
+        entry = open_zarr_array(z.store, "r")._manifest()[0][name]
+        assert (entry["c"], entry["n"]) == (zlib.crc32(written[k]), 128)
+        if armed:
+            # the cache kept bytes of its own, with the checksum of the file
+            kept, crc = cache.get_with_crc(z.store, name)
+            assert type(kept) is bytes and kept == written[k] and crc == entry["c"]
+    # the cache's copy is the one copy, and it is counted
+    assert scope.counters.get("encode_copy_bytes", 0) == (256 if armed else 0)
+
+
+def test_a_chunk_too_large_for_the_peer_cache_is_not_copied(tmp_path, peer_cache):
+    from cubed_tpu.observability.accounting import task_scope
+
+    peer_cache.max_bytes = 100
+    z = open_zarr_array(str(tmp_path / "a.zarr"), "w", shape=(4, 4), dtype="f8", chunks=(4, 4))
+    with task_scope() as scope:
+        z[...] = np.ones((4, 4))
+    assert peer_cache.get(z.store, "0.0") is None
+    assert "encode_copy_bytes" not in scope.counters
+
+
+@pytest.mark.parametrize("leaves_tmp", [False, True])
+def test_injected_write_faults_behave_as_before_when_handed_a_view(tmp_path, leaves_tmp):
+    from cubed_tpu.observability.accounting import task_scope
+    from cubed_tpu.runtime import faults
+
+    z = open_zarr_array(str(tmp_path / "a.zarr"), "w", shape=(4, 4), dtype="f8", chunks=(4, 4))
+    value = np.arange(16.0).reshape(4, 4)
+    config = faults.FaultConfig(
+        seed=3, storage_write_failure_rate=1.0, storage_write_leaves_tmp=leaves_tmp
+    )
+    with faults.scoped(config), task_scope():
+        with pytest.raises(faults.FaultInjectedIOError):
+            z[...] = value
+    names = [n for n in os.listdir(z.store) if not n.startswith(".")]
+    if leaves_tmp:
+        # a writer killed mid-write: half the chunk in a temp file, no chunk
+        (tmp,) = names
+        assert tmp.startswith("0.0.") and tmp.endswith(".tmp")
+        with open(os.path.join(z.store, tmp), "rb") as f:
+            assert f.read() == value.tobytes()[:64]
+    else:
+        assert names == []
+    assert z.nchunks_initialized == 0
+
+
+def test_an_injected_corruption_is_a_copy_and_the_callers_array_is_untouched(tmp_path):
+    import zlib
+
+    from cubed_tpu.observability.accounting import task_scope
+    from cubed_tpu.runtime import faults
+    from cubed_tpu.storage import integrity
+
+    value = np.arange(16.0).reshape(4, 4)
+    meant = value.tobytes()
+    seen = set()
+    for seed in range(8):  # both kinds: a flipped bit, a file cut in half
+        z = open_zarr_array(str(tmp_path / f"a{seed}.zarr"), "w", shape=(4, 4), dtype="f8",
+                            chunks=(4, 4))
+        with integrity.scoped("write"), task_scope(), faults.scoped(
+            faults.FaultConfig(seed=seed, storage_corrupt_rate=1.0)
+        ):
+            z[...] = value
+        with open(os.path.join(z.store, "0.0"), "rb") as f:
+            stored = f.read()
+        assert stored != meant and len(stored) in (128, 64)
+        seen.add(len(stored))
+        assert value.tobytes() == meant
+        # the manifest holds the checksum of what was meant
+        entry = open_zarr_array(z.store, "r")._manifest()[0]["0.0"]
+        assert (entry["c"], entry["n"]) == (zlib.crc32(meant), 128)
+        view = memoryview(value.reshape(-1).view(np.uint8))
+        with task_scope():
+            again = faults.FaultInjector(
+                faults.FaultConfig(seed=seed, storage_corrupt_rate=1.0)
+            ).storage_corrupt_fault(f"a{seed}.zarr/0.0", view)
+        assert type(again) is bytes and again == stored
+    assert seen == {128, 64}
