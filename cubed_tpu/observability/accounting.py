@@ -143,6 +143,7 @@ class TaskScope:
         "spans",
         "spans_dropped",
         "max_spans",
+        "thread",
         "_open",
         "_next_id",
     )
@@ -152,6 +153,9 @@ class TaskScope:
         #: stats are shipped; the device executor's in-process scopes, each
         #: a whole array's worth of chunk IO, take more room)
         self.max_spans = max_spans
+        #: name of the thread the scope was made on, which is the one that
+        #: records into it (``fold`` marks a helper thread's spans with it)
+        self.thread = threading.current_thread().name
         self.bytes_read = 0
         self.bytes_written = 0
         self.chunks_read = 0
@@ -201,8 +205,12 @@ class TaskScope:
         through ``current_scope()``, that being per thread. Byte and chunk
         counts and named counters add up. ``other``'s spans get fresh ids
         above this scope's, so that parents stay parents, and those that
-        had no parent become children of the span open here now. Call it
-        when ``other`` is closed, from the thread that owns this scope."""
+        had no parent become children of the span open here now. Spans of
+        another thread than this scope's say so (attr ``thread``): they ran
+        beside their new parent and not inside its time, and a reader of
+        self time leaves them out of it (``_ComputeAggregator._fold_spans``).
+        Call it when ``other`` is closed, from the thread that owns this
+        scope."""
         self.bytes_read += other.bytes_read
         self.bytes_written += other.bytes_written
         self.chunks_read += other.chunks_read
@@ -215,6 +223,8 @@ class TaskScope:
         room = max(0, self.max_spans - len(self.spans))
         for span in other.spans[:room]:
             span = dict(span, id=span["id"] + base)
+            if other.thread != self.thread:
+                span["attrs"] = {"thread": other.thread, **span.get("attrs", {})}
             if "parent" in span:
                 span["parent"] += base
             elif parent is not None:

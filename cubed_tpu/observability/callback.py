@@ -226,17 +226,24 @@ class _ComputeAggregator(EventLogCallback):
     def _fold_spans(self, spans: list) -> None:
         """Add one task's spans to the per-name totals. A span's ``parent``
         is the ``id`` of the span of the same task that enclosed it, so
-        self time is duration minus the children's."""
+        self time is duration minus the children's: those recorded on the
+        span's own thread. What a helper thread did under the span (attr
+        ``thread``, ``TaskScope.fold``) ran beside it, not in its time."""
+
+        def thread(s):
+            return s.get("attrs", {}).get("thread")
+
         children: dict = {}
         for s in spans:
             parent = s.get("parent")
             if parent is not None:
-                children[parent] = children.get(parent, 0.0) + s["dur"]
+                key = (parent, thread(s))
+                children[key] = children.get(key, 0.0) + s["dur"]
         for s in spans:
             name, dur = s["name"], s["dur"]
             self._span_s[name] = self._span_s.get(name, 0.0) + dur
             self._span_self_s[name] = self._span_self_s.get(name, 0.0) + max(
-                0.0, dur - children.get(s.get("id"), 0.0)
+                0.0, dur - children.get((s.get("id"), thread(s)), 0.0)
             )
             self._span_n[name] = self._span_n.get(name, 0) + 1
 

@@ -93,10 +93,14 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   times its phases with ``scope_span`` (``jax.preload``, ``jax.h2d`` (one
   a chunk of a streamed preload), ``jax.struct_key``, ``jax.trace_lower``,
   ``jax.compile``, ``jax.dispatch``, ``jax.flush``, ``jax.device_wait``,
-  ``jax.d2h``, and ``jax.rechunk`` around a rechunk through storage): the
+  ``jax.d2h``, ``jax.write_wait`` (this thread blocked on the flush's
+  writer), and ``jax.rechunk`` around a rechunk through storage): the
   phases' only clock (the task events keep their timestamps), a no-op unless a ``TraceCollector`` is attached or
   ``CUBED_TPU_TASK_SPANS=1`` (docs/observability.md, "Device executor
-  spans").
+  spans"). What the two pipelines wait for, and how many pages a preload
+  makes resident, is counted armed or not, on ``perf_counter_ns`` and from
+  ``/proc/self/statm``: ``stats["write_wait_us"]``, ``stage_wait_us``,
+  ``preload_page_faults``.
 
 Reference parity: replaces cubed's serverless executors
 (cubed/runtime/executors/*) with a device-mesh substrate.
@@ -214,11 +218,15 @@ class _Staging:
         self.buffer: Optional[np.ndarray] = None
         self.busy = None
 
-    def release(self) -> None:
-        """Wait until the buffer may be written again."""
-        if self.busy is not None:
-            self.busy.block_until_ready()
-            self.busy = None
+    def release(self) -> int:
+        """Wait until the buffer may be written again: the microseconds the
+        device update took to let go of it, 0 where none held it."""
+        if self.busy is None:
+            return 0
+        started = time.perf_counter_ns()
+        self.busy.block_until_ready()
+        self.busy = None
+        return (time.perf_counter_ns() - started) // 1000
 
     def sized(self, nbytes: int) -> np.ndarray:
         """The buffer, free to be written, with room for ``nbytes``: made on
@@ -267,6 +275,19 @@ def _holds(dtype, *kinds) -> bool:
     if dtype.fields is not None:
         return any(_holds(field[0], *kinds) for field in dtype.fields.values())
     return any(dtype == kind for kind in kinds)
+
+
+def _resident_pages() -> int:
+    """The pages of this process that are resident now
+    (``/proc/self/statm``), 0 where the system has no such file. Its growth
+    over a phase that frees nothing is the pages the phase's first touches
+    faulted in (``preload_page_faults``); the kernel's own count of faults
+    (``ru_minflt``) is not kept by every host: gVisor's reads 0."""
+    try:
+        with open("/proc/self/statm", "rb") as statm:
+            return int(statm.read().split()[1])
+    except (OSError, ValueError, IndexError):
+        return 0
 
 
 def _value_nbytes(value) -> int:
@@ -387,6 +408,12 @@ class JaxExecutor(DagExecutor):
         #: (the part of ``d2h_bytes`` that reached the store from a staging
         #: buffer with no copy on the host after the join, and the bytes the
         #: store had to copy in a flush's writes; each 0, not absent),
+        #: ``write_wait_us`` / ``stage_wait_us`` (microseconds this thread
+        #: was blocked on the flush's writer thread, and on a device update
+        #: that still read a staging buffer, in ``_stream_to_device``),
+        #: ``preload_page_faults`` (the pages the preloads made resident:
+        #: the growth of the process's resident set over each; the three
+        #: each 0, not absent),
         #: ``h2d_stream_declined`` (stored arrays that
         #: qualified for the stream and were put whole for want of room in
         #: HBM), ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
@@ -659,9 +686,13 @@ class JaxExecutor(DagExecutor):
         The buffers take turns, so the read of chunk k + 1 overlaps the
         transfer and update of chunk k; chunk k's span ends with the wait
         for chunk k - 1's update, which frees the buffer that chunk k + 1 is
-        read into. A source's last update is waited for by whichever read
-        needs its buffer next. ``transferred`` is ``_device_put``'s: the
-        byte count and the bit-pattern view."""
+        read into (the span's ``wait_us``). A source's last update is
+        waited for by whichever read needs its buffer next: the next
+        source's, here, or a flush's join, in its ``jax.d2h``. The waits
+        made here, and no other, are ``stats["stage_wait_us"]``: where they
+        are most of the ``jax.h2d`` spans' time the device's update paces
+        the stream, else the read does. ``transferred`` is
+        ``_device_put``'s: the byte count and the bit-pattern view."""
         jax = _jax()
         chunk_nbytes = stored._chunk_nbytes()
         write = _chunk_writer()
@@ -670,6 +701,8 @@ class JaxExecutor(DagExecutor):
         grid = itertools.product(*(range(len(c)) for c in chunkset))
         for k, idx in enumerate(grid):
             stage, other = self._staging[k % 2], self._staging[1 - k % 2]
+            # the source before may have left its last update on this one
+            self.stats["stage_wait_us"] += stage.release()
             chunk = stored._read_chunk_into(idx, stage.sized(chunk_nbytes))
             if chunk is None:
                 chunk = stored._empty_chunk()
@@ -686,7 +719,8 @@ class JaxExecutor(DagExecutor):
                     whole, piece, np.asarray(start, np.int32), extent
                 )
                 sp.attrs["bytes"] = piece.nbytes
-                other.release()
+                waited = sp.attrs["wait_us"] = other.release()
+            self.stats["stage_wait_us"] += waited
             self.stats["h2d_stream_bytes"] += piece.nbytes
         return whole
 
@@ -845,6 +879,9 @@ class JaxExecutor(DagExecutor):
             h2d_stream_bytes=0,
             flush_stream_bytes=0,
             encode_copy_bytes=0,
+            write_wait_us=0,
+            stage_wait_us=0,
+            preload_page_faults=0,
             h2d_bits_bytes=0,
             rechunk_host_whole=0,
             rechunk_host_copy=0,
@@ -1090,6 +1127,7 @@ class JaxExecutor(DagExecutor):
             if concrete.shape and getattr(concrete, "chunks", None)
             else None
         )
+        before = _resident_pages()
         with scope_span(
             "jax.preload", bytes=nbytes, chunks=concrete.nchunks
         ) as sp:
@@ -1097,6 +1135,8 @@ class JaxExecutor(DagExecutor):
             value = self._device_put(concrete, tuple(concrete.shape), cs)
             sp.attrs["streamed"] = self.stats["h2d_stream_bytes"] > streamed
             self._admit(resident, key, value, arr, budget)
+            faults = sp.attrs["faults"] = max(0, _resident_pages() - before)
+        self.stats["preload_page_faults"] += faults
         return True
 
     def _segment_keep(self, ops, dag, requested_stores) -> Dict[str, Any]:
@@ -2437,8 +2477,12 @@ class JaxExecutor(DagExecutor):
         thread's. ``stats["flush_stream_bytes"]`` counts the bytes that
         reached the store from a staging buffer with no copy on the host
         after the join, ``stats["encode_copy_bytes"]`` those the store had
-        to copy (``encode_copy_bytes`` of the write's scope). A value that
-        does not leave as planes takes the same pipeline and no buffer."""
+        to copy (``encode_copy_bytes`` of the write's scope). The time this
+        thread is blocked on the writer is the span ``jax.write_wait`` (one
+        a write, a child of ``jax.flush``) and, armed or not,
+        ``stats["write_wait_us"]``: most of the flush where the writer
+        paces it, next to nothing where the fetch does. A value that does
+        not leave as planes takes the same pipeline and no buffer."""
         shape = tuple(concrete.shape)
         if not shape:
             concrete[()] = self._to_host(value, concrete.dtype)
@@ -2495,8 +2539,13 @@ class JaxExecutor(DagExecutor):
             into this thread's scope, its error raised."""
             if not pending:
                 return
-            future, staged_nbytes = pending.pop()
-            inner, error = future.result()
+            future, staged_nbytes, k = pending.pop()
+            with scope_span("jax.write_wait", cat="wait", chunk=k):
+                started = time.perf_counter_ns()
+                inner, error = future.result()
+                self.stats["write_wait_us"] += (
+                    time.perf_counter_ns() - started
+                ) // 1000
             copied = None  # not observed without a scope
             if inner is not None:
                 copied = inner.counters.get("encode_copy_bytes", 0)
@@ -2527,6 +2576,7 @@ class JaxExecutor(DagExecutor):
                     pending.append((
                         pool.submit(contextvars.copy_context().run, write, sel, host),
                         host.nbytes if stage.holds(host) else 0,
+                        k,
                     ))
                     chunks += 1
             finally:

@@ -847,7 +847,8 @@ class ZarrV2Array:
         first, which is the one copy. ``copied``: the caller made ``arr``
         by copying what it was given (``__setitem__``: a padded or merged
         chunk, a converted dtype); either way the chunk's bytes count as
-        ``encode_copy_bytes``, and the ``chunk_encode`` span says so."""
+        ``encode_copy_bytes``, and the ``chunk_encode`` span says so. The
+        CRC-32 and the manifest line are counted as ``checksum_us``."""
         # cooperative cancellation: checked BEFORE the write starts — an
         # abort never interrupts an atomic chunk write mid-flight, so the
         # store/manifest/journal stay consistent for resume
@@ -870,8 +871,14 @@ class ZarrV2Array:
                 # the two leaves a chunk without an entry, which resume
                 # treats as not-computed (safe re-run) — never an entry
                 # without its chunk
+                # counted and no span: ``storage_write``'s children are its
+                # fsyncs, and what remains of it is this and the file write
+                started = time.perf_counter_ns()
                 entry = integrity.record_checksum(
                     self._io, self.store, key, data
+                )
+                record_scoped_counter(
+                    "checksum_us", (time.perf_counter_ns() - started) // 1000
                 )
                 if self._manifest_cache is not None:
                     self._manifest_cache[0][key] = entry

@@ -7,6 +7,8 @@ flush and times its phases with ``scope_span``; the spans ride its
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 import pytest
 
@@ -22,6 +24,8 @@ from cubed_tpu.observability.callback import _ComputeAggregator
 from cubed_tpu.observability.collect import TraceCollector
 from cubed_tpu.runtime.executors import jax as jxm
 from cubed_tpu.runtime.executors.jax import JaxExecutor
+from cubed_tpu.runtime.executors.python import PythonDagExecutor
+from cubed_tpu.storage.store import ZarrV2Array
 
 SIDE, CHUNK = 400, 200
 NBYTES = SIDE * SIDE * 8
@@ -32,8 +36,12 @@ NBYTES = SIDE * SIDE * 8
 ZARR_ADD_SPANS = {
     "jax.preload", "jax.h2d", "storage_read", "jax.struct_key",
     "jax.dispatch", "jax.flush", "jax.device_wait", "jax.d2h",
-    "chunk_encode", "storage_write", "fsync",
+    "jax.write_wait", "chunk_encode", "storage_write", "fsync",
 }
+
+#: counted armed or not, each a whole number, 0 and not absent: what the two
+#: streaming pipelines waited for and how many pages a preload made resident
+PIPELINE_COUNTERS = ("write_wait_us", "stage_wait_us", "preload_page_faults")
 
 
 class _Capture:
@@ -89,6 +97,12 @@ def _spans(collector) -> list:
     return [s for rec in collector._records for s in rec["spans"]]
 
 
+def _attrs(span, *but) -> dict:
+    """A span's attributes but the named ones (a count of pages, a wait:
+    what no test can know beforehand)."""
+    return {k: v for k, v in span["attrs"].items() if k not in but}
+
+
 def test_zarr_add_yields_every_span_each_inside_its_parent(sources, tmp_path):
     tc = TraceCollector(trace_dir=None)
     _store(sources, tmp_path, "c", [tc])
@@ -113,15 +127,19 @@ def test_zarr_add_yields_every_span_each_inside_its_parent(sources, tmp_path):
     assert parents_of["storage_read"] == {"jax.preload"}  # no mesh: read, then put
     assert parents_of["jax.device_wait"] == {"jax.flush"}
     assert parents_of["jax.d2h"] == {"jax.flush"}
+    assert parents_of["jax.write_wait"] == {"jax.flush"}
     assert parents_of["chunk_encode"] == {"jax.flush"}
     assert parents_of["storage_write"] == {"jax.flush"}
     assert "storage_write" in parents_of["fsync"]
     by_name = {s["name"]: s for s in _spans(tc)}
-    assert by_name["jax.preload"]["attrs"] == {
+    assert _attrs(by_name["jax.preload"], "faults") == {
         "bytes": NBYTES, "chunks": 4, "streamed": True,
     }
     assert by_name["jax.h2d"]["attrs"]["bytes"] == CHUNK * CHUNK * 8  # one a chunk
     assert by_name["jax.flush"]["attrs"] == {"bytes": NBYTES, "chunks": 4}
+    assert type(by_name["jax.preload"]["attrs"]["faults"]) is int
+    assert by_name["jax.preload"]["attrs"]["faults"] >= 0
+    assert type(by_name["jax.h2d"]["attrs"]["wait_us"]) is int
     assert by_name["jax.d2h"]["attrs"]["bytes"] == CHUNK * CHUNK * 8
 
 
@@ -145,7 +163,7 @@ def test_a_streamed_preload_is_one_span_a_source_with_a_read_and_a_put_a_chunk(
     if mode == "write":
         per_chunk.remove("integrity_verify")
     for preload in preloads:
-        assert preload["attrs"] == {"bytes": NBYTES, "chunks": 4, "streamed": True}
+        assert _attrs(preload, "faults") == {"bytes": NBYTES, "chunks": 4, "streamed": True}
         inside = sorted(
             (s for s in rec["spans"] if s.get("parent") == preload["id"]),
             key=lambda s: s["id"],
@@ -173,9 +191,11 @@ def test_a_source_of_one_chunk_is_a_preload_that_did_not_stream(tmp_path):
         executor=JaxExecutor(), callbacks=[tc, cap],
     )
     (preload,) = [s for s in _spans(tc) if s["name"] == "jax.preload"]
-    assert preload["attrs"] == {"bytes": CHUNK * CHUNK * 8, "chunks": 1, "streamed": False}
+    assert _attrs(preload, "faults") == {
+        "bytes": CHUNK * CHUNK * 8, "chunks": 1, "streamed": False,
+    }
     (h2d,) = [s for s in _spans(tc) if s["name"] == "jax.h2d"]
-    assert h2d["attrs"]["bytes"] == CHUNK * CHUNK * 8
+    assert h2d["attrs"] == {"bytes": CHUNK * CHUNK * 8}  # put whole: no buffer waited for
     assert cap.stats["h2d_stream_bytes"] == 0 < cap.stats["h2d_bytes"]
 
 
@@ -208,12 +228,24 @@ def test_executor_stats_span_totals_are_the_collectors_spans(sources, tmp_path):
         assert stats["span_s"][name] == pytest.approx(sum(s["dur"] for s in mine))
         assert stats["span_self_s"][name] <= stats["span_s"][name] + 1e-12
     assert stats["spans_dropped"] == 0
-    # self time is duration less the spans directly inside
+    # self time is duration less the spans directly inside on the same
+    # thread: the flush's writer works beside it
     (flush,) = [s for s in spans if s["name"] == "jax.flush"]
     (rec,) = [r for r in tc._records if flush in r["spans"]]
-    inside = sum(s["dur"] for s in rec["spans"] if s.get("parent") == flush["id"])
-    assert stats["span_self_s"]["jax.flush"] == pytest.approx(flush["dur"] - inside)
+    inside = [s for s in rec["spans"] if s.get("parent") == flush["id"]]
+    own = [s for s in inside if "thread" not in s.get("attrs", {})]
+    assert {s["name"] for s in inside} - {s["name"] for s in own} == {
+        "chunk_encode", "storage_write",
+    }
+    assert stats["span_self_s"]["jax.flush"] == pytest.approx(
+        flush["dur"] - sum(s["dur"] for s in own)
+    )
     assert stats["span_self_s"]["fsync"] == pytest.approx(stats["span_s"]["fsync"])
+    # the writer's own nesting is as it was: a write less its fsyncs
+    assert stats["span_self_s"]["storage_write"] == pytest.approx(
+        stats["span_s"]["storage_write"]
+        - sum(s["dur"] for s in spans if s["name"] == "fsync" and "parent" in s)
+    )
 
 
 def test_unarmed_no_span_is_allocated_and_no_sync_is_added(
@@ -415,6 +447,112 @@ def test_a_flush_of_many_chunks_drops_no_span(tmp_path):
     assert 5 * 64 > accounting.MAX_TASK_SPANS and cap.stats["spans_dropped"] == 0
 
 
+# -- who waited for whom, and whether the pages were fresh -----------------------
+
+
+@pytest.mark.parametrize("counter", PIPELINE_COUNTERS)
+def test_a_pipeline_counter_is_a_whole_number_and_zero_where_no_pipeline_ran(
+    tmp_path, counter
+):
+    """A compute that preloads no source and flushes one 0-d chunk: each
+    counter is there, a whole number, the same in the executor's ``stats``
+    and in ``executor_stats``, and reads 0."""
+    spec = ct.Spec(work_dir=str(tmp_path / "work"), allowed_mem="500MB")
+    a = ct.from_array(np.arange(36.0).reshape(6, 6), chunks=(3, 3), spec=spec)
+    executor, cap = JaxExecutor(), _Capture()
+    assert float(xp.sum(a).compute(executor=executor, callbacks=[cap])) == 630.0
+    assert counter in executor.stats and counter in cap.stats
+    assert type(executor.stats[counter]) is int and type(cap.stats[counter]) is int
+    assert executor.stats[counter] == cap.stats[counter]
+    assert executor.stats[counter] == 0
+
+
+def test_a_0d_flush_takes_its_checksum_and_no_writer(tmp_path):
+    """The 0-d result's write takes no writer thread; its CRC-32 is counted
+    all the same, by the store and under the one name: the executor keeps
+    no count of its own beside the compute's."""
+    spec = ct.Spec(work_dir=str(tmp_path / "work"), allowed_mem="500MB")
+    a = ct.from_array(np.arange(36.0).reshape(6, 6), chunks=(3, 3), spec=spec)
+    executor, cap = JaxExecutor(), _Capture()
+    assert float(xp.sum(a).compute(executor=executor, callbacks=[cap])) == 630.0
+    assert cap.stats["checksum_us"] > 0 and "checksum_us" not in executor.stats
+    assert executor.stats["write_wait_us"] == 0
+
+
+@pytest.mark.parametrize("armed", [True, False], ids=["armed", "disarmed"])
+def test_a_compute_whose_writes_are_slow_waits_for_its_writer(
+    sources, tmp_path, monkeypatch, armed
+):
+    real = ZarrV2Array._write_chunk
+
+    def write_chunk(self, *args, **kwargs):
+        time.sleep(0.05)
+        return real(self, *args, **kwargs)
+
+    monkeypatch.setattr(ZarrV2Array, "_write_chunk", write_chunk)
+    tc = TraceCollector(trace_dir=None)
+    stats = _store(sources, tmp_path, "c", [tc] if armed else []).stats
+    assert type(stats["write_wait_us"]) is int
+    assert stats["write_wait_us"] >= (4 - 1) * 30_000
+    if not armed:
+        assert "span_n" not in stats
+        return
+    assert stats["span_n"]["jax.write_wait"] == 4 and stats["spans_dropped"] == 0
+    spans = _spans(tc)
+    (flush,) = [s for s in spans if s["name"] == "jax.flush"]
+    waits = [s for s in spans if s["name"] == "jax.write_wait"]
+    assert [s["attrs"]["chunk"] for s in waits] == [0, 1, 2, 3]
+    assert all(s["parent"] == flush["id"] and s["cat"] == "wait" for s in waits)
+    assert stats["span_s"]["jax.write_wait"] == pytest.approx(
+        stats["write_wait_us"] / 1e6, abs=0.02
+    )
+    # the flush's own time is what its fetches and its waits leave of it
+    assert stats["span_self_s"]["jax.flush"] < 0.05 < stats["span_s"]["jax.flush"]
+
+
+@pytest.mark.parametrize("mode", ["write", "off"])
+@pytest.mark.parametrize("make_executor", [JaxExecutor, PythonDagExecutor],
+                         ids=["jax", "python"])
+def test_checksum_us_times_the_crc_and_the_manifest_line_on_every_executor(
+    sources, tmp_path, mode, make_executor
+):
+    """The store's own count, scoped: it reaches ``executor_stats`` from a
+    task of any executor, the device executor's writer thread among them."""
+    spec, paths = sources
+    spec = ct.Spec(work_dir=spec.work_dir, allowed_mem="500MB", integrity=mode)
+    executor = make_executor()
+    stats = _store((spec, paths), tmp_path, "c", [], executor=executor).stats
+    if mode == "off":
+        assert stats.get("checksum_us", 0) == 0
+    else:
+        assert type(stats["checksum_us"]) is int and stats["checksum_us"] > 0
+
+
+def test_h2d_and_storage_write_have_the_children_they_had(sources, tmp_path):
+    """``h2d_s`` is ``jax.h2d``'s self time and ``fsync_s`` what
+    ``storage_write``'s children cover: the wait for a staging buffer and the
+    CRC-32 are counters, so that neither span gained a child."""
+    tc = TraceCollector(trace_dir=None)
+    stats = _store(sources, tmp_path, "c", [tc]).stats
+    children = {}
+    for rec in tc._records:
+        by_id = {s["id"]: s for s in rec["spans"]}
+        for s in rec["spans"]:
+            if "parent" in s:
+                children.setdefault(by_id[s["parent"]]["name"], set()).add(s["name"])
+    assert "jax.h2d" not in children
+    assert children["storage_write"] == {"fsync"}
+    assert children["jax.preload"] == {"storage_read", "jax.h2d"}
+    assert children["jax.flush"] == {
+        "jax.device_wait", "jax.d2h", "jax.write_wait", "chunk_encode", "storage_write",
+    }
+    assert stats["span_self_s"]["jax.h2d"] == pytest.approx(stats["span_s"]["jax.h2d"])
+    assert stats["checksum_us"] / 1e6 <= stats["span_self_s"]["storage_write"]
+    # every wait of this preload lies in a chunk's ``jax.h2d``: none is the flush's
+    waits = [s["attrs"]["wait_us"] for s in _spans(tc) if s["name"] == "jax.h2d"]
+    assert stats["stage_wait_us"] == sum(waits) <= stats["span_s"]["jax.h2d"] * 1e6
+
+
 # -- the generic pieces, on hand-made spans ---------------------------------
 
 
@@ -440,6 +578,33 @@ def test_aggregator_folds_self_time_per_task():
     assert out["span_n"] == {"inner": 2, "outer": 2}
     assert out["spans_dropped"] == 6
     assert "span_s" not in _ComputeAggregator().summary()
+
+
+def test_self_time_leaves_out_what_another_thread_did_under_a_span():
+    """A flush on two threads: the writer's spans hang under ``jax.flush``
+    and overlap its own children. Its self time is its duration less what its
+    own thread did inside it, however long the writer worked; the writer's
+    spans nest among themselves as ever; the sums by name are the sums."""
+    from cubed_tpu.runtime.types import TaskEndEvent
+
+    def on(thread, span):
+        return {**span, "attrs": {"thread": thread}}
+
+    agg = _ComputeAggregator()
+    agg.on_task_end(TaskEndEvent(array_name="op", num_tasks=0, spans=[
+        _span("jax.flush", 0.0, 1.0, 0),
+        _span("jax.d2h", 0.0, 0.25, 1, parent=0),
+        _span("jax.write_wait", 0.25, 0.5, 2, parent=0),
+        on("cubed-tpu-flush_0", _span("storage_write", 0.25, 0.5, 3, parent=0)),
+        on("cubed-tpu-flush_0", _span("fsync", 0.5, 0.125, 4, parent=3)),
+        on("cubed-tpu-flush_0", _span("storage_write", 0.75, 0.5, 5, parent=0)),
+    ]))
+    out = agg.summary()
+    assert out["span_s"]["jax.flush"] == 1.0 and out["span_s"]["storage_write"] == 1.0
+    assert out["span_self_s"]["jax.flush"] == 0.25  # not max(0, 1.0 - 1.75)
+    assert out["span_self_s"]["storage_write"] == 0.875
+    assert out["span_self_s"]["fsync"] == 0.125
+    assert out["span_self_s"]["jax.write_wait"] == 0.5
 
 
 def test_a_span_across_task_boundaries_is_cut_between_them():

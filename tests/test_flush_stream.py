@@ -30,7 +30,7 @@ import pytest
 import cubed_tpu as ct
 import cubed_tpu.array_api as xp
 import cubed_tpu.runtime.executors.jax as jx
-from cubed_tpu.observability.accounting import TaskScope, task_scope
+from cubed_tpu.observability.accounting import SPANS_ENV_VAR, TaskScope, task_scope
 from cubed_tpu.observability.collect import TraceCollector
 from cubed_tpu.runtime import faults
 from cubed_tpu.runtime.cancellation import CancellationToken, ComputeCancelledError
@@ -348,6 +348,54 @@ def test_two_buffers_take_turns_and_none_is_rewritten_before_its_write_returned(
     assert _no_writer_left()
 
 
+@pytest.mark.parametrize("armed", [True, False], ids=["armed", "disarmed"])
+def test_a_slow_writer_is_what_the_flush_waits_for_and_it_says_so(
+    tmp_path, pair_device, monkeypatch, armed
+):
+    """Every chunk write slowed by 50 ms: the fetch of chunk k + 1 hides
+    behind the write of chunk k and the executor's thread then waits for the
+    writer. ``write_wait_us`` says for how long, armed or not; armed, every
+    write waited for is one ``jax.write_wait`` span inside ``jax.flush``."""
+    real = ZarrV2Array._write_chunk
+
+    def write_chunk(self, *args, **kwargs):
+        time.sleep(0.05)
+        return real(self, *args, **kwargs)
+
+    monkeypatch.setattr(ZarrV2Array, "_write_chunk", write_chunk)
+    if armed:
+        monkeypatch.setenv(SPANS_ENV_VAR, "1")
+    else:
+        monkeypatch.delenv(SPANS_ENV_VAR, raising=False)
+    host, chunks = _pairs((24, 8)), 6
+    z, ex, scope = _flush(tmp_path, host, (4, 8))
+    assert _read_back(z).tobytes() == host.tobytes()
+    assert type(ex.stats["write_wait_us"]) is int
+    assert ex.stats["write_wait_us"] >= (chunks - 1) * 30_000
+    waits = [s for s in scope.spans if s["name"] == "jax.write_wait"]
+    if not armed:
+        assert scope.spans == [] and scope.spans_dropped == 0
+        return
+    (flush,) = [s for s in scope.spans if s["name"] == "jax.flush"]
+    assert [s["attrs"] for s in waits] == [{"chunk": k} for k in range(chunks)]
+    assert all(s["cat"] == "wait" and s["parent"] == flush["id"] for s in waits)
+    assert scope.spans_dropped == 0
+    # the span and the counter time the same wait, on two clocks
+    assert sum(s["dur"] for s in waits) == pytest.approx(
+        ex.stats["write_wait_us"] / 1e6, abs=0.02
+    )
+    # on this thread's line every stretch of the flush has a name: what the
+    # fetches and the waits leave of it is the slicing between them
+    own = [s for s in scope.spans if s.get("parent") == flush["id"]
+           and "thread" not in s.get("attrs", {})]
+    assert {s["name"] for s in own} == {"jax.device_wait", "jax.d2h", "jax.write_wait"}
+    assert flush["dur"] - sum(s["dur"] for s in own) < 0.05
+    # and the writer's spans say whose they are
+    theirs = [s for s in scope.spans if "thread" in s.get("attrs", {})]
+    assert {s["name"] for s in theirs} == {"chunk_encode", "storage_write", "fsync"}
+    assert all(s["attrs"]["thread"].startswith(WRITER) for s in theirs)
+
+
 def test_the_buffers_are_the_preloads_and_a_larger_chunk_grows_them(tmp_path, pair_device):
     src = open_zarr_array(str(tmp_path / "src.zarr"), "w", shape=(8, 8), dtype="f8", chunks=(4, 4))
     src[...] = _pairs((8, 8))
@@ -556,8 +604,13 @@ def test_the_flushs_event_holds_both_threads_spans_bytes_and_chunks(tmp_path, sp
     assert len(by_id) == len(event.spans), "span ids repeat after the fold"
     names = [s["name"] for s in event.spans]
     for name, n in (("jax.flush", 1), ("jax.device_wait", 8), ("jax.d2h", 8),
+                    ("jax.write_wait", 8),
                     ("chunk_encode", 8), ("storage_write", 8), ("fsync", 16)):
         assert names.count(name) == n, name
+    # what the writer thread recorded says so; this thread's spans do not
+    for s in event.spans:
+        on_writer = s["name"] in ("chunk_encode", "storage_write", "fsync")
+        assert s.get("attrs", {}).get("thread", "").startswith(WRITER) == on_writer, s
     (flush,) = [s for s in event.spans if s["name"] == "jax.flush"]
     for s in event.spans:
         if s["name"] == "fsync":
@@ -582,6 +635,13 @@ def test_the_flushs_event_holds_both_threads_spans_bytes_and_chunks(tmp_path, sp
     ) > 0
     assert cap.stats["flush_stream_bytes"] == cap.stats["d2h_bytes"] == want.nbytes
     assert cap.stats["encode_copy_bytes"] == 0
+    # the flush's self time is this thread's: the writer's spans ran beside
+    # it, and only the fetches and the waits are taken from it
+    own = sum(s["dur"] for s in event.spans if s["name"] in
+              ("jax.device_wait", "jax.d2h", "jax.write_wait"))
+    assert cap.stats["span_self_s"]["jax.flush"] == pytest.approx(flush["dur"] - own)
+    assert 0 < cap.stats["span_self_s"]["jax.flush"] < flush["dur"]
+    assert 0 < cap.stats["checksum_us"] <= cap.stats["span_self_s"]["storage_write"] * 1e6
     assert _no_writer_left()
 
 
@@ -631,8 +691,14 @@ def test_a_spill_flush_takes_the_pipeline_and_no_buffer(tmp_path, spec, pair_dev
 # -- a scope folded into another -------------------------------------------------------
 
 
-def test_fold_renumbers_spans_and_keeps_parents():
+@pytest.mark.parametrize("thread", [None, WRITER + "_0"], ids=["same_thread", "helper_thread"])
+def test_fold_renumbers_spans_and_keeps_parents(thread):
     outer, inner = TaskScope(max_spans=8), TaskScope()
+    assert outer.thread == inner.thread == threading.current_thread().name
+    # a scope is its thread's: what another thread's scope hands over says so
+    mark = {}
+    if thread is not None:
+        inner.thread, mark = thread, {"thread": thread}
     outer.add_span("a", 0.0, 5.0, span_id=0)
     outer._next_id, outer._open = 3, [2]  # span 2 is open: the flush
     inner.add_span("w", 1.0, 2.0, span_id=1, key="0.0")
@@ -645,9 +711,11 @@ def test_fold_renumbers_spans_and_keeps_parents():
     assert outer.bytes_written == 128 and outer.chunks_written == 1
     assert outer.counters == {"encode_copy_bytes": 65}
     w, f = outer.spans[1:]
-    assert (w["id"], w["parent"], w["attrs"]) == (4, 2, {"key": "0.0"})
+    assert (w["id"], w["parent"], w["attrs"]) == (4, 2, {"key": "0.0", **mark})
     assert (f["id"], f["parent"], f["dur"]) == (5, 4, 0.25)
+    assert f.get("attrs", {}) == mark
     assert outer._next_id == 6 and inner.spans[0]["id"] == 1  # the source is left alone
+    assert inner.spans[0]["attrs"] == {"key": "0.0"} and "attrs" not in inner.spans[1]
     # a second scope lands above the first; what finds no room is counted
     outer.max_spans = 4
     outer.fold(inner)

@@ -13,8 +13,10 @@ breaker pacing, byte accounting, verification with quarantine."""
 
 from __future__ import annotations
 
+import json
 import mmap
 import os
+import time
 
 import numpy as np
 import pytest
@@ -22,7 +24,7 @@ import pytest
 import cubed_tpu as ct
 import cubed_tpu.array_api as xp
 import cubed_tpu.runtime.executors.jax as jx
-from cubed_tpu.observability.accounting import task_scope
+from cubed_tpu.observability.accounting import SPANS_ENV_VAR, task_scope
 from cubed_tpu.observability.metrics import get_registry
 from cubed_tpu.runtime import faults
 from cubed_tpu.runtime.cancellation import CancellationToken, ComputeCancelledError
@@ -572,8 +574,9 @@ def test_a_buffer_is_not_rewritten_before_the_update_that_read_it_is_ready(
 
     def release(self):
         waits.append(self.busy is not None)
-        real_release(self)
+        waited = real_release(self)
         assert self.busy is None
+        return waited
 
     monkeypatch.setattr(jx._Staging, "release", release)
     executor = JaxExecutor()
@@ -581,8 +584,134 @@ def test_a_buffer_is_not_rewritten_before_the_update_that_read_it_is_ready(
         got, _ = _put(z, executor)
         assert got.tobytes() == host.tobytes()
     assert len({id(s.buffer) for s in executor._staging}) == 2
-    # a release ahead of every read and one at the end of every chunk's span
-    assert len(waits) == 3 * 2 * z.nchunks
+    # ahead of every read the stream's own release, which is counted, and
+    # ``sized``'s, which finds nothing left; one at the end of every chunk's span
+    assert len(waits) == 3 * 3 * z.nchunks
     # and a real wait for every update: none is left unwaited but the last
     assert sum(waits) == 3 * z.nchunks - 1
     assert sum(s.busy is not None for s in executor._staging) == 1
+    # each timed, armed or not, and the time is a whole number of microseconds
+    assert type(executor.stats["stage_wait_us"]) is int
+    assert executor.stats["stage_wait_us"] >= 0
+
+
+# -- who waited for whom, and whether the pages were fresh -------------------------
+
+
+class _Held:
+    """A device update that keeps its staging buffer for ``seconds``."""
+
+    def __init__(self, seconds):
+        self.seconds = seconds
+
+    def block_until_ready(self):
+        time.sleep(self.seconds)
+
+
+@pytest.mark.parametrize(
+    "where", ["release", "ahead_of_a_read", "inside_h2d", "a_flushs_join"]
+)
+def test_stage_wait_us_rises_when_the_device_update_is_held(tmp_path, monkeypatch, where):
+    """``release`` says how long a device update kept its staging buffer,
+    and the stream counts the waits it makes: ahead of a chunk's read, and
+    at the end of a chunk's ``jax.h2d`` span, which then says so
+    (``wait_us``) and has no child for it. The wait of a flush's join for
+    the preload's last update is the flush's (``jax.d2h``) and not counted
+    here, so that the count stays a part of the preload's time."""
+    held_us = 20_000
+    if where == "release":
+        stage = jx._Staging()
+        assert stage.release() == 0
+        stage.busy = _Held(held_us / 1e6)
+        waited = stage.release()
+        assert type(waited) is int and waited >= held_us and stage.busy is None
+        assert stage.release() == 0
+        return
+    host = _values(np.float64, (8, 8))
+    z = _stored(tmp_path, host, (4, 8))  # two chunks
+    executor = JaxExecutor()
+    _put(z, executor)  # compiled, the buffers made
+    quick = executor.stats["stage_wait_us"]
+    assert quick < held_us
+    if where == "a_flushs_join":
+        stage = executor._staging[1]
+        stage.busy = _Held(held_us / 1e6)
+        started = time.perf_counter()
+        out = stage.array((4, 8), np.dtype(np.float64))
+        assert time.perf_counter() - started >= held_us / 1e6
+        assert stage.holds(out) and stage.busy is None
+        assert executor.stats["stage_wait_us"] == quick
+        return
+    # chunk 0 is read into the first buffer and waits, inside its span, for
+    # the second
+    executor._staging[0 if where == "ahead_of_a_read" else 1].busy = _Held(held_us / 1e6)
+    monkeypatch.setenv(SPANS_ENV_VAR, "1")
+    with task_scope(jx._SCOPE_SPANS) as scope:
+        got, _ = _put(z, executor)
+    assert got.tobytes() == host.tobytes()
+    assert executor.stats["stage_wait_us"] >= quick + held_us
+    puts = [s for s in scope.spans if s["name"] == "jax.h2d"]
+    assert len(puts) == 2 and all(type(s["attrs"]["wait_us"]) is int for s in puts)
+    assert (puts[0]["attrs"]["wait_us"] >= held_us) == (where == "inside_h2d")
+    assert (puts[0]["dur"] >= held_us / 1e6) == (where == "inside_h2d")
+    # the wait has no span of its own: ``h2d_s`` is the put's self time
+    assert not [s for s in scope.spans if s.get("parent") in {p["id"] for p in puts}]
+    assert {s["name"] for s in scope.spans} == {"jax.h2d", "storage_read"}
+
+
+def test_resident_pages_sees_fresh_pages_touched():
+    """What ``preload_page_faults`` is read from: the resident set grows by
+    the pages a first touch brings in, where the kernel's own count of
+    faults may stand still (gVisor's ``ru_minflt``)."""
+    before = jx._resident_pages()
+    if not before:
+        pytest.skip("no /proc/self/statm on this system")
+    assert type(before) is int
+    pages = 4096
+    with mmap.mmap(-1, pages * mmap.PAGESIZE) as fresh:
+        for page in range(pages):
+            fresh[page * mmap.PAGESIZE] = 1
+        assert jx._resident_pages() - before >= pages // 2
+
+
+def test_a_fresh_executors_preload_faults_in_its_staging_buffers_and_the_next_does_not(tmp_path):
+    """``preload_page_faults`` is the growth of the resident set over
+    ``_preload``: a fresh executor's first stream writes into two staging
+    buffers nobody has touched, its second into the same two. Chunks of
+    33 MiB, above the size at which glibc ever recycles freed memory, so
+    that fresh buffers are fresh pages whatever the process did before; and
+    an array one column wide under them (the store pads a chunk to its full
+    size), so that the resident array, made anew by every preload, is a few
+    pages and does not count beside them."""
+    if not jx._resident_pages():
+        pytest.skip("no /proc/self/statm on this system")
+    rows, columns = 2112, 2048  # 33 MiB a stored chunk
+    host = _values(np.float64, (rows + 1, 1))
+    # chunks wider than the array, as Zarr allows and another writer may
+    # leave them: the store's own ``create`` cuts them to the shape
+    path = _stored(tmp_path, np.zeros((1, columns)), (rows, columns)).store
+    os.remove(os.path.join(path, "0.0"))
+    with open(os.path.join(path, ".zarray")) as f:
+        meta = json.load(f)
+    meta.update(shape=list(host.shape), chunks=[rows, columns])
+    with open(os.path.join(path, ".zarray"), "w") as f:
+        json.dump(meta, f)
+    z = open_zarr_array(path, "a")
+    z[...] = host
+    assert z.nchunks == 2 and z._chunk_nbytes() == rows * columns * 8 > 32 * 2**20
+    executor = JaxExecutor()
+    executor.stats["preload_page_faults"] = 0
+    counts = []
+    for name in ("first", "second"):
+        resident = {}
+        executor._resident = resident
+        assert executor._preload(z, resident, executor._budget())
+        (res,) = resident.values()
+        assert np.asarray(res.value).tobytes() == host.tobytes()
+        counts.append(executor.stats["preload_page_faults"] - sum(counts))
+    first, second = counts
+    assert type(executor.stats["preload_page_faults"]) is int
+    assert executor.stats["h2d_stream_bytes"] == 2 * 2 * z._chunk_nbytes()
+    assert first > 0 and first >= 2 * second, counts
+    # two buffers of 33 MiB, in pages of the system's size
+    assert first - second >= 2 * 32 * 2**20 // mmap.PAGESIZE, counts
