@@ -484,8 +484,11 @@ def preload_stream_reading(n: int, *, seed: int) -> dict:
             executor._carry_bits = carry_bits
             resident: dict = {}
             t0 = time.perf_counter()
-            if not executor._preload(stored, resident, executor._budget()):
-                raise AssertionError("the stored array was not preloaded")
+            # the staging pair as a compute holds it; the second form finds
+            # the buffers the first one made
+            with executor._lease() as staging:
+                if not executor._preload(stored, resident, executor._budget()):
+                    raise AssertionError("the stored array was not preloaded")
             (res,) = resident.values()
             jax.block_until_ready(res.value)
             t1 = time.perf_counter()
@@ -513,8 +516,9 @@ def preload_stream_reading(n: int, *, seed: int) -> dict:
                 f"{form} {host.nbytes / 1e9:.2f} GB in {stored.nchunks} chunks of "
                 f"{n * n * 8 / 1e6:.0f} MB, edge values in each: streamed preload "
                 f"{t1 - t0:.3f} s (h2d_stream_bytes={stats['h2d_stream_bytes']} of "
-                f"h2d_bytes={stats['h2d_bytes']}, staging buffers "
-                f"{[b.buffer.nbytes for b in executor._staging]}), whole-array put of "
+                f"h2d_bytes={stats['h2d_bytes']}, stage_reused_bytes="
+                f"{stats['stage_reused_bytes']}, staging buffers "
+                f"{[b.buffer.nbytes for b in staging]}), whole-array put of "
                 f"the host array {t2 - t1:.3f} s; 0 of {host.size} values differ"
             )
             del res, resident, whole, streamed, put

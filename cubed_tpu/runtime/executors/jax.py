@@ -8,12 +8,23 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   storage (the reference pays a full storage round-trip per op).
 - **Streamed preload.** Without a mesh a stored source of several chunks is
   never assembled on the host: each chunk file is read into one of two
-  staging buffers that the executor keeps, put on the device from there and
-  written into its place in one resident array that is updated in place
-  (``_stream_to_device``, ``_chunk_writer``), bit for bit what the put of
-  the whole array gives. The route is chosen from what ``_device_put``
+  staging buffers, put on the device from there and written into its place
+  in one resident array that is updated in place (``_stream_to_device``,
+  ``_chunk_writer``), bit for bit what the put of the whole array gives. The route is chosen from what ``_device_put``
   observes (``_streams``); ``stats["h2d_stream_bytes"]`` counts what went
   that way and ``stats["h2d_stream_declined"]`` what found no room.
+- **The staging pair is the process's.** The two buffers outlive the
+  executor: ``execute_dag`` leases the one pair the process keeps
+  (``_leased_staging``) and gives it back when the compute ends, however
+  it ends, with no device update left on either buffer. A compute that
+  finds the pair leased (the service runs computes on many threads) works
+  with a fresh pair of its own and waits for nobody. So from a process's
+  second compute on no chunk is read into, or joined into, a page nobody
+  has touched: ``stats["stage_reused_bytes"]`` counts the streamed bytes
+  that passed through a buffer the compute found allocated. Between
+  computes the process holds two buffers of the largest chunk streamed so
+  far; ``release_staging_buffers()`` hands the pages back, and a forked
+  child starts with none.
 - **Streamed flush.** The way out is the mirror image
   (``_flush_chunks``): a chunk that leaves as planes is joined into one of
   the same two buffers and written from there (the store hands the file a
@@ -100,7 +111,8 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   spans"). What the two pipelines wait for, and how many pages a preload
   makes resident, is counted armed or not, on ``perf_counter_ns`` and from
   ``/proc/self/statm``: ``stats["write_wait_us"]``, ``stage_wait_us``,
-  ``preload_page_faults``.
+  ``preload_page_faults`` (the staging buffers of a process's first
+  compute, 0 from its second on: ``stage_reused_bytes`` says why).
 
 Reference parity: replaces cubed's serverless executors
 (cubed/runtime/executors/*) with a device-mesh substrate.
@@ -115,6 +127,7 @@ import itertools
 import logging
 import math
 import mmap
+import os
 import re
 import sys
 import threading
@@ -210,13 +223,21 @@ class _Staging:
     update that consumed the bytes now in ``buffer``: until it is ready the
     buffer may still be read by the transfer (the put is asynchronous on
     the TPU, and on the CPU backend a put value may alias the numpy
-    memory), so it is not written."""
+    memory), so it is not written.
 
-    __slots__ = ("buffer", "busy")
+    The buffers come in pairs, and a pair belongs to the process, not to
+    an executor: a compute leases it for the length of one ``execute_dag``
+    (``_leased_staging``), so that the pages a chunk is read into have
+    been touched by the compute before. ``kept`` says whether ``buffer``
+    is still the one the lease found: False once this compute had to make
+    it, or make it larger."""
+
+    __slots__ = ("buffer", "busy", "kept")
 
     def __init__(self):
         self.buffer: Optional[np.ndarray] = None
         self.busy = None
+        self.kept = False
 
     def release(self) -> int:
         """Wait until the buffer may be written again: the microseconds the
@@ -230,8 +251,9 @@ class _Staging:
 
     def sized(self, nbytes: int) -> np.ndarray:
         """The buffer, free to be written, with room for ``nbytes``: made on
-        first use and again only for a larger chunk, so that after its
-        first chunk no read writes a fresh page."""
+        first use and again only for a larger chunk (which replaces the
+        smaller one), so that after its first chunk no read writes a fresh
+        page."""
         self.release()
         if self.buffer is None or self.buffer.nbytes < nbytes:
             # on a page boundary, as the page cache's pages are that the
@@ -243,6 +265,7 @@ class _Staging:
             raw = np.empty(nbytes + mmap.PAGESIZE, np.uint8)
             shift = -raw.ctypes.data % mmap.PAGESIZE
             self.buffer = raw[shift : shift + nbytes]
+            self.kept = False
         return self.buffer
 
     def array(self, shape, dtype: np.dtype) -> np.ndarray:
@@ -324,6 +347,16 @@ class JaxExecutor(DagExecutor):
         installs a process-global warnings filter ignoring jax's
         "requested dtype float64 is not available" message (see
         _install_f32_truncation_filter for why and what it costs).
+
+    Notes
+    -----
+    An executor keeps no host buffer between computes. The two chunk-sized
+    staging buffers of a streamed preload and flush are the process's: a
+    compute leases them for the length of ``execute_dag`` and the process
+    keeps them afterwards (two buffers of the largest chunk streamed so
+    far), so that the next compute, of this executor or another, reads into
+    pages already touched. ``release_staging_buffers()`` of this module
+    hands those pages back; nothing calls it automatically.
     """
 
     def __init__(
@@ -374,11 +407,16 @@ class JaxExecutor(DagExecutor):
         self._resident: Dict[str, _Resident] = {}
         self._spilling = False
         #: the host memory of a streamed preload and of a flush: two
-        #: chunk-sized buffers that take turns, kept for every source this
-        #: executor loads and every array it stores, and released with it.
-        #: One pair serves both ways: the executor's units run one after
-        #: another, so no flush runs while a stream holds a buffer
-        self._staging = (_Staging(), _Staging())
+        #: chunk-sized buffers that take turns, for every source a compute
+        #: loads and every array it stores. The pair is the process's and
+        #: is here only while a compute runs: ``execute_dag`` leases it at
+        #: entry and gives it back at the end, on success, error and
+        #: cancellation alike (``_lease``); a compute that finds it leased
+        #: by another thread's gets a fresh pair of its own, dropped at the
+        #: end. ``release_staging_buffers()`` hands the pages of the idle
+        #: pair back. One pair serves both ways: the executor's units run
+        #: one after another, so no flush runs while a stream holds a buffer
+        self._staging: Optional[Tuple[_Staging, _Staging]] = None
         self._prepared_bases: Dict[int, Any] = {}
         #: keys of the task events of this compute in the order they were
         #: fired, kept only while spans are recorded (see ``_task_end``)
@@ -412,8 +450,12 @@ class JaxExecutor(DagExecutor):
         #: was blocked on the flush's writer thread, and on a device update
         #: that still read a staging buffer, in ``_stream_to_device``),
         #: ``preload_page_faults`` (the pages the preloads made resident:
-        #: the growth of the process's resident set over each; the three
-        #: each 0, not absent),
+        #: the growth of the process's resident set over each: the staging
+        #: buffers in a process's first compute, 0 from its second on; the
+        #: three each 0, not absent), ``stage_reused_bytes`` (the part of
+        #: ``h2d_stream_bytes + flush_stream_bytes`` that passed through a
+        #: staging buffer the compute found allocated when it leased the
+        #: pair, not one it had to make or to make larger; 0, not absent),
         #: ``h2d_stream_declined`` (stored arrays that
         #: qualified for the stream and were put whole for want of room in
         #: HBM), ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
@@ -678,10 +720,10 @@ class JaxExecutor(DagExecutor):
 
     def _stream_to_device(self, stored, transferred):
         """``stored`` on the device without a copy of it on the host: each
-        chunk file is read into one of the executor's two staging buffers,
-        put on the device from there and written into its place in one
-        resident array of the full shape, which is allocated once and
-        updated in place (``_chunk_writer``).
+        chunk file is read into one of the two staging buffers this compute
+        has leased (``_lease``), put on the device from there and written
+        into its place in one resident array of the full shape, which is
+        allocated once and updated in place (``_chunk_writer``).
 
         The buffers take turns, so the read of chunk k + 1 overlaps the
         transfer and update of chunk k; chunk k's span ends with the wait
@@ -722,6 +764,7 @@ class JaxExecutor(DagExecutor):
                 waited = sp.attrs["wait_us"] = other.release()
             self.stats["stage_wait_us"] += waited
             self.stats["h2d_stream_bytes"] += piece.nbytes
+            self.stats["stage_reused_bytes"] += piece.nbytes if stage.kept else 0
         return whole
 
     def _to_host(self, value, dtype, stage: Optional[_Staging] = None) -> np.ndarray:
@@ -858,9 +901,22 @@ class JaxExecutor(DagExecutor):
                 from ...random import _mode_scope
 
                 stack.enter_context(_mode_scope("threefry"))
+            stack.enter_context(self._lease())
             return self._execute_dag_inner(
                 dag, callbacks, array_names, resume, spec, **kwargs
             )
+
+    @contextlib.contextmanager
+    def _lease(self) -> Iterator[Tuple[_Staging, _Staging]]:
+        """The staging pair in this executor's hands for the length of the
+        block: one ``execute_dag``, or what drives ``_device_put`` or
+        ``_flush`` without one (``chip_smoke.py``, the tests). Outside it
+        the executor holds no host buffer."""
+        with _leased_staging() as self._staging:
+            try:
+                yield self._staging
+            finally:
+                self._staging = None
 
     def _execute_dag_inner(
         self,
@@ -878,6 +934,7 @@ class JaxExecutor(DagExecutor):
             mesh_devices=0 if self.mesh is None else self.mesh.devices.size,
             h2d_stream_bytes=0,
             flush_stream_bytes=0,
+            stage_reused_bytes=0,
             encode_copy_bytes=0,
             write_wait_us=0,
             stage_wait_us=0,
@@ -2539,7 +2596,7 @@ class JaxExecutor(DagExecutor):
             into this thread's scope, its error raised."""
             if not pending:
                 return
-            future, staged_nbytes, k = pending.pop()
+            future, staged_nbytes, reused, k = pending.pop()
             with scope_span("jax.write_wait", cat="wait", chunk=k):
                 started = time.perf_counter_ns()
                 inner, error = future.result()
@@ -2555,6 +2612,7 @@ class JaxExecutor(DagExecutor):
                 raise error
             if copied == 0:
                 self.stats["flush_stream_bytes"] += staged_nbytes
+                self.stats["stage_reused_bytes"] += staged_nbytes if reused else 0
 
         chunks = 0
         pending: list = []  # at most the one write in flight
@@ -2576,6 +2634,7 @@ class JaxExecutor(DagExecutor):
                     pending.append((
                         pool.submit(contextvars.copy_context().run, write, sel, host),
                         host.nbytes if stage.holds(host) else 0,
+                        stage.kept,
                         k,
                     ))
                     chunks += 1
@@ -2693,6 +2752,56 @@ _STRUCT_DEBUG: Optional[list] = None
 #: otherwise interleave the size-check/evict/insert sequences and could
 #: evict an entry a sibling just read or resurrect one past the bound
 _CACHE_LOCK = threading.Lock()
+
+#: the process's pair of staging buffers while no compute has it: empty, or
+#: the one pair. It outlives executors as the program caches do, so that a
+#: compute reads its chunks into pages the compute before has touched (a
+#: fresh 200 MB buffer costs a read 0.15 s more on the v5e's host, PERF.md
+#: section 6, PRs 36 and 37). What it keeps from the process between
+#: computes is two buffers of the largest chunk streamed so far
+_STAGING_POOL: list = []
+
+
+@contextlib.contextmanager
+def _leased_staging() -> Iterator[Tuple[_Staging, _Staging]]:
+    """The process's staging pair for the length of the block, or a fresh
+    pair where another thread's compute has it: concurrent computes never
+    share a buffer and never wait for one another. At the end, however the
+    block ends, both buffers' device updates are waited out and let go of,
+    so that no device value outlives its compute here and the next
+    lessee's first ``release`` costs nothing; then the pair goes back if
+    the pool is empty, and is dropped otherwise (the pool never holds more
+    than one). A pair whose update raises is not given back."""
+    with _CACHE_LOCK:
+        pair = _STAGING_POOL.pop() if _STAGING_POOL else None
+    if pair is None:
+        pair = (_Staging(), _Staging())
+    for stage in pair:
+        stage.kept = stage.buffer is not None
+    try:
+        yield pair
+    finally:
+        for stage in pair:
+            stage.release()
+        with _CACHE_LOCK:
+            if not _STAGING_POOL:
+                _STAGING_POOL.append(pair)
+
+
+def release_staging_buffers() -> None:
+    """Hand the pages of the idle staging pair back to the system (two
+    buffers of the largest chunk this process has streamed; 400 MB after a
+    compute over 200 MB chunks). Nothing calls it: a process that computes
+    again wants them kept. A pair that a running compute has leased is not
+    touched, and comes back to the pool when that compute ends."""
+    with _CACHE_LOCK:
+        _STAGING_POOL.clear()
+
+
+# a forked child starts with no pair: the pages would be copied on its first
+# write, and ``multiprocess.py`` gives the accelerator to one process at a
+# time. No lock: the thread that held it may not exist in the child
+os.register_at_fork(after_in_child=_STAGING_POOL.clear)
 
 
 #: the dtypes that ``_to_host`` can fetch as two 32-bit planes. complex128

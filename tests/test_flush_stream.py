@@ -1,5 +1,6 @@
-"""A resident array goes to its store chunk by chunk through the executor's
-two reused staging buffers, the mirror image of ``test_preload_stream.py``.
+"""A resident array goes to its store chunk by chunk through the two reused
+staging buffers its compute has leased from the process, the mirror image of
+``test_preload_stream.py``.
 
 ``JaxExecutor._flush_chunks`` is a pipeline of depth two over the target's
 chunk grid: the calling thread slices a chunk on the device, fetches it and,
@@ -32,11 +33,12 @@ import cubed_tpu.array_api as xp
 import cubed_tpu.runtime.executors.jax as jx
 from cubed_tpu.observability.accounting import SPANS_ENV_VAR, TaskScope, task_scope
 from cubed_tpu.observability.collect import TraceCollector
-from cubed_tpu.runtime import faults
+from cubed_tpu.runtime import faults, memory
 from cubed_tpu.runtime.cancellation import CancellationToken, ComputeCancelledError
 from cubed_tpu.runtime.executors.jax import JaxExecutor
 from cubed_tpu.storage import integrity
 from cubed_tpu.storage.store import ZarrV2Array, _LocalIO, open_zarr_array
+from tests.utils import leased_staging
 
 RNG = np.random.default_rng(35)
 WRITER = "cubed-tpu-flush"
@@ -57,6 +59,15 @@ def _values(dtype, shape) -> np.ndarray:
         return RNG.standard_normal(shape).astype(dtype)
     info = np.iinfo(dtype)
     return RNG.integers(info.min, info.max, shape, dtype=dtype, endpoint=True)
+
+
+@pytest.fixture(autouse=True)
+def _empty_pool():
+    """Every test starts as a process's first compute does, with no staging
+    pair kept, and leaves none behind."""
+    jx.release_staging_buffers()
+    yield
+    jx.release_staging_buffers()
 
 
 @pytest.fixture
@@ -87,7 +98,7 @@ def _flush(tmp_path, host, chunks, name="t", executor=None, carry_bits=False, **
     executor = executor or JaxExecutor()
     executor._carry_bits = carry_bits
     res = jx._Resident(_on_device(host, carry_bits), host.nbytes, z)
-    with task_scope(jx._SCOPE_SPANS) as scope:
+    with task_scope(jx._SCOPE_SPANS) as scope, leased_staging(executor):
         executor._flush(res)
     return z, executor, scope
 
@@ -183,12 +194,13 @@ def test_one_chunk_zero_d_record_and_empty_targets(tmp_path, pair_device, make):
     host, chunks = make()
     z, ex, scope = _flush(tmp_path, host, chunks, carry_bits=host.dtype.fields is not None)
     assert _read_back(z).tobytes() == host.tobytes()
+    (pair,) = jx._STAGING_POOL  # the lease came back
     if host.dtype.fields is not None:
         # the 8-byte fields leave as planes into fresh arrays and are copied
         # into the record: nothing of a record lies in a buffer
         assert ex.stats["d2h_plane_bytes"] == host.size * 16
         assert ex.stats["flush_stream_bytes"] == 0
-        assert all(stage.buffer is None for stage in ex._staging)
+        assert all(stage.buffer is None for stage in pair)
     elif host.shape == (6, 5):
         assert ex.stats["flush_stream_bytes"] == host.nbytes
     else:
@@ -319,16 +331,18 @@ def test_two_buffers_take_turns_and_none_is_rewritten_before_its_write_returned(
     seen = _spy_on_the_pipeline(monkeypatch, write_s=0.03)
     z, ex, _ = _flush(tmp_path, host, (4, 8))
     assert _read_back(z).tobytes() == host.tobytes()
+    assert ex._staging is None
+    (staging,) = jx._STAGING_POOL
     joins = [r for r in seen if r[0] == "join"]
     writes = [r for r in seen if r[0] == "write"]
     wrote = [r for r in seen if r[0] == "wrote"]
     assert len(joins) == len(writes) == len(wrote) == 6
     addresses = [r[1] for r in joins]
-    # the executor's own pair, page-aligned, alternating, written from in place
-    assert addresses == [s.buffer.ctypes.data for s in ex._staging] * 3
+    # the leased pair, page-aligned, alternating, written from in place
+    assert addresses == [s.buffer.ctypes.data for s in staging] * 3
     assert len(set(addresses)) == 2 and all(a % mmap.PAGESIZE == 0 for a in addresses)
     assert [r[1] for r in writes] == addresses
-    assert all(s.buffer.nbytes == 4 * 8 * 8 for s in ex._staging)
+    assert all(s.buffer.nbytes == 4 * 8 * 8 for s in staging)
     # fetches on the calling thread, every write on the one writer, in order
     assert {r[-1] for r in joins} == {threading.current_thread().name}
     assert all(r[-1].startswith(WRITER) for r in writes)
@@ -400,14 +414,28 @@ def test_the_buffers_are_the_preloads_and_a_larger_chunk_grows_them(tmp_path, pa
     src = open_zarr_array(str(tmp_path / "src.zarr"), "w", shape=(8, 8), dtype="f8", chunks=(4, 4))
     src[...] = _pairs((8, 8))
     ex = JaxExecutor()
-    ex._device_put(src, (8, 8), src.chunkset())
-    kept = [s.buffer for s in ex._staging]
-    assert [b.nbytes for b in kept] == [128, 128]
-    _flush(tmp_path, _pairs((8, 8)), (4, 4), "same", ex)
-    assert all(s.buffer is k for s, k in zip(ex._staging, kept))
-    _flush(tmp_path, _pairs((16, 8)), (8, 8), "larger", ex)
-    assert [s.buffer.nbytes for s in ex._staging] == [512, 512]
-    assert ex.stats["flush_stream_bytes"] == 8 * 8 * 8 + 16 * 8 * 8
+    with ex._lease() as staging:
+        ex._device_put(src, (8, 8), src.chunkset())
+        kept = [s.buffer for s in staging]
+        assert [b.nbytes for b in kept] == [128, 128]
+        _flush(tmp_path, _pairs((8, 8)), (4, 4), "same", ex)
+        assert all(s.buffer is k for s, k in zip(staging, kept))
+        # this compute made the buffers: nothing of it counts as reused
+        assert ex.stats["stage_reused_bytes"] == 0
+        _flush(tmp_path, _pairs((16, 8)), (8, 8), "larger", ex)
+        assert [s.buffer.nbytes for s in staging] == [512, 512]
+        assert ex.stats["flush_stream_bytes"] == 8 * 8 * 8 + 16 * 8 * 8
+        assert ex.stats["stage_reused_bytes"] == 0
+    # the next executor's flush finds them, and says so
+    _, other, _ = _flush(tmp_path, _pairs((16, 8)), (8, 8), "next")
+    (pair,) = jx._STAGING_POOL
+    assert pair is staging and [s.buffer.nbytes for s in pair] == [512, 512]
+    assert other.stats["stage_reused_bytes"] == other.stats["flush_stream_bytes"] == 16 * 8 * 8
+    # a chunk through a buffer that had to be made larger does not count
+    _, third, _ = _flush(tmp_path, _pairs((32, 8)), (16, 8), "grown")
+    assert third.stats["flush_stream_bytes"] == 32 * 8 * 8
+    assert third.stats["stage_reused_bytes"] == 0
+    assert [s.buffer.nbytes for s in pair] == [1024, 1024] and jx._STAGING_POOL == [pair]
 
 
 def test_a_flush_holds_two_chunks_and_one_pair_of_planes_on_the_host(tmp_path, pair_device):
@@ -490,7 +518,8 @@ def test_a_fetch_error_waits_for_the_write_in_flight_and_keeps_its_records(
     ex = JaxExecutor()
     res = jx._Resident(_on_device(_pairs((24, 8))), 24 * 8 * 8, z)
     with task_scope(jx._SCOPE_SPANS) as scope, pytest.raises(RuntimeError, match="device is gone"):
-        ex._flush(res)
+        with leased_staging(ex):
+            ex._flush(res)
     # chunk 1's write was in flight: it finished, and is accounted for
     assert [r[0] for r in seen].count("wrote") == 2
     assert scope.chunks_written == 2 and scope.bytes_written == 2 * 4 * 8 * 8
@@ -530,7 +559,8 @@ def test_without_a_scope_on_the_caller_the_writer_has_none_either(tmp_path, pair
     z = open_zarr_array(str(tmp_path / "t.zarr"), "w", shape=(8, 8), dtype="f8", chunks=(4, 4))
     ex = JaxExecutor()
     with faults.scoped(faults.FaultConfig(seed=5, storage_write_failure_rate=1.0)):
-        ex._flush(jx._Resident(_on_device(host), host.nbytes, z))
+        with leased_staging(ex):
+            ex._flush(jx._Resident(_on_device(host), host.nbytes, z))
     assert _read_back(z).tobytes() == host.tobytes()
     # nothing observed, nothing claimed
     assert ex.stats["flush_stream_bytes"] == 0 == ex.stats["encode_copy_bytes"]
@@ -586,6 +616,163 @@ def test_a_cancellation_seen_on_the_second_thread_is_raised_from_the_compute(
     assert [name for name, _ in calls] == ["0.0", "0.1"]
     assert all(thread.startswith(WRITER) for _, thread in calls)
     assert _no_writer_left()
+
+
+@pytest.mark.parametrize("ending", ["write_fault", "write_error", "cancelled"])
+def test_the_lease_comes_back_however_the_flush_ends(
+    tmp_path, spec, pair_device, monkeypatch, ending
+):
+    """An injected storage fault in a chunk write, an error of the disk and
+    a cancellation seen by the writer each end the compute from inside its
+    flush: the pair it had leased is the pool's again, with no device update
+    left on either buffer, and the executor holds none."""
+    token, ex, held = CancellationToken(), JaxExecutor(), []
+    real = _LocalIO.write_bytes_atomic
+
+    def write(self, name, data, inject=True):
+        if self.root.endswith("out.zarr") and not name.startswith("."):
+            held.append(ex._staging)
+            if len(held) == 2 and ending == "write_error":
+                raise OSError("disk full, says the test")
+            if len(held) == 2 and ending == "cancelled":
+                token.cancel("the test asked")
+        return real(self, name, data, inject)
+
+    real_flush = JaxExecutor._flush
+
+    def flush(self, res):
+        # the injector armed for this flush alone: the target's metadata is written
+        rate = 0.0 if held else 1.0
+        with faults.scoped(faults.FaultConfig(seed=5, storage_write_failure_rate=rate)):
+            real_flush(self, res)
+
+    monkeypatch.setattr(_LocalIO, "write_bytes_atomic", write)
+    if ending == "write_fault":
+        monkeypatch.setattr(JaxExecutor, "_flush", flush)
+    expr, _ = _sum_of_two(tmp_path, spec)
+    error = {"write_fault": faults.FaultInjectedIOError, "write_error": OSError,
+             "cancelled": ComputeCancelledError}[ending]
+    with pytest.raises(error):
+        ct.to_zarr(expr, str(tmp_path / "out.zarr"), executor=ex, cancellation=token)
+    assert held and all(pair is held[0] for pair in held)
+    assert ex._staging is None and jx._STAGING_POOL == [held[0]]
+    assert all(stage.busy is None and stage.buffer is not None for stage in held[0])
+    assert _no_writer_left()
+    # and the next compute works with it
+    cap = _Capture()
+    expr, want = _sum_of_two(tmp_path, spec)
+    ct.to_zarr(expr, str(tmp_path / "again.zarr"), executor=JaxExecutor(), callbacks=[cap])
+    assert open_zarr_array(str(tmp_path / "again.zarr"), "r")[...].tobytes() == want.tobytes()
+    assert cap.stats["stage_reused_bytes"] == (
+        cap.stats["h2d_stream_bytes"] + cap.stats["flush_stream_bytes"]
+    ) == 3 * want.nbytes
+    assert jx._STAGING_POOL == [held[0]]
+
+
+# -- whose the buffers are --------------------------------------------------------------
+
+
+def test_two_computes_at_once_never_write_the_same_buffer(tmp_path, spec, pair_device, monkeypatch):
+    """Two threads compute at once in a process whose pool holds a pair: one
+    leases it, the other finds it gone and works with a fresh pair of its
+    own, waiting for nobody. Every read-into and every join of the one lands
+    at another address than any of the other's, both targets are right to
+    the bit, and the pool holds one pair afterwards."""
+    # ``Plan.execute`` arms the memory guard for the process by save and
+    # restore (``memory.scoped``, with its environment variable), which two
+    # computes at once interleave: whatever they leave, put back what was
+    monkeypatch.setattr(memory, "_active", memory._active)
+    if memory.MEMORY_GUARD_ENV_VAR in os.environ:
+        monkeypatch.setenv(memory.MEMORY_GUARD_ENV_VAR, os.environ[memory.MEMORY_GUARD_ENV_VAR])
+    else:
+        monkeypatch.delenv(memory.MEMORY_GUARD_ENV_VAR, raising=False)
+    for name in ("warm", "one", "two"):
+        (tmp_path / name).mkdir()
+    expr, _ = _sum_of_two(tmp_path / "warm", spec)
+    ct.to_zarr(expr, str(tmp_path / "warm" / "out.zarr"), executor=JaxExecutor())
+    (pooled,) = jx._STAGING_POOL
+    jobs = {name: (*_sum_of_two(tmp_path / name, spec), JaxExecutor(), _Capture())
+            for name in ("one", "two")}
+    both_inside = threading.Barrier(2, timeout=60)
+    leases, addresses, lock = {}, {"one": set(), "two": set()}, threading.Lock()
+    real_read, real_join = _LocalIO.readinto, jx._join_planes
+
+    def note(address):
+        me = threading.current_thread().name
+        if me not in jobs:
+            return
+        with lock:
+            first = me not in leases
+            # kept, so that no buffer is freed and its address used again
+            leases.setdefault(me, jobs[me][2]._staging)
+            addresses[me].add(address)
+        if first:
+            both_inside.wait()  # each holds its lease before either goes on
+
+    def readinto(self, name, buffer):
+        note(np.asarray(buffer).__array_interface__["data"][0])
+        return real_read(self, name, buffer)
+
+    def join(first, second, dtype, out=None):
+        assert out is not None
+        note(out.ctypes.data)
+        return real_join(first, second, dtype, out)
+
+    monkeypatch.setattr(_LocalIO, "readinto", readinto)
+    monkeypatch.setattr(jx, "_join_planes", join)
+    errors = []
+
+    def run(name):
+        expr, _, ex, cap = jobs[name]
+        try:
+            ct.to_zarr(expr, str(tmp_path / name / "out.zarr"), executor=ex, callbacks=[cap])
+        except BaseException as error:  # shown by the test's thread
+            errors.append(error)
+
+    threads = [threading.Thread(target=run, args=(name,), name=name) for name in jobs]
+    for thread in threads:
+        thread.start()
+    for thread in threads:
+        thread.join(120)
+    assert not errors and not any(thread.is_alive() for thread in threads)
+    for name, (_, want, _, _) in jobs.items():
+        assert open_zarr_array(str(tmp_path / name / "out.zarr"), "r")[...].tobytes() == want.tobytes()
+    assert leases["one"] is not leases["two"]
+    assert all(len(seen) == 2 for seen in addresses.values())
+    assert not addresses["one"] & addresses["two"]
+    # one of them had the pool's pair and found its buffers made, the other made its own
+    mine = [name for name in jobs if leases[name] is pooled]
+    assert len(mine) == 1
+    for name, (_, want, _, cap) in jobs.items():
+        staged = cap.stats["h2d_stream_bytes"] + cap.stats["flush_stream_bytes"]
+        assert staged == 3 * want.nbytes
+        assert cap.stats["stage_reused_bytes"] == (staged if name in mine else 0)
+    # the pool never holds more than one pair: the first to end gave its own back
+    assert len(jx._STAGING_POOL) == 1 and jx._STAGING_POOL[0] in leases.values()
+    assert all(stage.busy is None for pair in leases.values() for stage in pair)
+    assert _no_writer_left()
+
+
+def test_nothing_a_compute_returns_lies_in_a_pooled_buffer(tmp_path, spec, pair_device):
+    """What goes through a staging buffer goes to the device or to the store's
+    file: the array ``compute`` returns is read back from the store and shares
+    no memory with the pair the pool keeps, so the next compute, which writes
+    other values through the same two buffers, leaves it as it was."""
+    expr, want = _sum_of_two(tmp_path, spec)
+    cap = _Capture()
+    got = expr.compute(executor=JaxExecutor(), callbacks=[cap])
+    assert got.tobytes() == want.tobytes()
+    assert cap.stats["flush_stream_bytes"] == want.nbytes  # it did go through them
+    (pair,) = jx._STAGING_POOL
+    assert all(stage.buffer is not None and stage.busy is None for stage in pair)
+    assert not any(np.may_share_memory(got, stage.buffer) for stage in pair)
+    (tmp_path / "next").mkdir()
+    other, other_want = _sum_of_two(tmp_path / "next", spec)
+    again = other.compute(executor=JaxExecutor(), callbacks=[cap])
+    assert cap.stats["stage_reused_bytes"] == 3 * want.nbytes
+    assert again.tobytes() == other_want.tobytes() != want.tobytes()
+    assert got.tobytes() == want.tobytes()
+    assert not any(np.may_share_memory(again, stage.buffer) for stage in pair)
 
 
 # -- spans and counters --------------------------------------------------------------

@@ -5,6 +5,7 @@ Reference parity: cubed/tests/utils.py:14-103.
 
 from __future__ import annotations
 
+import contextlib
 import platform
 
 from cubed_tpu.runtime.types import Callback
@@ -113,3 +114,15 @@ def execute_pipeline(primitive_op, executor=None):
         primitive_op.target_array.create(mode="a")
     for m in primitive_op.pipeline.mappable:
         primitive_op.pipeline.function(m, config=primitive_op.pipeline.config)
+
+
+@contextlib.contextmanager
+def leased_staging(executor):
+    """A ``JaxExecutor`` with a staging pair in its hands, as inside a
+    compute, for tests that drive ``_device_put`` or ``_flush`` themselves:
+    the lease it has, or one for the length of the block."""
+    if executor._staging is not None:
+        yield executor._staging
+    else:
+        with executor._lease() as staging:
+            yield staging
