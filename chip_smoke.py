@@ -96,6 +96,16 @@ PATH_COUNTERS = (
     "h2d_stream_bytes",
 )
 
+#: how the chunks of a stored array moved under a mesh (JaxExecutor.stats)
+MESH_IO_COUNTERS = (
+    "mesh_owner_bytes",
+    "mesh_gathered_bytes",
+    "h2d_bytes",
+    "h2d_stream_bytes",
+    "h2d_stream_declined",
+    "d2h_bytes",
+)
+
 #: the sizes the upstream project itself calls real (see the module docstring)
 VORTICITY = dict(shape=(500, 450, 400), chunks=100, allowed_mem="4GB")
 ZARR_ADD = dict(n=10000, chunk=5000, allowed_mem="2GB")
@@ -364,6 +374,31 @@ def vorticity_leg(
     if warm_value != value:
         raise AssertionError(f"{name}: same seed gave {value} then {warm_value}")
     return {"mean": (cold, warm)}
+
+
+def check_owner_io(runs: dict) -> None:
+    """Of a ``zarr_add_leg`` under a mesh whose shards are blocks of whole
+    chunks: every compute streamed every source to the chips that own its
+    chunks (``h2d_stream_bytes`` all of ``h2d_bytes``, ``mesh_owner_bytes``
+    above 0), and what touched more than one chip
+    (``mesh_gathered_bytes``) is at most what came back: nothing for the
+    add, whose target's chunks each lie on one chip. (The mean's row of two
+    chunks lies over four chips, so each of its chunks crosses two shards
+    and is sliced by a program of them all.)"""
+    for what, pair in runs.items():
+        for temp, run in zip(("cold", "warm"), pair):
+            moved = {k: run.stats.get(k, 0) for k in MESH_IO_COUNTERS}
+            _say(f"{what} ({temp}): mesh " + " ".join(f"{k}={v}" for k, v in moved.items()))
+            crossed = 0 if what == "add" else moved["d2h_bytes"]
+            if (
+                moved["mesh_gathered_bytes"] > crossed
+                or not moved["mesh_owner_bytes"]
+                or moved["h2d_stream_bytes"] != moved["h2d_bytes"]
+            ):
+                raise RuntimeError(
+                    f"{what} ({temp}): a chunk did not move between the host "
+                    f"and its owner: {moved}"
+                )
 
 
 def zarr_add_leg(
@@ -784,12 +819,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                 make_executor=mesh_executor, log=log, name="mesh vorticity",
             )
             check_shares()
-            zarr_add_leg(
+            # a 2 x 2 grid on four chips: a chunk a chip, in and out; the
+            # mean reduces stored data along an axis the mesh divides
+            check_owner_io(zarr_add_leg(
                 **ZARR_ADD, seed=args.seed,
                 work_dir=os.path.join(root, "mesh-zarr_add"),
-                make_executor=mesh_executor, log=log,
-                computes=("add", "rechunk"), name="mesh zarr_add",
-            )
+                make_executor=mesh_executor, log=log, name="mesh zarr_add",
+            ))
             check_shares()
         else:
             print("== mesh legs skipped: one device visible", flush=True)

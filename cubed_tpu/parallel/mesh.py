@@ -11,10 +11,13 @@ round-trips. Multi-host meshes extend the same mapping over DCN.
 
 from __future__ import annotations
 
+import itertools
 import math
 from typing import Optional, Sequence
 
 import numpy as np
+
+from ..utils import get_item
 
 
 def make_mesh(
@@ -123,6 +126,46 @@ def sharding_for_chunks(
         (tuple(a) if len(a) > 1 else a[0]) if a else None for a in assigned
     ]
     return NamedSharding(mesh, PartitionSpec(*spec))
+
+
+def shard_bounds(index, shape: Sequence[int]) -> tuple:
+    """A shard's index (slices, as a sharding or a shard gives it) as one
+    (start, stop) a dim."""
+    return tuple(
+        (sl.start or 0, dim if sl.stop is None else sl.stop)
+        for sl, dim in zip(index, shape)
+    )
+
+
+def within(sel, bounds) -> bool:
+    """Whether the region ``sel`` (slices) lies inside ``bounds``."""
+    return all(lo <= cut.start and cut.stop <= hi for cut, (lo, hi) in zip(sel, bounds))
+
+
+def chunk_owners(sharding, shape: Sequence[int], chunkset):
+    """chunk coords -> (device, the bounds of that device's shard) where
+    ``sharding`` lays the array out as blocks of whole chunks, each held by
+    one device this process can address: every chunk then has one owner and
+    lies inside its shard. None for any other layout (a shard boundary
+    inside a chunk, a region held by several devices, a device of another
+    process), which has no owner to stream a chunk to."""
+    shape = tuple(shape)
+    if not sharding.is_fully_addressable:
+        return None
+    shards = {}
+    for device, index in sharding.devices_indices_map(shape).items():
+        bounds = shard_bounds(index, shape)
+        if bounds in shards:
+            return None
+        shards[bounds] = device
+    owners = {}
+    for coords in itertools.product(*(range(len(c)) for c in chunkset)):
+        sel = get_item(chunkset, coords)
+        held = next((b for b in shards if within(sel, b)), None)
+        if held is None:
+            return None
+        owners[coords] = (shards[held], held)
+    return owners
 
 
 def reshard(x, mesh, chunkset, shape):

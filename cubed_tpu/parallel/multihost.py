@@ -23,6 +23,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..chunks import blockdims_from_blockshape
 from ..utils import get_item
+from .mesh import shard_bounds, within
 
 
 def default_host_of_device(device) -> int:
@@ -78,22 +79,28 @@ def chunk_owner_devices(
     the start-corner rule still yields a total, deterministic partition —
     which is all per-host IO needs (each byte read once, by one host).
     """
-    index_map = sharding.devices_indices_map(tuple(shape))
-    nb = [len(c) for c in chunkset]
+    held = _device_bounds(sharding, shape)
     owners: Dict[Tuple[int, ...], object] = {}
-    for coords in itertools.product(*(range(n) for n in nb)):
-        sel = get_item(chunkset, coords)
-        start = tuple(s.start for s in sel)
-        owner = None
-        for device, idx in index_map.items():
-            if all(
-                (sl.start or 0) <= st < (sl.stop if sl.stop is not None else dim)
-                for sl, st, dim in zip(idx, start, shape)
-            ):
-                owner = device
-                break
-        owners[coords] = owner
+    for coords in itertools.product(*(range(len(c)) for c in chunkset)):
+        corner = _start_corner(get_item(chunkset, coords))
+        owners[coords] = next(
+            (device for device, bounds in held if within(corner, bounds)), None
+        )
     return owners
+
+
+def _device_bounds(sharding, shape) -> list:
+    """[(device, the bounds of its shard)] in the sharding's device order."""
+    shape = tuple(shape)
+    return [
+        (device, shard_bounds(index, shape))
+        for device, index in sharding.devices_indices_map(shape).items()
+    ]
+
+
+def _start_corner(sel) -> tuple:
+    """The one-element region at the start of ``sel``."""
+    return tuple(slice(s.start, s.start + 1) for s in sel)
 
 
 def chunk_within_owner_shard(
@@ -102,19 +109,11 @@ def chunk_within_owner_shard(
     """True when the chunk's whole region lies inside its owner's shard —
     the alignment a multi-process flush needs (a straddling chunk's data
     spans devices other processes own and cannot be fetched locally)."""
-    index_map = sharding.devices_indices_map(tuple(shape))
     sel = get_item(chunkset, coords)
-    start = tuple(s.start for s in sel)
-    for device, idx in index_map.items():
-        if all(
-            (sl.start or 0) <= st < (sl.stop if sl.stop is not None else dim)
-            for sl, st, dim in zip(idx, start, shape)
-        ):
-            return all(
-                (sl.start or 0) <= c.start
-                and c.stop <= (sl.stop if sl.stop is not None else dim)
-                for sl, c, dim in zip(idx, sel, shape)
-            )
+    corner = _start_corner(sel)
+    for _, bounds in _device_bounds(sharding, shape):
+        if within(corner, bounds):
+            return within(sel, bounds)
     return False
 
 

@@ -6,13 +6,23 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   store path. Zarr is only touched at plan boundaries: sources are loaded once,
   and requested outputs are flushed at the end. Intermediates never hit
   storage (the reference pays a full storage round-trip per op).
-- **Streamed preload.** Without a mesh a stored source of several chunks is
-  never assembled on the host: each chunk file is read into one of two
-  staging buffers, put on the device from there and written into its place
-  in one resident array that is updated in place (``_stream_to_device``,
-  ``_chunk_writer``), bit for bit what the put of the whole array gives. The route is chosen from what ``_device_put``
-  observes (``_streams``); ``stats["h2d_stream_bytes"]`` counts what went
-  that way and ``stats["h2d_stream_declined"]`` what found no room.
+- **Streamed preload.** A stored source of several chunks is never
+  assembled on the host: each chunk file is read into one of two staging
+  buffers, put from there on the chip that owns the chunk and written into
+  its place in that chip's shard, which is updated in place
+  (``_stream_to_device``, ``_chunk_writer``), bit for bit what the put of
+  the whole array gives. Without a mesh the one owner is the default device
+  and its shard the array; under one, every shard has to be a block of whole
+  chunks on a chip of its own, and the shards are joined at the end. The
+  route is chosen from what ``_device_put`` observes (``_streams``);
+  ``stats["h2d_stream_bytes"]`` counts what went that way and
+  ``stats["h2d_stream_declined"]`` what found no room, or no owner.
+- **Under a mesh a chunk touches one chip.** On the way out a chunk is
+  sliced from the shard that holds it (``_chunk_of``), so its slice, split
+  and fetch run on that chip alone. ``stats["mesh_owner_bytes"]`` counts
+  the bytes that moved between the host and exactly one chip, either way,
+  ``stats["mesh_gathered_bytes"]`` those that touched several (a shard
+  assembled by the callback, a chunk that crosses shards).
 - **The staging pair is the process's.** The two buffers outlive the
   executor: ``execute_dag`` leases the one pair the process keeps
   (``_leased_staging``) and gives it back when the compute ends, however
@@ -457,8 +467,13 @@ class JaxExecutor(DagExecutor):
         #: staging buffer the compute found allocated when it leased the
         #: pair, not one it had to make or to make larger; 0, not absent),
         #: ``h2d_stream_declined`` (stored arrays that
-        #: qualified for the stream and were put whole for want of room in
-        #: HBM), ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
+        #: qualified for the stream and did not take it: put whole for want
+        #: of room in HBM, or read through the callback under a sharding
+        #: that gives some chunk no one owner), ``mesh_owner_bytes`` /
+        #: ``mesh_gathered_bytes`` (under a mesh, the bytes of values that
+        #: moved between the host and exactly one chip, either way, and of
+        #: those that touched several; each 0, not absent, without one),
+        #: ``d2h_plane_bytes`` (the part of ``d2h_bytes`` that
         #: left as 32-bit planes), ``d2h_plane_strided_bytes`` (the part of
         #: that whose planes reached the host in another order than
         #: row-major; 0, not absent), ``d2h_plane_no_room`` / ``d2h_plane_inexact``
@@ -640,16 +655,19 @@ class JaxExecutor(DagExecutor):
         the shape of the array it is (a part of) for sharding, None for a
         piece that is placed whole. A record array becomes a dict of its
         fields. float64 goes as its uint64 bit pattern when this compute
-        carries bits (see the module docstring). Under a mesh a storage
-        array is read through ``make_array_from_callback``: each process
+        carries bits (see the module docstring). A stored array of several
+        chunks goes chunk by chunk, each to the chip that owns it
+        (``_stream_to_device``), where every chunk has one owner and HBM
+        has the room (``_streams``). Any other storage array under a mesh
+        is read through ``make_array_from_callback``: each process
         materializes only the regions its addressable shards cover — the
         per-host Zarr IO sharding seam of docs/multihost.md (on one host
-        this degenerates to reading everything, shard by shard). Without
-        one, a stored array of several chunks goes chunk by chunk
-        (``_stream_to_device``) where HBM has the room (``_streams``), and
-        anything else is read whole on the host and put in one piece. All
-        routes share the representation rules here and give the same
-        device value, bit for bit."""
+        this degenerates to reading everything, shard by shard). Anything
+        else is read whole on the host and put in one piece. All routes
+        share the representation rules here and give the same device
+        value, bit for bit; under a mesh every one counts its bytes as
+        ``mesh_owner_bytes`` or ``mesh_gathered_bytes``
+        (``_count_mesh_io``)."""
         jax = _jax()
         stored = not isinstance(value, (np.ndarray, np.generic))
         if value.dtype.fields is not None:
@@ -671,11 +689,13 @@ class JaxExecutor(DagExecutor):
             return data.view(np.uint64)
 
         sharding = self._sharding_for(shape, chunkset)
-        if sharding is None and stored:
-            if self._streams(value):
-                return self._stream_to_device(value, transferred)
-            value = value[...] if value.shape else value[()]
-            stored = False
+        if stored:
+            owners = self._streams(value, sharding)
+            if owners is not None:
+                return self._stream_to_device(value, transferred, sharding, owners)
+            if sharding is None:
+                value = value[...] if value.shape else value[()]
+                stored = False
         with scope_span("jax.h2d", cat="transfer") as sp:
             if stored:
                 # the store's reads happen inside, shard by shard
@@ -685,45 +705,92 @@ class JaxExecutor(DagExecutor):
             else:
                 out = jax.device_put(transferred(value), sharding)
             sp.attrs["bytes"] = _value_nbytes(out)
+            self._count_mesh_io(out, sp)
         return out
 
-    def _streams(self, stored) -> bool:
-        """Whether ``_device_put`` sends the unsharded ``stored`` to the
-        device chunk by chunk: one of the package's own Zarr arrays (the
-        chunk-level read is theirs), of more than one chunk, with room in
-        HBM for the array and two chunks in flight beside what is resident.
+    def _count_mesh_io(self, value, sp) -> None:
+        """One transfer's bytes on the mesh's two counters, and the chip on
+        the transfer's span. ``value`` is what lies on the device: on one
+        chip, it moved between the host and exactly that chip
+        (``mesh_owner_bytes``, and the span's ``device``); on several, it
+        was assembled for them or gathered from them
+        (``mesh_gathered_bytes``). Without a mesh neither counts."""
+        sharding = getattr(value, "sharding", None)
+        if sharding is None:  # a host value that an eager op left resident
+            return
+        devices = sharding.device_set
+        one_chip = len(devices) == 1
+        if one_chip:
+            sp.attrs["device"] = next(iter(devices)).id
+        if self.mesh is not None:
+            self.stats[
+                "mesh_owner_bytes" if one_chip else "mesh_gathered_bytes"
+            ] += _value_nbytes(value)
+
+    def _streams(self, stored, sharding) -> Optional[Dict[tuple, tuple]]:
+        """Where ``_device_put`` sends ``stored`` to the device chunk by
+        chunk, each chunk's owner: chunk coords -> (device, the bounds of
+        its shard); None where it does not. It does for one of the
+        package's own Zarr arrays (the chunk-level read is theirs), of more
+        than one chunk, whose chunks each have one chip to go to, with room
+        there for its shard and two chunks in flight beside what is
+        resident. Without a sharding the one owner is the default device
+        (None) and its shard the whole array; under one, every shard has to
+        be a block of whole chunks on a chip of its own
+        (``parallel.mesh.chunk_owners``), and the room is a chip's share of
+        the budget.
         A device that holds 64-bit elements as 32-bit pairs updates such an
         array through a split copy of it and of the chunk (1.21 GB of
         temporaries for an 800 MB float64 array and a 200 MB chunk, by the
         v5e's compiler), so there the room is asked for twice, and every
-        update is a pass over the whole array, so there the chunks are few
+        update is a pass over the whole shard, so there the chunks are few
         (``_PAIR_STREAM_MAX_CHUNKS``). All of it observed in the call;
-        nothing selects the route from outside."""
+        nothing selects the route from outside. A source that qualifies and
+        is declined, for want of room or of a chunk-aligned layout, is
+        counted (``h2d_stream_declined``)."""
         if (
             not isinstance(stored, ZarrV2Array)
             or stored.nchunks < 2
             or stored.size == 0
         ):
-            return False
+            return None
+        chunkset = stored.chunkset()
+        if sharding is None:
+            whole = tuple((0, dim) for dim in stored.shape)
+            grid = itertools.product(*(range(len(c)) for c in chunkset))
+            owners, chips = {idx: (None, whole) for idx in grid}, 1
+        else:
+            from ...parallel.mesh import chunk_owners
+
+            owners = chunk_owners(sharding, stored.shape, chunkset)
+            if owners is None:
+                self.stats["h2d_stream_declined"] += 1
+                return None
+            chips = len(sharding.device_set)
         held = sum(r.nbytes for r in self._resident.values())
-        needed = stored.nbytes + 2 * stored._chunk_nbytes()
+        needed = stored.nbytes // chips + 2 * stored._chunk_nbytes()
         if stored.dtype in _PAIR_DTYPES and not _float64_round_trips(
             self._first_device()
         ):
-            if stored.nchunks > _PAIR_STREAM_MAX_CHUNKS:
-                return False
+            if stored.nchunks > _PAIR_STREAM_MAX_CHUNKS * chips:
+                return None
             needed *= 2
-        if held + needed > self._budget():
+        if held + needed * chips > self._budget():
             self.stats["h2d_stream_declined"] += 1
-            return False
-        return True
+            return None
+        return owners
 
-    def _stream_to_device(self, stored, transferred):
+    def _stream_to_device(self, stored, transferred, sharding, owners):
         """``stored`` on the device without a copy of it on the host: each
         chunk file is read into one of the two staging buffers this compute
-        has leased (``_lease``), put on the device from there and written
-        into its place in one resident array of the full shape, which is
-        allocated once and updated in place (``_chunk_writer``).
+        has leased (``_lease``), put on the chip that owns the chunk from
+        there and written into its place in that chip's shard, which is
+        allocated once and updated in place (``_chunk_writer``). Without a
+        sharding there is one owner and its shard is the array; under one
+        (``owners``, from ``_streams``) the shards are joined at the end
+        into one array of that sharding, and the chunks are taken a chip in
+        turn, so that one chip's update runs while the next chip's chunk is
+        read and put.
 
         The buffers take turns, so the read of chunk k + 1 overlaps the
         transfer and update of chunk k; chunk k's span ends with the wait
@@ -738,34 +805,52 @@ class JaxExecutor(DagExecutor):
         jax = _jax()
         chunk_nbytes = stored._chunk_nbytes()
         write = _chunk_writer()
-        whole = None
         chunkset = stored.chunkset()
-        grid = itertools.product(*(range(len(c)) for c in chunkset))
-        for k, idx in enumerate(grid):
+        # grid order chip by chip, then one chunk of each chip in turn
+        queues: Dict[Any, list] = {}
+        for idx, (device, _) in owners.items():
+            queues.setdefault(device, []).append(idx)
+        order = [
+            idx
+            for turn in itertools.zip_longest(*queues.values())
+            for idx in turn
+            if idx is not None
+        ]
+        shards: Dict[Any, Any] = {}
+        for k, idx in enumerate(order):
+            device, bounds = owners[idx]
             stage, other = self._staging[k % 2], self._staging[1 - k % 2]
             # the source before may have left its last update on this one
             self.stats["stage_wait_us"] += stage.release()
             chunk = stored._read_chunk_into(idx, stage.sized(chunk_nbytes))
             if chunk is None:
                 chunk = stored._empty_chunk()
-            # where the chunk goes, and what of it (stored padded) lies
-            # inside the array
+            # where the chunk goes in its shard, and what of it (stored
+            # padded) lies inside the array
             sel = get_item(chunkset, idx)
-            start = tuple(s.start for s in sel)
+            start = tuple(s.start - b[0] for s, b in zip(sel, bounds))
             extent = tuple(s.stop - s.start for s in sel)
             with scope_span("jax.h2d", cat="transfer") as sp:
-                piece = jax.device_put(transferred(chunk))
-                if whole is None:
-                    whole = jax.numpy.zeros(stored.shape, piece.dtype)
-                whole, stage.busy = write(
-                    whole, piece, np.asarray(start, np.int32), extent
+                piece = jax.device_put(transferred(chunk), device)
+                if device not in shards:
+                    shards[device] = jax.numpy.zeros(
+                        tuple(b[1] - b[0] for b in bounds), piece.dtype, device=device
+                    )
+                shards[device], stage.busy = write(
+                    shards[device], piece, np.asarray(start, np.int32), extent
                 )
                 sp.attrs["bytes"] = piece.nbytes
+                self._count_mesh_io(piece, sp)
                 waited = sp.attrs["wait_us"] = other.release()
             self.stats["stage_wait_us"] += waited
             self.stats["h2d_stream_bytes"] += piece.nbytes
             self.stats["stage_reused_bytes"] += piece.nbytes if stage.kept else 0
-        return whole
+        if sharding is None:
+            (whole,) = shards.values()
+            return whole
+        return jax.make_array_from_single_device_arrays(
+            tuple(stored.shape), sharding, list(shards.values())
+        )
 
     def _to_host(self, value, dtype, stage: Optional[_Staging] = None) -> np.ndarray:
         """Device -> host: a device value (or dict of record fields) as a
@@ -801,6 +886,7 @@ class JaxExecutor(DagExecutor):
             if self._carry_bits and host.dtype == np.uint64 and dtype == np.float64:
                 host = host.view(np.float64)
             sp.attrs["bytes"] = host.nbytes
+            self._count_mesh_io(value, sp)
         self.stats["d2h_bytes"] += host.nbytes
         # present, and 0, where nothing left as planes, or none strided
         self.stats["d2h_plane_bytes"] += host.nbytes if planes else 0
@@ -940,6 +1026,8 @@ class JaxExecutor(DagExecutor):
             stage_wait_us=0,
             preload_page_faults=0,
             h2d_bits_bytes=0,
+            mesh_owner_bytes=0,
+            mesh_gathered_bytes=0,
             rechunk_host_whole=0,
             rechunk_host_copy=0,
         )
@@ -2514,7 +2602,8 @@ class JaxExecutor(DagExecutor):
 
         A pipeline of depth two over the chunk grid, in grid order, the
         mirror image of ``_stream_to_device``: this thread slices chunk
-        k + 1 on the device, fetches it and joins its planes into staging
+        k + 1 on the device (on the chip that holds it, where one does:
+        ``_chunk_of``), fetches it and joins its planes into staging
         buffer (k + 1) mod 2 while a second thread writes chunk k from
         buffer k mod 2 (``ZarrV2Array.__setitem__``: file write, fsync,
         rename, directory fsync, CRC-32, manifest line, all of which let
@@ -2624,9 +2713,9 @@ class JaxExecutor(DagExecutor):
                     # the device slice is not bound to a name here: it would
                     # stay alive on the device while the next chunk is sliced
                     host = self._to_host(
-                        {f: v[sel] for f, v in value.items()}
+                        {f: _chunk_of(v, sel) for f, v in value.items()}
                         if isinstance(value, dict)
-                        else value[sel],
+                        else _chunk_of(value, sel),
                         concrete.dtype,
                         stage,
                     )
@@ -2643,6 +2732,26 @@ class JaxExecutor(DagExecutor):
                 # waited for and its records kept
                 settle(pending)
         return chunks
+
+
+def _chunk_of(value, sel):
+    """``value[sel]``, cut on the chip that holds it where one does: a
+    chunk that lies inside one shard of a value laid over several chips is
+    sliced out of that shard, so the slice, and the split and fetch that
+    follow it, are programs of that one chip and no other is touched. A
+    chunk that crosses shards, and any value on one device, is sliced as it
+    is (of a sharded value that is a program of every chip it lies on)."""
+    shards = getattr(value, "addressable_shards", ())
+    if len(shards) > 1:
+        from ...parallel.mesh import shard_bounds, within
+
+        for shard in shards:
+            bounds = shard_bounds(shard.index, value.shape)
+            if within(sel, bounds):
+                return shard.data[
+                    tuple(slice(c.start - lo, c.stop - lo) for c, (lo, _) in zip(sel, bounds))
+                ]
+    return value[sel]
 
 
 #: the ``chunk_key`` of the event that carries a flush's IO and spans

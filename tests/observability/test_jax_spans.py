@@ -195,7 +195,8 @@ def test_a_source_of_one_chunk_is_a_preload_that_did_not_stream(tmp_path):
         "bytes": CHUNK * CHUNK * 8, "chunks": 1, "streamed": False,
     }
     (h2d,) = [s for s in _spans(tc) if s["name"] == "jax.h2d"]
-    assert h2d["attrs"] == {"bytes": CHUNK * CHUNK * 8}  # put whole: no buffer waited for
+    # put whole, on the one device: no buffer waited for
+    assert h2d["attrs"] == {"bytes": CHUNK * CHUNK * 8, "device": 0}
     assert cap.stats["h2d_stream_bytes"] == 0 < cap.stats["h2d_bytes"]
 
 
@@ -318,24 +319,44 @@ def test_flush_event_completes_no_task(sources, tmp_path):
     assert len(carriers) == 1
 
 
-def test_under_a_mesh_reads_are_recorded_and_nest_under_h2d(sources, tmp_path):
+@pytest.mark.parametrize("chips, streams", [(4, True), (8, False)],
+                         ids=["a_chunk_a_chip_streams", "through_a_chunk_nests_under_h2d"])
+def test_under_a_mesh_reads_are_recorded_beside_or_under_h2d(sources, tmp_path, chips, streams):
+    """Four chips take the 2 x 2 grid a chunk each: the sources stream, a
+    chunk's read is the sibling of its ``jax.h2d`` as without a mesh, and the
+    span says which chip the chunk went to. Eight cut an axis four ways,
+    through the chunks: the callback's reads nest under the one ``jax.h2d``
+    of a source, which names no chip."""
     import jax
 
     from cubed_tpu.parallel.mesh import make_mesh
 
     tc = TraceCollector(trace_dir=None)
-    executor = JaxExecutor(mesh=make_mesh(devices=jax.devices()[:4]))
+    executor = JaxExecutor(mesh=make_mesh(devices=jax.devices()[:chips]))
     stats = _store(sources, tmp_path, "c", [tc], executor=executor).stats
-    reads = 0
+    reads, chips_named, chips_left = 0, [], []
     for rec in tc._records:
         by_id = {s["id"]: s for s in rec["spans"]}
         for s in rec["spans"]:
             if s["name"] == "storage_read":
                 reads += 1
-                assert by_id[s["parent"]]["name"] == "jax.h2d"
+                assert by_id[s["parent"]]["name"] == ("jax.preload" if streams else "jax.h2d")
+            if s["name"] == "jax.h2d" and "device" in s["attrs"]:
+                chips_named.append(s["attrs"]["device"])
+            if s["name"] == "jax.d2h":
+                chips_left.append(s["attrs"].get("device"))
     assert reads >= 8
     assert stats["span_n"]["storage_read"] == reads
-    assert stats["span_self_s"]["jax.h2d"] < stats["span_s"]["jax.h2d"]
+    if streams:
+        assert sorted(chips_named) == sorted([d.id for d in jax.devices()[:4]] * 2)
+        assert stats["span_n"]["jax.h2d"] == 8
+        # and each chunk of the target left from the chip that holds it
+        assert sorted(chips_left) == sorted(d.id for d in jax.devices()[:4])
+    else:
+        assert chips_named == [] and stats["span_n"]["jax.h2d"] == 2
+        # a chunk that crosses shards is fetched from them all: no one chip
+        assert chips_left == [None] * 4
+        assert stats["span_self_s"]["jax.h2d"] < stats["span_s"]["jax.h2d"]
     np.testing.assert_allclose(
         ct.from_zarr(str(tmp_path / "c.zarr"), spec=sources[0]).compute(),
         sum(ct.from_zarr(p, spec=sources[0]).compute() for p in sources[1]),

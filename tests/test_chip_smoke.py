@@ -57,6 +57,58 @@ def test_zarr_add_leg_small(tmp_path, compile_log, make_executor):
     _assert_clean(runs, ["add", "mean", "rechunk"])
 
 
+def _four_chip_executor():
+    import jax
+
+    return JaxExecutor(mesh=make_mesh(devices=jax.devices()[:4]))
+
+
+def test_mesh_zarr_add_leg_moves_every_chunk_to_and_from_its_owner(tmp_path, compile_log, capsys):
+    # the chip's leg in small: a 2 x 2 grid on four chips, one chunk a chip,
+    # a mean along an axis the mesh divides, and a rechunk whose target the
+    # segment lays out by column slab
+    runs = chip_smoke.zarr_add_leg(
+        80, 40, "200MB", seed=0, work_dir=str(tmp_path / "z"),
+        make_executor=_four_chip_executor, log=compile_log, name="mesh zarr_add",
+    )
+    _assert_clean(runs, ["add", "mean", "rechunk"])
+    chip_smoke.check_owner_io(runs)
+    for what, (cold, warm) in runs.items():
+        for run in (cold, warm):
+            assert run.stats["h2d_stream_bytes"] == run.stats["h2d_bytes"] > 0
+            # the mean's two chunks of 40 lie over four shards of 20
+            assert run.stats["mesh_gathered_bytes"] == (640 if what == "mean" else 0)
+            assert (run.stats["mesh_owner_bytes"] + run.stats["mesh_gathered_bytes"]
+                    == run.stats["h2d_bytes"] + run.stats["d2h_bytes"])
+    # more than the row that came back is a source that missed its owners
+    runs["mean"][1].stats["mesh_gathered_bytes"] = 641
+    with pytest.raises(RuntimeError, match=r"mean \(warm\).*'mesh_gathered_bytes': 641"):
+        chip_smoke.check_owner_io(runs)
+    assert "add (cold): mesh mesh_owner_bytes=153600 mesh_gathered_bytes=0" in capsys.readouterr().out
+
+
+def test_mesh_zarr_add_leg_fails_where_a_chunk_crossed_chips(tmp_path, compile_log):
+    # 90 rows in chunks of 40 on eight chips: a shard ends inside a chunk
+    runs = chip_smoke.zarr_add_leg(
+        90, 40, "200MB", seed=0, work_dir=str(tmp_path / "z"),
+        make_executor=_mesh_executor, log=compile_log,
+        computes=("add",), name="mesh zarr_add",
+    )
+    with pytest.raises(RuntimeError, match="did not move between the host and its owner"):
+        chip_smoke.check_owner_io(runs)
+    cold, warm = runs["add"]
+    streamed = dict(mesh_gathered_bytes=0, h2d_stream_bytes=cold.stats["h2d_bytes"])
+    cold.stats.update(streamed, mesh_owner_bytes=8)
+    warm.stats.update(streamed, mesh_owner_bytes=0)
+    with pytest.raises(RuntimeError, match=r"add \(warm\).*'mesh_owner_bytes': 0"):
+        chip_smoke.check_owner_io(runs)
+    warm.stats.update(mesh_owner_bytes=8, h2d_stream_bytes=0)
+    with pytest.raises(RuntimeError, match=r"add \(warm\).*'h2d_stream_bytes': 0"):
+        chip_smoke.check_owner_io(runs)
+    warm.stats.update(streamed)
+    chip_smoke.check_owner_io(runs)
+
+
 def test_measured_fails_when_an_op_left_the_device_path(compile_log):
     def compute(callbacks):
         class _Event:

@@ -1,14 +1,16 @@
 """A stored source goes to the device chunk by chunk through reused staging
 buffers.
 
-Without a mesh ``JaxExecutor._device_put`` walks a stored array's chunk grid
+``JaxExecutor._device_put`` walks a stored array's chunk grid
 (``_stream_to_device``): each chunk file is read into one of two host
 buffers that the process keeps and a compute leases, put on the device from
 there and written into its place in one resident array, updated in place. The device value is
 bit for bit what the whole-array route gives (the array assembled on the
 host, put in one piece), which a stored array still takes where HBM lacks the
-room, and which one chunk, a 0-d array, a record array and a mesh always
-take. The reads stay the store's: cancellation, injected faults, retries and
+room, and which one chunk, a 0-d array and a record array always take.
+Under a mesh the same walk sends each chunk to the chip that owns it, where
+every shard is a block of whole chunks (``tests/test_zarr_add_mesh.py``);
+any other layout is read shard by shard through the callback. The reads stay the store's: cancellation, injected faults, retries and
 breaker pacing, byte accounting, verification with quarantine."""
 
 from __future__ import annotations
@@ -266,21 +268,38 @@ def test_a_host_array_is_put_in_one_piece(tmp_path):
     assert executor.stats["h2d_stream_bytes"] == 0 and executor.stats["h2d_bytes"] == host.nbytes
 
 
-def test_under_a_mesh_the_shard_by_shard_callback_stays(tmp_path):
+@pytest.mark.parametrize(
+    "shape, streams",
+    # a 4 x 2 grid: a chunk-row a chip; a 3 x 3 grid: 12 rows divide by four
+    # chips and no side of the grid does, so a shard ends inside a chunk
+    [((16, 8), True), ((12, 12), False)],
+    ids=["chunk_aligned_streams_to_the_owners", "through_a_chunk_keeps_the_callback"],
+)
+def test_under_a_mesh_a_source_streams_where_every_chunk_has_one_owner(tmp_path, shape, streams):
     import jax
 
     from cubed_tpu.parallel.mesh import make_mesh
 
-    host = _values(np.float64, (16, 8))
+    host = _values(np.float64, shape)
     z = _stored(tmp_path, host, (4, 4))
     executor = JaxExecutor(mesh=make_mesh(devices=jax.devices()[:4]))
     with executor._lease() as staging:
         value = executor._device_put(z, tuple(z.shape), z.chunkset())
+        buffers = [stage.buffer for stage in staging]
     assert len(value.sharding.device_set) == 4
     assert np.asarray(value).tobytes() == host.tobytes()
-    assert executor.stats["h2d_stream_bytes"] == 0
-    assert "h2d_stream_declined" not in executor.stats
-    assert all(stage.buffer is None for stage in staging)
+    if streams:
+        # every chunk through the two leased buffers to the chip that owns it
+        assert executor.stats["h2d_stream_bytes"] == executor.stats["mesh_owner_bytes"] == host.nbytes
+        assert "h2d_stream_declined" not in executor.stats
+        assert executor.stats["mesh_gathered_bytes"] == 0
+        assert [buffer.nbytes for buffer in buffers] == [z._chunk_nbytes()] * 2
+    else:
+        # each shard assembled on the host by the callback, and counted
+        assert executor.stats["h2d_stream_bytes"] == 0 and executor.stats["mesh_owner_bytes"] == 0
+        assert executor.stats["h2d_stream_declined"] == 1
+        assert executor.stats["mesh_gathered_bytes"] == host.nbytes
+        assert buffers == [None, None]
 
 
 @pytest.mark.parametrize("short_by", [1, 4 * 4 * 8], ids=["a_byte", "a_chunk"])
@@ -322,7 +341,7 @@ def test_a_pair_device_needs_the_room_twice_for_64_bit_elements(
     needed = (z.nbytes + 2 * z._chunk_nbytes()) * (2 if twice else 1)
     for budget, streams in ((needed, True), (needed - 1, False)):
         executor = JaxExecutor(device_mem=budget)
-        assert executor._streams(z) is streams
+        assert (executor._streams(z, None) is not None) is streams
         assert executor.stats["h2d_stream_declined"] == int(not streams)
 
 
@@ -332,11 +351,11 @@ def test_a_pair_device_is_not_sent_a_64_bit_array_in_many_chunks(tmp_path, monke
     coarse = _stored(tmp_path, _values(np.float64, (64, 4)), (1, 4), name="coarse")
     narrow = _stored(tmp_path, _values(np.float32, (65, 4)), (1, 4), name="narrow")
     assert (fine.nchunks, coarse.nchunks) == (65, jx._PAIR_STREAM_MAX_CHUNKS)
-    assert all(JaxExecutor()._streams(z) for z in (fine, coarse, narrow))
+    assert all(JaxExecutor()._streams(z, None) for z in (fine, coarse, narrow))
     monkeypatch.setattr(jx, "_float64_round_trips", lambda device: False)
     executor = JaxExecutor()
-    assert not executor._streams(fine)
-    assert executor._streams(coarse) and executor._streams(narrow)
+    assert not executor._streams(fine, None)
+    assert executor._streams(coarse, None) and executor._streams(narrow, None)
     got, _ = _put(fine, executor)
     assert got.tobytes() == fine[...].tobytes()
     assert executor.stats["h2d_stream_bytes"] == 0
