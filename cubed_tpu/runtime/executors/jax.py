@@ -7,40 +7,46 @@ Design (SURVEY.md section 7; north star in BASELINE.json):
   and requested outputs are flushed at the end. Intermediates never hit
   storage (the reference pays a full storage round-trip per op).
 - **Streamed preload.** A stored source of several chunks is never
-  assembled on the host: each chunk file is read into one of two staging
-  buffers, put from there on the chip that owns the chunk and written into
-  its place in that chip's shard, which is updated in place
-  (``_stream_to_device``, ``_chunk_writer``), bit for bit what the put of
-  the whole array gives. Without a mesh the one owner is the default device
-  and its shard the array; under one, every shard has to be a block of whole
-  chunks on a chip of its own, and the shards are joined at the end. The
-  route is chosen from what ``_device_put`` observes (``_streams``);
-  ``stats["h2d_stream_bytes"]`` counts what went that way and
-  ``stats["h2d_stream_declined"]`` what found no room, or no owner.
+  assembled on the host: each chunk file is read into one of a pair of
+  staging buffers, put from there on the chip that owns the chunk and
+  written into its place in that chip's shard, which is updated in place
+  (``_stream_to_device``, ``_stream_lane``, ``_chunk_writer``), bit for bit
+  what the put of the whole array gives. That loop is a lane, and there is
+  one for each chip that owns chunks. Without a mesh the one owner is the
+  default device and its shard the array, and the lane runs on the calling
+  thread; under one, every shard has to be a block of whole chunks on a
+  chip of its own, every lane runs on a thread and through a pair of its
+  own (``_stream_lanes``), and the shards are joined at the end. The route
+  and the number of lanes are chosen from what ``_device_put`` observes
+  (``_streams``); ``stats["h2d_stream_bytes"]`` counts what went that way,
+  ``stats["h2d_lane_bytes"]`` the part of it that went on a lane thread,
+  and ``stats["h2d_stream_declined"]`` what found no room, or no owner.
 - **Under a mesh a chunk touches one chip.** On the way out a chunk is
   sliced from the shard that holds it (``_chunk_of``), so its slice, split
   and fetch run on that chip alone. ``stats["mesh_owner_bytes"]`` counts
   the bytes that moved between the host and exactly one chip, either way,
   ``stats["mesh_gathered_bytes"]`` those that touched several (a shard
   assembled by the callback, a chunk that crosses shards).
-- **The staging pair is the process's.** The two buffers outlive the
-  executor: ``execute_dag`` leases the one pair the process keeps
-  (``_leased_staging``) and gives it back when the compute ends, however
-  it ends, with no device update left on either buffer. A compute that
-  finds the pair leased (the service runs computes on many threads) works
-  with a fresh pair of its own and waits for nobody. So from a process's
-  second compute on no chunk is read into, or joined into, a page nobody
-  has touched: ``stats["stage_reused_bytes"]`` counts the streamed bytes
-  that passed through a buffer the compute found allocated. Between
-  computes the process holds two buffers of the largest chunk streamed so
-  far; ``release_staging_buffers()`` hands the pages back, and a forked
-  child starts with none.
+- **The staging pairs are the process's.** The buffers outlive the
+  executor: ``execute_dag`` leases a pair from those the process keeps
+  (``_leased_staging``), a source with several owners takes one more for
+  each further lane into the same lease, and all go back when the compute
+  ends, however it ends, with no device update left on any buffer. A
+  compute that finds the pool empty (the service runs computes on many
+  threads) works with a fresh pair of its own and waits for nobody. So
+  from a process's second compute on no chunk is read into, or joined
+  into, a page nobody has touched: ``stats["stage_reused_bytes"]`` counts
+  the streamed bytes that passed through a buffer the compute found
+  allocated. Between computes the process holds a pair a lane of its
+  widest compute so far, two buffers each of the largest chunk streamed
+  through it; ``release_staging_buffers()`` hands the pages back, and a
+  forked child starts with none.
 - **Streamed flush.** The way out is the mirror image
   (``_flush_chunks``): a chunk that leaves as planes is joined into one of
-  the same two buffers and written from there (the store hands the file a
-  view, ``ZarrV2Array._write_chunk``), by a second thread, while this one
-  fetches the next chunk into the other buffer; one writer, grid order, and
-  ``_flush`` returns when every chunk is durable.
+  the two buffers of the compute's first pair and written from there (the
+  store hands the file a view, ``ZarrV2Array._write_chunk``), by a second
+  thread, while this one fetches the next chunk into the other buffer; one
+  writer, grid order, and ``_flush`` returns when every chunk is durable.
   ``stats["flush_stream_bytes"]`` counts what reached the store that way
   and ``stats["encode_copy_bytes"]`` what the store had to copy.
 - **Whole-array fast path.** Ops whose kernel is shape-invariant (elementwise /
@@ -225,22 +231,23 @@ class _Resident:
 
 
 class _Staging:
-    """One of the two host buffers that a chunk passes through between the
-    store and the device: a streamed preload reads chunk files into them
-    (``JaxExecutor._stream_to_device``), and a flush joins the planes of a
-    chunk into them and writes the chunk file from there
-    (``JaxExecutor._flush_chunks``). ``busy`` is a result of the device
-    update that consumed the bytes now in ``buffer``: until it is ready the
-    buffer may still be read by the transfer (the put is asynchronous on
-    the TPU, and on the CPU backend a put value may alias the numpy
-    memory), so it is not written.
+    """One of a pair of host buffers that a chunk passes through between
+    the store and the device: a lane of a streamed preload reads its chip's
+    chunk files into its pair (``JaxExecutor._stream_lane``), and a flush
+    joins the planes of a chunk into the compute's first pair and writes
+    the chunk file from there (``JaxExecutor._flush_chunks``). ``busy`` is
+    a result of the device update that consumed the bytes now in
+    ``buffer``: until it is ready the buffer may still be read by the
+    transfer (the put is asynchronous on the TPU, and on the CPU backend a
+    put value may alias the numpy memory), so it is not written. One
+    thread at a time works with a pair: the lane's, or the calling one.
 
-    The buffers come in pairs, and a pair belongs to the process, not to
-    an executor: a compute leases it for the length of one ``execute_dag``
-    (``_leased_staging``), so that the pages a chunk is read into have
-    been touched by the compute before. ``kept`` says whether ``buffer``
-    is still the one the lease found: False once this compute had to make
-    it, or make it larger."""
+    A pair belongs to the process, not to an executor: a compute leases
+    one, and one more for each further lane of a source with several
+    owners, for the length of one ``execute_dag`` (``_leased_staging``), so
+    that the pages a chunk is read into have been touched by the compute
+    before. ``kept`` says whether ``buffer`` is still the one the lease
+    found: False once this compute had to make it, or make it larger."""
 
     __slots__ = ("buffer", "busy", "kept")
 
@@ -360,13 +367,15 @@ class JaxExecutor(DagExecutor):
 
     Notes
     -----
-    An executor keeps no host buffer between computes. The two chunk-sized
+    An executor keeps no host buffer between computes. The chunk-sized
     staging buffers of a streamed preload and flush are the process's: a
-    compute leases them for the length of ``execute_dag`` and the process
-    keeps them afterwards (two buffers of the largest chunk streamed so
-    far), so that the next compute, of this executor or another, reads into
-    pages already touched. ``release_staging_buffers()`` of this module
-    hands those pages back; nothing calls it automatically.
+    compute leases a pair of them (under a mesh a pair a chip, one for each
+    lane of a sharded preload) for the length of ``execute_dag`` and the
+    process keeps them afterwards (two buffers a pair of the largest chunk
+    streamed so far), so that the next compute, of this executor or
+    another, reads into pages already touched.
+    ``release_staging_buffers()`` of this module hands those pages back;
+    nothing calls it automatically.
     """
 
     def __init__(
@@ -416,7 +425,7 @@ class JaxExecutor(DagExecutor):
         #: what ``_leaves_as_planes`` reads the room for its planes from
         self._resident: Dict[str, _Resident] = {}
         self._spilling = False
-        #: the host memory of a streamed preload and of a flush: two
+        #: the host memory of a streamed preload and of a flush: a pair of
         #: chunk-sized buffers that take turns, for every source a compute
         #: loads and every array it stores. The pair is the process's and
         #: is here only while a compute runs: ``execute_dag`` leases it at
@@ -424,9 +433,13 @@ class JaxExecutor(DagExecutor):
         #: cancellation alike (``_lease``); a compute that finds it leased
         #: by another thread's gets a fresh pair of its own, dropped at the
         #: end. ``release_staging_buffers()`` hands the pages of the idle
-        #: pair back. One pair serves both ways: the executor's units run
-        #: one after another, so no flush runs while a stream holds a buffer
+        #: pairs back. One pair serves both ways: the executor's units run
+        #: one after another, so no flush runs while a stream holds a buffer.
+        #: ``_leased`` is all this compute holds: that pair first, then one
+        #: more for each further lane of a source with several owners
+        #: (``_lane_pairs``), given back together
         self._staging: Optional[Tuple[_Staging, _Staging]] = None
+        self._leased: Optional[list] = None
         self._prepared_bases: Dict[int, Any] = {}
         #: keys of the task events of this compute in the order they were
         #: fired, kept only while spans are recorded (see ``_task_end``)
@@ -452,13 +465,18 @@ class JaxExecutor(DagExecutor):
         #: device), ``h2d_bytes`` / ``d2h_bytes`` (bytes moved by ``_device_put``
         #: / ``_to_host``), ``h2d_stream_bytes`` (the part of ``h2d_bytes`` that
         #: went chunk by chunk through the staging buffers; 0, not absent,
-        #: where nothing did), ``flush_stream_bytes`` / ``encode_copy_bytes``
+        #: where nothing did), ``h2d_lane_bytes`` (the part of that which
+        #: reached its chip on a lane thread of that chip's own: all of it
+        #: where a source had several owners, 0, not absent, where the one
+        #: lane ran on the calling thread), ``flush_stream_bytes`` / ``encode_copy_bytes``
         #: (the part of ``d2h_bytes`` that reached the store from a staging
         #: buffer with no copy on the host after the join, and the bytes the
         #: store had to copy in a flush's writes; each 0, not absent),
         #: ``write_wait_us`` / ``stage_wait_us`` (microseconds this thread
-        #: was blocked on the flush's writer thread, and on a device update
-        #: that still read a staging buffer, in ``_stream_to_device``),
+        #: was blocked on the flush's writer thread, and microseconds a
+        #: lane of ``_stream_to_device`` was blocked on a device update
+        #: that still read a staging buffer, summed over the lanes'
+        #: threads where there are several),
         #: ``preload_page_faults`` (the pages the preloads made resident:
         #: the growth of the process's resident set over each: the staging
         #: buffers in a process's first compute, 0 from its second on; the
@@ -680,12 +698,15 @@ class JaxExecutor(DagExecutor):
         if as_bits:
             self.stats["f64_as_bits"] += 1
 
-        def transferred(data):
+        def transferred(data, stats=self.stats):
+            """``data`` as it goes to the device, counted into ``stats`` (a
+            lane of ``_stream_to_device`` on a thread of its own counts into
+            its own)."""
             data = np.asarray(data)
-            self.stats["h2d_bytes"] += data.nbytes
+            stats["h2d_bytes"] += data.nbytes
             if not as_bits:
                 return data
-            self.stats["h2d_bits_bytes"] += data.nbytes
+            stats["h2d_bits_bytes"] += data.nbytes
             return data.view(np.uint64)
 
         sharding = self._sharding_for(shape, chunkset)
@@ -708,13 +729,15 @@ class JaxExecutor(DagExecutor):
             self._count_mesh_io(out, sp)
         return out
 
-    def _count_mesh_io(self, value, sp) -> None:
+    def _count_mesh_io(self, value, sp, stats=None) -> None:
         """One transfer's bytes on the mesh's two counters, and the chip on
         the transfer's span. ``value`` is what lies on the device: on one
         chip, it moved between the host and exactly that chip
         (``mesh_owner_bytes``, and the span's ``device``); on several, it
         was assembled for them or gathered from them
-        (``mesh_gathered_bytes``). Without a mesh neither counts."""
+        (``mesh_gathered_bytes``). Without a mesh neither counts. ``stats``
+        is the counter of a preload lane that runs on a thread of its own,
+        else ``self.stats``."""
         sharding = getattr(value, "sharding", None)
         if sharding is None:  # a host value that an eager op left resident
             return
@@ -723,7 +746,7 @@ class JaxExecutor(DagExecutor):
         if one_chip:
             sp.attrs["device"] = next(iter(devices)).id
         if self.mesh is not None:
-            self.stats[
+            (self.stats if stats is None else stats)[
                 "mesh_owner_bytes" if one_chip else "mesh_gathered_bytes"
             ] += _value_nbytes(value)
 
@@ -781,47 +804,67 @@ class JaxExecutor(DagExecutor):
         return owners
 
     def _stream_to_device(self, stored, transferred, sharding, owners):
-        """``stored`` on the device without a copy of it on the host: each
-        chunk file is read into one of the two staging buffers this compute
-        has leased (``_lease``), put on the chip that owns the chunk from
-        there and written into its place in that chip's shard, which is
-        allocated once and updated in place (``_chunk_writer``). Without a
-        sharding there is one owner and its shard is the array; under one
-        (``owners``, from ``_streams``) the shards are joined at the end
-        into one array of that sharding, and the chunks are taken a chip in
-        turn, so that one chip's update runs while the next chip's chunk is
-        read and put.
+        """``stored`` on the device without a copy of it on the host: one
+        lane (``_stream_lane``) for each chip that owns chunks of it, as
+        ``owners`` says (from ``_streams``), observed in the call. One
+        owner, which is every source without a sharding: the lane runs here,
+        on the calling thread, through the pair this compute leased first
+        (``_lease``); its shard is the array. Several: every lane runs on a
+        thread of its own through a pair of its own (``_stream_lanes``), so
+        that no chip's reads, puts and waits stand in line behind
+        another's, and the shards are joined at the end, in the owners'
+        order, into one array of ``sharding``. ``transferred`` is
+        ``_device_put``'s: the byte count and the bit-pattern view."""
+        # a chip's chunks in grid order, the chips in the owners' order
+        queues: Dict[Any, list] = {}
+        for idx, (device, _) in owners.items():
+            queues.setdefault(device, []).append(idx)
+        if len(queues) == 1:
+            (chunks,) = queues.values()
+            shards = [
+                self._stream_lane(
+                    stored, transferred, owners, chunks, self._staging, self.stats
+                )
+            ]
+        else:
+            shards = self._stream_lanes(
+                stored, transferred, owners, list(queues.values())
+            )
+        if sharding is None:
+            (whole,) = shards
+            return whole
+        return _jax().make_array_from_single_device_arrays(
+            tuple(stored.shape), sharding, shards
+        )
+
+    def _stream_lane(self, stored, transferred, owners, chunks, pair, stats):
+        """One chip's ``chunks`` of ``stored`` (all of one owner in
+        ``owners``, in grid order) onto that chip; its shard. Each chunk
+        file is read into one of the two staging buffers of ``pair``, put on
+        the chip from there and written into its place in the shard, which
+        is allocated once and updated in place (``_chunk_writer``).
 
         The buffers take turns, so the read of chunk k + 1 overlaps the
         transfer and update of chunk k; chunk k's span ends with the wait
         for chunk k - 1's update, which frees the buffer that chunk k + 1 is
-        read into (the span's ``wait_us``). A source's last update is
-        waited for by whichever read needs its buffer next: the next
-        source's, here, or a flush's join, in its ``jax.d2h``. The waits
-        made here, and no other, are ``stats["stage_wait_us"]``: where they
-        are most of the ``jax.h2d`` spans' time the device's update paces
-        the stream, else the read does. ``transferred`` is
-        ``_device_put``'s: the byte count and the bit-pattern view."""
+        read into (the span's ``wait_us``). A lane's last update is waited
+        for by whichever read needs its buffer next: the next source's
+        lane through this pair, here, or a flush's join, in its
+        ``jax.d2h``. The waits made here, and no other, are
+        ``stats["stage_wait_us"]``: where they are most of the ``jax.h2d``
+        spans' time the device's update paces the lane, else the read does.
+        ``stats`` is what the lane counts into: the compute's, or a lane
+        thread's own."""
         jax = _jax()
         chunk_nbytes = stored._chunk_nbytes()
         write = _chunk_writer()
         chunkset = stored.chunkset()
-        # grid order chip by chip, then one chunk of each chip in turn
-        queues: Dict[Any, list] = {}
-        for idx, (device, _) in owners.items():
-            queues.setdefault(device, []).append(idx)
-        order = [
-            idx
-            for turn in itertools.zip_longest(*queues.values())
-            for idx in turn
-            if idx is not None
-        ]
-        shards: Dict[Any, Any] = {}
-        for k, idx in enumerate(order):
+        shard = None
+        for k, idx in enumerate(chunks):
             device, bounds = owners[idx]
-            stage, other = self._staging[k % 2], self._staging[1 - k % 2]
+            stage, other = pair[k % 2], pair[1 - k % 2]
             # the source before may have left its last update on this one
-            self.stats["stage_wait_us"] += stage.release()
+            stats["stage_wait_us"] += stage.release()
             chunk = stored._read_chunk_into(idx, stage.sized(chunk_nbytes))
             if chunk is None:
                 chunk = stored._empty_chunk()
@@ -831,26 +874,110 @@ class JaxExecutor(DagExecutor):
             start = tuple(s.start - b[0] for s, b in zip(sel, bounds))
             extent = tuple(s.stop - s.start for s in sel)
             with scope_span("jax.h2d", cat="transfer") as sp:
-                piece = jax.device_put(transferred(chunk), device)
-                if device not in shards:
-                    shards[device] = jax.numpy.zeros(
+                piece = jax.device_put(transferred(chunk, stats), device)
+                if shard is None:
+                    shard = jax.numpy.zeros(
                         tuple(b[1] - b[0] for b in bounds), piece.dtype, device=device
                     )
-                shards[device], stage.busy = write(
-                    shards[device], piece, np.asarray(start, np.int32), extent
+                shard, stage.busy = write(
+                    shard, piece, np.asarray(start, np.int32), extent
                 )
                 sp.attrs["bytes"] = piece.nbytes
-                self._count_mesh_io(piece, sp)
+                self._count_mesh_io(piece, sp, stats)
                 waited = sp.attrs["wait_us"] = other.release()
-            self.stats["stage_wait_us"] += waited
-            self.stats["h2d_stream_bytes"] += piece.nbytes
-            self.stats["stage_reused_bytes"] += piece.nbytes if stage.kept else 0
-        if sharding is None:
-            (whole,) = shards.values()
-            return whole
-        return jax.make_array_from_single_device_arrays(
-            tuple(stored.shape), sharding, list(shards.values())
-        )
+            stats["stage_wait_us"] += waited
+            stats["h2d_stream_bytes"] += piece.nbytes
+            stats["stage_reused_bytes"] += piece.nbytes if stage.kept else 0
+        return shard
+
+    def _stream_lanes(self, stored, transferred, owners, queues) -> list:
+        """The lanes of a source with several owners, lane n (``queues[n]``,
+        one chip's chunks) on a thread of its own
+        (``cubed-tpu-preload-<n>``) through the nth pair this compute has
+        leased (``_lane_pairs``); the shards, in the lanes' order, once
+        every thread has ended.
+
+        A lane's thread finds the compute's cancellation token and the
+        injected faults through a copy of this thread's context, traces and
+        dispatches under this thread's jax configuration (``execute_dag``
+        sets x64 and the matmul precision thread-locally: a lane that did
+        not take them over would put a float64 where the compute carries
+        float32) with its chip as the default device (``zeros`` fills an
+        array on the default device and copies it to the one asked for:
+        800 MB a shard through the first chip, for every lane at once), and
+        works in a task scope and a counter of its own, since
+        spans and byte counts find theirs through the calling thread and
+        ``+=`` on a shared ``Counter`` loses counts. Both are folded into
+        this thread's here, lane by lane, so ``stage_wait_us`` and the
+        ``jax.h2d`` and ``storage_read`` spans are sums over the threads
+        that waited and read. ``stats["h2d_lane_bytes"]`` counts the bytes
+        that reached their chip on such a thread (0 where the one lane ran
+        on the calling thread). The first error of any lane, a cancellation
+        among them, is raised from here after every lane has ended: no
+        chunk is started after it and no thread is left."""
+        jax = _jax()
+        pairs = self._lane_pairs(len(queues))
+        outer = current_scope()
+        x64 = jax.config.jax_enable_x64
+        precision = jax.config.jax_default_matmul_precision
+        failed: list = []  # what the lanes raised, in the order they did
+        ended: list = [None] * len(queues)
+
+        def lane(n):
+            """On lane n's thread: (its shard, its counters, its scope)."""
+            scoped = (
+                task_scope(_SCOPE_SPANS) if outer is not None
+                else contextlib.nullcontext()
+            )
+            counted: Counter = Counter()
+            shard = inner = None
+            chip = owners[queues[n][0]][0]
+            try:
+                with scoped as inner, jax.enable_x64(x64), \
+                        jax.default_matmul_precision(precision), \
+                        jax.default_device(chip):
+                    shard = self._stream_lane(
+                        stored, transferred, owners,
+                        itertools.takewhile(lambda _: not failed, queues[n]),
+                        pairs[n], counted,
+                    )
+            except BaseException as error:  # raised again below
+                failed.append(error)
+            ended[n] = (shard, counted, inner)
+
+        threads = [
+            threading.Thread(
+                target=contextvars.copy_context().run, args=(lane, n),
+                name=f"cubed-tpu-preload-{n}",
+            )
+            for n in range(len(queues))
+        ]
+        # every thread that started is waited for, however this block ends
+        with contextlib.ExitStack() as joined:
+            try:
+                for thread in threads:
+                    thread.start()
+                    joined.callback(thread.join)
+            except BaseException as error:
+                failed.append(error)  # the lanes that run start no further chunk
+                raise
+        for _, counted, inner in ended:
+            self.stats.update(counted)
+            self.stats["h2d_lane_bytes"] += counted["h2d_stream_bytes"]
+            if inner is not None:
+                outer.fold(inner)
+        if failed:
+            raise failed[0]
+        return [shard for shard, _, _ in ended]
+
+    def _lane_pairs(self, lanes: int) -> list:
+        """The first ``lanes`` staging pairs of this compute's lease, taking
+        from the process's pool (or making) those it does not hold yet: they
+        stay leased until the lease ends, so a compute's second source finds
+        the pairs its first took. The first is ``self._staging``."""
+        while len(self._leased) < lanes:
+            self._leased.append(_take_staging())
+        return self._leased[:lanes]
 
     def _to_host(self, value, dtype, stage: Optional[_Staging] = None) -> np.ndarray:
         """Device -> host: a device value (or dict of record fields) as a
@@ -994,15 +1121,17 @@ class JaxExecutor(DagExecutor):
 
     @contextlib.contextmanager
     def _lease(self) -> Iterator[Tuple[_Staging, _Staging]]:
-        """The staging pair in this executor's hands for the length of the
+        """A staging pair in this executor's hands for the length of the
         block: one ``execute_dag``, or what drives ``_device_put`` or
-        ``_flush`` without one (``chip_smoke.py``, the tests). Outside it
-        the executor holds no host buffer."""
-        with _leased_staging() as self._staging:
+        ``_flush`` without one (``chip_smoke.py``, the tests). A source with
+        several owners takes a pair a lane into the same lease
+        (``_lane_pairs``). Outside it the executor holds no host buffer."""
+        with _leased_staging() as self._leased:
+            self._staging = self._leased[0]
             try:
                 yield self._staging
             finally:
-                self._staging = None
+                self._staging = self._leased = None
 
     def _execute_dag_inner(
         self,
@@ -1019,6 +1148,7 @@ class JaxExecutor(DagExecutor):
             dict.fromkeys((*_MESH_COUNTERS, *_FLOAT_BYTES.values()), 0),
             mesh_devices=0 if self.mesh is None else self.mesh.devices.size,
             h2d_stream_bytes=0,
+            h2d_lane_bytes=0,
             flush_stream_bytes=0,
             stage_reused_bytes=0,
             encode_copy_bytes=0,
@@ -2862,55 +2992,93 @@ _STRUCT_DEBUG: Optional[list] = None
 #: evict an entry a sibling just read or resurrect one past the bound
 _CACHE_LOCK = threading.Lock()
 
-#: the process's pair of staging buffers while no compute has it: empty, or
-#: the one pair. It outlives executors as the program caches do, so that a
-#: compute reads its chunks into pages the compute before has touched (a
-#: fresh 200 MB buffer costs a read 0.15 s more on the v5e's host, PERF.md
-#: section 6, PRs 36 and 37). What it keeps from the process between
-#: computes is two buffers of the largest chunk streamed so far
+#: the process's staging pairs while no compute has them: none, or as many
+#: as the widest compute so far has leased at once (one, but under a mesh,
+#: where a streamed preload runs a lane a chip, each through a pair of its
+#: own: ``_POOL_WIDTH``). They outlive executors as the program caches do,
+#: so that a compute reads its chunks into pages the compute before has
+#: touched (a fresh 200 MB buffer costs a read 0.15 s more on the v5e's
+#: host, PERF.md section 6, PRs 36 and 37). What they keep from the process
+#: between computes is two buffers a pair of the largest chunk streamed
+#: through it so far
 _STAGING_POOL: list = []
 
+#: the most pairs the pool keeps: the most one compute has leased at once
+#: since the process started or last called ``release_staging_buffers``
+_POOL_WIDTH = 1
 
-@contextlib.contextmanager
-def _leased_staging() -> Iterator[Tuple[_Staging, _Staging]]:
-    """The process's staging pair for the length of the block, or a fresh
-    pair where another thread's compute has it: concurrent computes never
-    share a buffer and never wait for one another. At the end, however the
-    block ends, both buffers' device updates are waited out and let go of,
-    so that no device value outlives its compute here and the next
-    lessee's first ``release`` costs nothing; then the pair goes back if
-    the pool is empty, and is dropped otherwise (the pool never holds more
-    than one). A pair whose update raises is not given back."""
+
+def _take_staging() -> Tuple[_Staging, _Staging]:
+    """A staging pair out of the process's pool, or a fresh one where the
+    pool has none left (another thread's compute has them, or no compute was
+    as wide yet): concurrent computes never share a buffer and never wait
+    for one another. Whoever takes one gives it back through the lease it
+    belongs to (``_leased_staging``)."""
     with _CACHE_LOCK:
         pair = _STAGING_POOL.pop() if _STAGING_POOL else None
     if pair is None:
         pair = (_Staging(), _Staging())
     for stage in pair:
         stage.kept = stage.buffer is not None
+    return pair
+
+
+@contextlib.contextmanager
+def _leased_staging() -> Iterator[list]:
+    """The staging pairs of one compute for the length of the block: a list
+    that starts with one pair (``_take_staging``) and to which the lessee
+    appends what more it takes (a pair a lane, ``JaxExecutor._lane_pairs``).
+    At the end, however the block ends, every buffer's device update is
+    waited out and let go of, so that no device value outlives its compute
+    here and the next lessee's first ``release`` costs nothing; then the
+    pairs go back, as many as bring the pool to the width of the widest
+    compute so far, and the rest are dropped (a compute that found the pool
+    taken by another thread's). A pair whose update raises is not given
+    back; the first such error is raised once the others are."""
+    global _POOL_WIDTH
+    pairs = [_take_staging()]
     try:
-        yield pair
+        yield pairs
     finally:
-        for stage in pair:
-            stage.release()
+        sound, errors = [], []
+        for pair in pairs:
+            try:
+                for stage in pair:
+                    stage.release()
+                sound.append(pair)
+            except BaseException as error:
+                errors.append(error)
         with _CACHE_LOCK:
-            if not _STAGING_POOL:
-                _STAGING_POOL.append(pair)
+            _POOL_WIDTH = max(_POOL_WIDTH, len(pairs))
+            room = _POOL_WIDTH - len(_STAGING_POOL)  # never under 0
+            # the first leased goes in last, and comes out first
+            _STAGING_POOL.extend(reversed(sound[:room]))
+        if errors:
+            raise errors[0]
+
+
+def _drop_staging_pool() -> None:
+    global _POOL_WIDTH
+    _STAGING_POOL.clear()
+    _POOL_WIDTH = 1
 
 
 def release_staging_buffers() -> None:
-    """Hand the pages of the idle staging pair back to the system (two
-    buffers of the largest chunk this process has streamed; 400 MB after a
-    compute over 200 MB chunks). Nothing calls it: a process that computes
-    again wants them kept. A pair that a running compute has leased is not
-    touched, and comes back to the pool when that compute ends."""
+    """Hand the pages of the idle staging pairs back to the system (two
+    buffers a pair of the largest chunk streamed through it; 400 MB after a
+    compute over 200 MB chunks on one chip, 1.6 GB after one under a mesh of
+    four), and start the pool's width over. Nothing calls it: a process
+    that computes again wants them kept. Pairs that a running compute has
+    leased are not touched, and come back to the pool when that compute
+    ends."""
     with _CACHE_LOCK:
-        _STAGING_POOL.clear()
+        _drop_staging_pool()
 
 
 # a forked child starts with no pair: the pages would be copied on its first
 # write, and ``multiprocess.py`` gives the accelerator to one process at a
 # time. No lock: the thread that held it may not exist in the child
-os.register_at_fork(after_in_child=_STAGING_POOL.clear)
+os.register_at_fork(after_in_child=_drop_staging_pool)
 
 
 #: the dtypes that ``_to_host`` can fetch as two 32-bit planes. complex128
@@ -3164,12 +3332,16 @@ def _float64_round_trips(device) -> bool:
 
     Observed, not assumed from the platform's name: a few values that need
     all 53 significand bits or float64's exponent range go to the device
-    and back once per kind of device."""
+    and back once per kind of device, as float64 whatever the calling
+    thread's x64 setting (a compute under ``compute_dtype="float32"`` would
+    else put them as float32 and leave that answer for every later one)."""
     key = (device.platform, device.device_kind)
     known = _FLOAT64_ROUND_TRIPS.get(key)
     if known is None:
+        jax = _jax()
         probe = np.array([np.pi, 1.0 + 2.0**-52, 1e300, 1e-300])
-        back = np.asarray(_jax().device_put(probe, device))
+        with jax.enable_x64(True):
+            back = np.asarray(jax.device_put(probe, device))
         known = back.tobytes() == probe.tobytes()
         _FLOAT64_ROUND_TRIPS[key] = known
     return known

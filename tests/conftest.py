@@ -39,6 +39,27 @@ def spec(tmp_path):
     return ct.Spec(work_dir=str(tmp_path), allowed_mem="500MB", reserved_mem=0)
 
 
+@pytest.fixture(autouse=True)
+def _memory_guard_as_found():
+    """Every test ends with the memory guard it found. ``Plan.execute`` arms
+    the guard by save and restore of a process global and its environment
+    variable (``memory.scoped``), which two computes at once interleave
+    (PERF.md section 7): a test that computes on several threads can leave
+    an ``observe`` configuration behind, and that then overrides
+    ``memory_guard="enforce"`` in every later test of the same worker
+    (``runtime/test_memory_guard.py`` failed so, by the files a worker
+    happened to be given)."""
+    from cubed_tpu.runtime import memory
+
+    active, env = memory._active, os.environ.get(memory.MEMORY_GUARD_ENV_VAR)
+    yield
+    memory._active = active
+    if env is None:
+        os.environ.pop(memory.MEMORY_GUARD_ENV_VAR, None)
+    else:
+        os.environ[memory.MEMORY_GUARD_ENV_VAR] = env
+
+
 @pytest.fixture
 def invariant_audit():
     """Post-hoc exactly-once audit over whatever durable artifacts a test's

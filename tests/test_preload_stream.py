@@ -4,12 +4,14 @@ buffers.
 ``JaxExecutor._device_put`` walks a stored array's chunk grid
 (``_stream_to_device``): each chunk file is read into one of two host
 buffers that the process keeps and a compute leases, put on the device from
-there and written into its place in one resident array, updated in place. The device value is
+there and written into its place in one resident array, updated in place: a
+lane, on the calling thread where the source has one owner. The device value is
 bit for bit what the whole-array route gives (the array assembled on the
 host, put in one piece), which a stored array still takes where HBM lacks the
 room, and which one chunk, a 0-d array and a record array always take.
 Under a mesh the same walk sends each chunk to the chip that owns it, where
-every shard is a block of whole chunks (``tests/test_zarr_add_mesh.py``);
+every shard is a block of whole chunks, a lane a chip, each on a thread and
+through a pair of its own (``tests/test_zarr_add_mesh.py``);
 any other layout is read shard by shard through the callback. The reads stay the store's: cancellation, injected faults, retries and
 breaker pacing, byte accounting, verification with quarantine."""
 
@@ -289,8 +291,10 @@ def test_under_a_mesh_a_source_streams_where_every_chunk_has_one_owner(tmp_path,
     assert len(value.sharding.device_set) == 4
     assert np.asarray(value).tobytes() == host.tobytes()
     if streams:
-        # every chunk through the two leased buffers to the chip that owns it
+        # every chunk to the chip that owns it, on a lane thread of that chip's
+        # (the first lane's pair is the one the lease began with)
         assert executor.stats["h2d_stream_bytes"] == executor.stats["mesh_owner_bytes"] == host.nbytes
+        assert executor.stats["h2d_lane_bytes"] == host.nbytes
         assert "h2d_stream_declined" not in executor.stats
         assert executor.stats["mesh_gathered_bytes"] == 0
         assert [buffer.nbytes for buffer in buffers] == [z._chunk_nbytes()] * 2
@@ -345,6 +349,18 @@ def test_a_pair_device_needs_the_room_twice_for_64_bit_elements(
         assert executor.stats["h2d_stream_declined"] == int(not streams)
 
 
+def test_whether_float64_round_trips_is_the_devices_and_not_the_threads_x64(monkeypatch):
+    """Asked for the first time inside a compute under
+    ``compute_dtype="float32"`` (x64 off for that thread), the probe still
+    goes as float64: the answer is kept for the process."""
+    import jax
+
+    monkeypatch.setattr(jx, "_FLOAT64_ROUND_TRIPS", {})
+    with jax.enable_x64(False):
+        assert jx._float64_round_trips(jax.devices()[0]) is True
+    assert list(jx._FLOAT64_ROUND_TRIPS.values()) == [True]
+
+
 def test_a_pair_device_is_not_sent_a_64_bit_array_in_many_chunks(tmp_path, monkeypatch):
     """Every update of a 64-bit array is a pass over all of it there."""
     fine = _stored(tmp_path, _values(np.float64, (65, 4)), (1, 4), name="fine")
@@ -395,6 +411,8 @@ def test_a_compute_streams_every_source_and_says_so(sources, tmp_path):
     np.testing.assert_array_equal(got, a + b)
     padded = 2 * 4 * 3 * 8 * 8 * 8
     assert cap.stats["h2d_stream_bytes"] == cap.stats["h2d_bytes"] == padded
+    # one owner: the lane ran on the calling thread
+    assert "h2d_lane_bytes" in cap.stats and cap.stats["h2d_lane_bytes"] == 0
     assert not cap.stats.get("h2d_stream_declined")
     assert cap.stats["bytes_read"] == padded and cap.stats["chunks_read"] == 24
     # both sources went through the same two buffers, each one chunk large,
@@ -440,6 +458,8 @@ def test_the_counter_is_present_and_zero_where_nothing_streamed(tmp_path):
     assert "h2d_stream_bytes" in cap.stats and cap.stats["h2d_stream_bytes"] == 0
     assert "stage_reused_bytes" in cap.stats and cap.stats["stage_reused_bytes"] == 0
     assert type(cap.stats["stage_reused_bytes"]) is int
+    assert "h2d_lane_bytes" in cap.stats and cap.stats["h2d_lane_bytes"] == 0
+    assert type(cap.stats["h2d_lane_bytes"]) is int
 
 
 # -- the reads are still the store's ----------------------------------------------
@@ -578,9 +598,10 @@ def test_two_buffers_take_turns_across_chunks_and_sources(tmp_path, monkeypatch)
 
 
 def test_the_buffers_grow_to_the_largest_chunk_seen_and_stay_with_the_process(tmp_path):
-    """The pool holds one pair, whatever executor streams: it grows to the
-    largest chunk seen (a larger buffer replaces the smaller, never adds),
-    outlives the executors, and goes when the process says so."""
+    """The pool holds one pair where no compute ran more than one lane,
+    whatever executor streams: it grows to the largest chunk seen (a larger
+    buffer replaces the smaller, never adds), outlives the executors, and
+    goes when the process says so."""
     small = _stored(tmp_path, _values(np.float64, (8, 8)), (4, 4), name="small")
     large = _stored(tmp_path, _values(np.float64, (16, 16)), (8, 8), name="large")
     assert jx._STAGING_POOL == []
@@ -758,6 +779,49 @@ def test_stage_wait_us_rises_when_the_device_update_is_held(tmp_path, monkeypatc
         # the wait has no span of its own: ``h2d_s`` is the put's self time
         assert not [s for s in scope.spans if s.get("parent") in {p["id"] for p in puts}]
         assert {s["name"] for s in scope.spans} == {"jax.h2d", "storage_read"}
+
+
+@pytest.mark.parametrize("where", ["ahead_of_a_read", "inside_h2d"])
+def test_under_a_mesh_a_held_update_is_waited_for_on_the_lane_whose_pair_it_holds(
+    tmp_path, monkeypatch, where
+):
+    """A lane waits for its own pair and for no other's: the held update
+    shows in ``stage_wait_us``, the sum over the threads that waited, and
+    where it falls inside a chunk's ``jax.h2d`` in that lane's span alone,
+    which the fold marks with the lane's thread."""
+    import jax
+
+    from cubed_tpu.parallel.mesh import make_mesh
+
+    held_us, lane = 20_000, 2
+    host = _values(np.float64, (16, 8))
+    z = _stored(tmp_path, host, (4, 4))  # a 4 x 2 grid: two chunks a chip
+    executor = JaxExecutor(mesh=make_mesh(devices=jax.devices()[:4]))
+    with executor._lease():
+        _put(z, executor)  # compiled, every lane's buffers made
+        quick = executor.stats["stage_wait_us"]
+        assert quick < held_us and len(executor._leased) == 4
+        # a lane's first chunk is read into its first buffer and waits, inside
+        # its span, for its second
+        executor._leased[lane][0 if where == "ahead_of_a_read" else 1].busy = _Held(held_us / 1e6)
+        monkeypatch.setenv(SPANS_ENV_VAR, "1")
+        with task_scope(jx._SCOPE_SPANS) as scope:
+            got, _ = _put(z, executor)
+        assert got.tobytes() == host.tobytes()
+        assert executor.stats["stage_wait_us"] >= quick + held_us
+        puts = [s for s in scope.spans if s["name"] == "jax.h2d"]
+        assert len(puts) == 8 and all(type(s["attrs"]["wait_us"]) is int for s in puts)
+        assert {s["attrs"]["thread"] for s in puts} == {f"cubed-tpu-preload-{n}" for n in range(4)}
+        waited = {s["attrs"]["thread"] for s in puts if s["attrs"]["wait_us"] >= held_us}
+        assert waited == ({f"cubed-tpu-preload-{lane}"} if where == "inside_h2d" else set())
+        # each lane's spans name one chip, and the four lanes four
+        chips = {}
+        for s in puts:
+            chips.setdefault(s["attrs"]["thread"], set()).add(s["attrs"]["device"])
+        assert all(len(c) == 1 for c in chips.values())
+        assert len(set().union(*chips.values())) == 4
+        assert sorted(s["name"] for s in scope.spans) == ["jax.h2d"] * 8 + ["storage_read"] * 8
+        assert scope.spans_dropped == 0 and scope.chunks_read == 8 and scope.bytes_read == host.nbytes
 
 
 def test_resident_pages_sees_fresh_pages_touched():

@@ -4,8 +4,10 @@ The deployment ``zarr-add-mesh4`` at a small size on four of the suite's
 virtual CPU devices: ``to_zarr(add(from_zarr, from_zarr))`` over a 4 x 4 grid
 of chunks under ``JaxExecutor(mesh=...)``, where ``sharding_for_chunks`` gives
 every chip one chunk-row. Every chunk of a source is read into a leased
-staging buffer and put on the chip that owns it (``_stream_to_device``), and
-every chunk of the target is sliced, split and fetched on its owner
+staging buffer and put on the chip that owns it (``_stream_to_device``), a
+lane a chip: that chip's chunks in grid order, on a thread of the lane's own
+through a staging pair of its own (without a mesh the one lane runs on the
+calling thread). Every chunk of the target is sliced, split and fetched on its owner
 (``_flush_chunks``, ``_chunk_of``); ``mesh_owner_bytes`` counts those bytes
 and ``mesh_gathered_bytes`` the ones that touched more than one chip. The
 target is read back with numpy alone. ``mean(a + b, axis=0)`` over the same
@@ -17,6 +19,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import zlib
 
@@ -203,18 +206,66 @@ def test_the_streamed_value_is_the_callbacks_and_numpys_bit_for_bit(
 
 
 def test_both_staging_buffers_are_taken_and_the_next_source_finds_them(tmp_path):
+    """Of every lane's pair: a compute under a mesh of four leases four, the
+    process keeps four, and the next compute's lanes find each their own."""
     z = _stored(tmp_path / "a.zarr", RNG.standard_normal(SHAPE))
-    first, second = JaxExecutor(mesh=_mesh()), JaxExecutor(mesh=_mesh())
+    first, second, plain = JaxExecutor(mesh=_mesh()), JaxExecutor(mesh=_mesh()), JaxExecutor()
     with leased_staging(first) as staging:
         first._device_put(z, tuple(z.shape), z.chunkset())
-        buffers = [stage.buffer for stage in staging]
-    assert [buffer.nbytes for buffer in buffers] == [z._chunk_nbytes()] * 2
-    assert first.stats["stage_reused_bytes"] == 0  # it had to make them
+        pairs = list(first._leased)
+        # the second source of a compute streams through the pairs of the first
+        first._device_put(z, tuple(z.shape), z.chunkset())
+        assert first._leased == pairs
+    assert len(pairs) == 4 and pairs[0] is staging
+    buffers = [stage.buffer for pair in pairs for stage in pair]
+    assert [buffer.nbytes for buffer in buffers] == [z._chunk_nbytes()] * 8
+    assert len({buffer.ctypes.data for buffer in buffers}) == 8
+    # the first source had to make them, the second found them
+    assert first.stats["stage_reused_bytes"] == 0
+    assert first.stats["h2d_stream_bytes"] == first.stats["h2d_lane_bytes"] == 2 * z.nbytes
+    assert sorted(map(id, jx._STAGING_POOL)) == sorted(map(id, pairs))
     with leased_staging(second) as staging:
         second._device_put(z, tuple(z.shape), z.chunkset())
-        assert all(stage.buffer is buffer for stage, buffer in zip(staging, buffers))
+        # lane for lane the pair it had, and no fresh buffer
+        assert staging is pairs[0] and jx._STAGING_POOL == []
+        assert all(ours is theirs for ours, theirs in zip(second._leased, pairs))
+        assert [stage.buffer for pair in second._leased for stage in pair] == buffers
     assert second.stats["stage_reused_bytes"] == second.stats["h2d_stream_bytes"] == z.nbytes
+    assert second.stats["h2d_lane_bytes"] == z.nbytes
     assert type(second.stats["stage_wait_us"]) is int and "preload_page_faults" not in second.stats
+    # a compute of one lane takes exactly one pair, the first, and returns it
+    with leased_staging(plain) as staging:
+        assert staging is pairs[0] and len(jx._STAGING_POOL) == 3
+        plain._device_put(z, tuple(z.shape), z.chunkset())
+        assert plain._leased == [pairs[0]] and len(jx._STAGING_POOL) == 3
+    assert plain.stats["stage_reused_bytes"] == plain.stats["h2d_stream_bytes"] == z.nbytes
+    assert plain.stats["h2d_lane_bytes"] == 0
+    assert sorted(map(id, jx._STAGING_POOL)) == sorted(map(id, pairs))
+    # the process lets go of all of them at once, and the width starts over
+    jx.release_staging_buffers()
+    assert jx._STAGING_POOL == []
+    with leased_staging(JaxExecutor()) as mine, leased_staging(JaxExecutor()) as yours:
+        assert mine is not yours
+    assert jx._STAGING_POOL == [yours]  # the first to end; the pool is one wide again
+
+
+def test_a_pair_whose_update_raised_is_not_given_back(tmp_path):
+    class _Lost:
+        def block_until_ready(self):
+            raise RuntimeError("the device is gone, says the test")
+
+    z = _stored(tmp_path / "a.zarr", RNG.standard_normal(SHAPE))
+    executor = JaxExecutor(mesh=_mesh())
+    with pytest.raises(RuntimeError, match="the device is gone"):
+        with leased_staging(executor):
+            executor._device_put(z, tuple(z.shape), z.chunkset())
+            pairs = list(executor._leased)
+            pairs[2][1].release()
+            pairs[2][1].busy = _Lost()
+    assert executor._staging is None and executor._leased is None
+    # the three sound ones came back with nothing of the device left on them
+    assert sorted(map(id, jx._STAGING_POOL)) == sorted(id(p) for p in pairs if p is not pairs[2])
+    assert all(stage.busy is None for pair in jx._STAGING_POOL for stage in pair)
 
 
 def test_without_a_mesh_the_loop_issues_the_device_operations_it_issued_before(
@@ -303,24 +354,158 @@ def test_the_stored_add_agrees_with_the_python_executor(tmp_path):
 
 
 def test_consecutive_chunks_go_to_different_chips(tmp_path, monkeypatch):
+    """They did while one thread took the chips in turn. Now a chip's chunks
+    are a lane: in grid order, on a thread of that chip's own, through a
+    pair no other lane touches."""
+    import jax
+
     z = _stored(tmp_path / "a.zarr", RNG.standard_normal(SHAPE))
-    reads = []
-    real = _LocalIO.readinto
-    monkeypatch.setattr(
-        _LocalIO, "readinto",
-        lambda self, name, buffer: (reads.append(os.path.basename(name)), real(self, name, buffer))[1],
-    )
+    reads, puts, zeros = [], [], []
+    real, real_put, real_zeros = _LocalIO.readinto, jax.device_put, jax.numpy.zeros
+
+    def readinto(self, name, buffer):
+        address = np.asarray(buffer).__array_interface__["data"][0]
+        reads.append((threading.current_thread().name, os.path.basename(name), address))
+        return real(self, name, buffer)
+
+    def device_put(x, device=None, **kw):
+        puts.append((threading.current_thread().name, device))
+        return real_put(x, device, **kw)
+
+    def make_zeros(shape, dtype=None, **kw):
+        zeros.append((threading.current_thread().name, kw["device"], jax.config.jax_default_device))
+        return real_zeros(shape, dtype, **kw)
+
+    monkeypatch.setattr(_LocalIO, "readinto", readinto)
+    monkeypatch.setattr(jax, "device_put", device_put)
+    monkeypatch.setattr(jax.numpy, "zeros", make_zeros)
     executor = JaxExecutor(mesh=_mesh())
+    before = {t.ident for t in threading.enumerate()}
     with leased_staging(executor):
-        executor._device_put(z, tuple(z.shape), z.chunkset())
-    rows = [int(key.split(".")[0]) for key in reads]
-    assert rows == [0, 1, 2, 3] * 4 and sorted(reads) == sorted(f"{i}.{j}" for i in range(4) for j in range(4))
-    # without a mesh: grid order, as before
-    reads.clear()
+        value = executor._device_put(z, tuple(z.shape), z.chunkset())
+        pairs = list(executor._leased)
+    owners = chunk_owners(value.sharding, SHAPE, z.chunkset())
+    lanes = [f"cubed-tpu-preload-{n}" for n in range(4)]
+    assert {thread for thread, _, _ in reads} == set(lanes) == {thread for thread, _ in puts}
+    chips, addresses = [], []
+    for n, lane in enumerate(lanes):
+        keys = [key for thread, key, _ in reads if thread == lane]
+        (row,) = {int(key.split(".")[0]) for key in keys}
+        assert keys == [f"{row}.{j}" for j in range(4)]  # grid order
+        # put on the chip that owns the row, and on no other
+        (chip,) = {device for thread, device in puts if thread == lane}
+        assert chip is owners[(row, 0)][0]
+        chips.append(chip)
+        # its shard made once, there, with that chip the lane's default device:
+        # ``zeros`` fills on the default device and copies to the one named
+        assert [made[1:] for made in zeros if made[0] == lane] == [(chip, chip)]
+        # the lane's pair, buffer for buffer, taking turns
+        mine = [address for thread, _, address in reads if thread == lane]
+        assert mine == [stage.buffer.ctypes.data for stage in pairs[n]] * 2
+        addresses += mine[:2]
+    assert len(set(chips)) == 4 and len(set(addresses)) == 8
+    assert {shard.device for shard in value.addressable_shards} == set(chips)
+    # joined before the shards were: no thread outlives the call
+    assert not [t for t in threading.enumerate()
+                if t.ident not in before and t.name.startswith("cubed-tpu")]
+    # without a mesh: one lane, on the calling thread, in grid order, as before
+    reads.clear(), puts.clear()
     plain = JaxExecutor()
     with leased_staging(plain):
         plain._device_put(z, tuple(z.shape), z.chunkset())
-    assert reads == [f"{i}.{j}" for i in range(4) for j in range(4)]
+        assert len(plain._leased) == 1
+    me = threading.current_thread().name
+    assert [(thread, key) for thread, key, _ in reads] == [
+        (me, f"{i}.{j}") for i in range(4) for j in range(4)]
+    assert puts == [(me, None)] * 16 and zeros[4:] == [(me, None, None)]
+    assert not [t for t in threading.enumerate()
+                if t.ident not in before and t.name.startswith("cubed-tpu")]
+
+
+def _grid(devices):
+    """(shape, chunks, mesh): a chunk-row a chip on 4 and on 8 devices."""
+    import jax
+
+    shape, chunks = (SHAPE, CHUNKS) if devices == 4 else ((64, 12), (8, 6))
+    return shape, chunks, make_mesh(devices=jax.devices()[:devices])
+
+
+@pytest.mark.parametrize("carry_bits", [False, True], ids=["as_numbers", "as_bits"])
+@pytest.mark.parametrize("devices", [4, 8])
+def test_the_lanes_counters_add_up_to_the_one_thread_loops(
+    tmp_path, monkeypatch, devices, carry_bits
+):
+    """Every lane counts into a counter of its own, added at the join: with
+    the interpreter switching threads as often as it can, nothing is lost."""
+    shape, chunks, mesh = _grid(devices)
+    host = RNG.standard_normal(shape)
+    z = _stored(tmp_path / "a.zarr", host, chunks)
+    if carry_bits:
+        monkeypatch.setattr(jx, "_float64_round_trips", lambda device: False)
+    counted = {}
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for name, executor in (("loop", JaxExecutor()), ("lanes", JaxExecutor(mesh=mesh))):
+            jx.release_staging_buffers()  # each as a process's first computes
+            executor._carry_bits = carry_bits
+            for _ in range(3):  # the first makes the buffers, the others find them
+                with leased_staging(executor):
+                    value = executor._device_put(z, tuple(z.shape), z.chunkset())
+                assert np.asarray(value).tobytes() == host.tobytes()
+            counted[name] = executor.stats
+    finally:
+        sys.setswitchinterval(interval)
+    lanes, loop = counted["lanes"], counted["loop"]
+    for key in ("h2d_bytes", "h2d_stream_bytes", "h2d_bits_bytes", "stage_reused_bytes", "f64_as_bits"):
+        assert lanes[key] == loop[key], key
+    assert lanes["h2d_stream_bytes"] == 3 * host.nbytes and lanes["stage_reused_bytes"] == 2 * host.nbytes
+    assert lanes["h2d_bits_bytes"] == (3 * host.nbytes if carry_bits else 0)
+    # all of it on a lane thread of its owner's under a mesh, none without one
+    assert lanes["h2d_lane_bytes"] == lanes["mesh_owner_bytes"] == lanes["h2d_stream_bytes"]
+    assert loop["h2d_lane_bytes"] == loop["mesh_owner_bytes"] == 0
+    assert lanes["mesh_gathered_bytes"] == 0
+    assert all(type(lanes[key]) is int for key in ("h2d_lane_bytes", "stage_wait_us", "h2d_bytes"))
+    assert lanes["stage_wait_us"] >= 0
+    assert len(jx._STAGING_POOL) == devices
+
+
+def test_with_float32_compute_a_lane_puts_what_the_calling_thread_would(tmp_path, monkeypatch):
+    """``execute_dag`` turns x64 off for its own thread alone; a lane's
+    thread takes the setting over, and with it the program the writer
+    compiled for the calling thread's."""
+    spec = ct.Spec(work_dir=str(tmp_path / "work"), allowed_mem="200MB")
+    a, b = RNG.standard_normal(SHAPE), RNG.standard_normal(SHAPE)
+    pa, pb = (_stored(tmp_path / f"{k}.zarr", h).store for k, h in (("a", a), ("b", b)))
+    written, real_writer = [], jx._chunk_writer
+
+    def writer():
+        write = real_writer()
+
+        def spy(whole, piece, start, extent):
+            written.append((threading.current_thread().name, str(whole.dtype), str(piece.dtype)))
+            return write(whole, piece, start, extent)
+
+        return spy
+
+    monkeypatch.setattr(jx, "_chunk_writer", writer)
+    read = {}
+    for name, mesh in (("mesh", _mesh()), ("plain", None)):
+        cap, target = _Capture(), str(tmp_path / f"{name}.zarr")
+        ct.to_zarr(xp.add(ct.from_zarr(pa, spec=spec), ct.from_zarr(pb, spec=spec)), target,
+                   executor=JaxExecutor(mesh=mesh, compute_dtype="float32"), callbacks=[cap])
+        read[name] = (_read_with_numpy(target), cap.stats, list(written))
+        written.clear()
+    (on_lanes, lanes_stats, lanes), (inline, plain_stats, loop) = read["mesh"], read["plain"]
+    assert {thread for thread, _, _ in lanes} == {f"cubed-tpu-preload-{n}" for n in range(4)}
+    assert {thread for thread, _, _ in loop} == {threading.current_thread().name}
+    assert {row[1:] for row in lanes} == {("float32", "float32")}
+    assert {row[1:] for row in loop} == {("float32", "float32")}
+    assert on_lanes.tobytes() == inline.tobytes()
+    expected = (a.astype(np.float32) + b.astype(np.float32)).astype(np.float64)
+    assert on_lanes.tobytes() == expected.tobytes()
+    assert lanes_stats["h2d_lane_bytes"] == lanes_stats["h2d_stream_bytes"] == plain_stats["h2d_stream_bytes"]
+    assert plain_stats["h2d_lane_bytes"] == 0 and type(plain_stats["h2d_lane_bytes"]) is int
 
 
 def test_a_chunk_leaves_from_its_owner_and_no_other_chip(tmp_path):
@@ -437,17 +622,24 @@ def test_chunk_owners_names_one_chip_a_chunk_or_none():
 def test_a_stream_that_ends_early_leaves_no_buffer_leased_and_no_thread(
     tmp_path, monkeypatch, ending
 ):
+    """A read that keeps failing, or a cancellation, in one lane of the
+    second source ends all four: no chunk is started after it, the error is
+    raised once every lane's thread has ended, and all four pairs are the
+    pool's again with nothing of the device left on them."""
     spec = ct.Spec(work_dir=str(tmp_path / "work"), allowed_mem="200MB")
     a, b = RNG.standard_normal(SHAPE), RNG.standard_normal(SHAPE)
     pa, pb = (_stored(tmp_path / f"{k}.zarr", h).store for k, h in (("a", a), ("b", b)))
     token, executor, reads = CancellationToken(), JaxExecutor(mesh=_mesh()), []
-    real = _LocalIO.readinto
+    real, lock = _LocalIO.readinto, threading.Lock()
+    at = 16 + 7  # in the second source: every lane has made both its buffers
 
     def readinto(self, name, buffer):
-        reads.append(name)
-        if len(reads) == 7 and ending == "cancelled":
+        with lock:
+            reads.append(name)
+            nth = len(reads)
+        if nth == at and ending == "cancelled":
             token.cancel("the test asked")
-        if len(reads) >= 7 and ending == "read_fault":
+        if nth >= at and ending == "read_fault":
             raise faults.FaultInjectedIOError("the disk is gone, says the test")
         return real(self, name, buffer)
 
@@ -457,16 +649,21 @@ def test_a_stream_that_ends_early_leaves_no_buffer_leased_and_no_thread(
     with pytest.raises(error):
         ct.to_zarr(xp.add(ct.from_zarr(pa, spec=spec), ct.from_zarr(pb, spec=spec)),
                    str(tmp_path / "c.zarr"), executor=executor, cancellation=token)
-    assert executor._staging is None
-    (pair,) = jx._STAGING_POOL
-    assert all(stage.busy is None and stage.buffer is not None for stage in pair)
+    assert executor._staging is None and executor._leased is None
+    if ending == "cancelled":
+        # the reads in flight finished, one a lane at most; no other began
+        assert at <= len(reads) <= at + 3
+    pairs = list(jx._STAGING_POOL)
+    assert len(pairs) == 4
+    assert all(stage.busy is None and stage.buffer is not None for pair in pairs for stage in pair)
     assert not [t for t in threading.enumerate()
                 if t.ident not in before and t.name.startswith("cubed-tpu")]
-    # and the next compute, under the same mesh, streams through the pair
+    # and the next compute, under the same mesh, streams through the pairs
     monkeypatch.setattr(_LocalIO, "readinto", real)
     cap, target = _Capture(), str(tmp_path / "d.zarr")
     ct.to_zarr(xp.add(ct.from_zarr(pa, spec=spec), ct.from_zarr(pb, spec=spec)), target,
                executor=JaxExecutor(mesh=_mesh()), callbacks=[cap])
     assert _read_with_numpy(target).tobytes() == (a + b).tobytes()
     assert cap.stats["stage_reused_bytes"] == cap.stats["h2d_stream_bytes"] == 2 * a.nbytes
-    assert jx._STAGING_POOL == [pair]
+    assert cap.stats["h2d_lane_bytes"] == cap.stats["h2d_stream_bytes"]
+    assert jx._STAGING_POOL == pairs
